@@ -143,8 +143,8 @@ if [ "${1:-}" = "--bench-smoke" ]; then
     # warm the cache twice into a throwaway dir: the second sweep must
     # load every serve-step executable and compile exactly 0
     _aotdir=$(mktemp -d)
-    JAX_PLATFORMS=cpu python scripts/tfos_warmcache.py \
-        --cache-dir "$_aotdir" --spec-k 4 --runs 2 --check-warm
+    JAX_PLATFORMS=cpu JAX_COMPILATION_CACHE_DIR="$_aotdir" \
+        python scripts/tfos_warmcache.py --spec-k 4 --runs 2 --check-warm
     rc=$?
     rm -rf "$_aotdir"
     if [ $rc -ne 0 ]; then

@@ -105,8 +105,8 @@ def main() -> None:
     elif sweep is None:
         out["pending"].append("resnet_sweep.json (no sweep captured)")
     elif flags:
-        # flags without a control: report them raw so a tunnel window
-        # that lost only the control run is distinguishable
+        # flags without a control: report them raw so a sweep that
+        # lost only the control run is distinguishable
         out["flag_rows_without_control"] = [
             {"xla": r["xla"], "images_per_sec": r["images_per_sec"],
              "mfu": r.get("mfu")} for r in flags]

@@ -2,7 +2,7 @@
 time goes.
 
 The round-2 verdict's weakest number is 0.24 compute MFU on the b256 bf16
-train step (`bench_artifacts/resnet50_tpu_2026-07-29.json`); closing that gap
+train step (the old sweep's `bench_artifacts/resnet_sweep.json`, not re-measured on the attached chip); closing that gap
 needs evidence, not guesses.  This script jits the exact `stage_resnet` step
 from `scripts/tpu_sweep.py`, traces a few executions with `jax.profiler`, and
 converts the xplane with the installed `xprof` package into an HLO-level

@@ -6,13 +6,15 @@ Runs the SAME bucket x group warm-up sweep a warm standby pays
 (``serving/aot.py``), so every serve-step executable the sweep touches —
 decode step, the prefill bucket/group grid, scatter, and (with
 ``--spec-k``) the draft-propose + fused-verify pair — is compiled ONCE,
-here, and serialized to the cache directory.  Every later process that
-points at the directory (``ServingCluster.run(aot_cache=...)``, a cold
-replica, a promoting standby) resolves those sites by
+here, and serialized to the tier's AOT cache directory
+(``util.aot_cache_dir()``: the ``aot/`` sub-directory of
+``JAX_COMPILATION_CACHE_DIR`` where set, else of ``<checkout>/.jax_cache``).
+Every later process of an ``aot_cache=True`` tier (a cold replica, a
+promoting standby) resolves those sites by
 ``deserialize_and_load``: a cache read where the fleet used to pay an
 XLA compile inside the cold-start/heal window.
 
-    python scripts/tfos_warmcache.py --cache-dir /shared/aot \\
+    JAX_COMPILATION_CACHE_DIR=/shared/jax python scripts/tfos_warmcache.py \\
         --builder mypkg.models:my_builder --max-batch 4 --spec-k 4
 
 The builder is any picklable-by-reference serving model builder
@@ -65,17 +67,19 @@ def _resolve_builder(spec: str | None):
     return getattr(importlib.import_module(mod), fn)
 
 
-def warm_once(builder, cache_dir: str, *, max_batch: int, seed: int,
+def warm_once(builder, *, max_batch: int, seed: int,
               spec_k: int | None, draft_window: int,
               kv_page_tokens: int | None, prefill_chunk: int | None) -> dict:
     """One pre-bake pass: fresh batcher + fresh cache handle over the
     (shared) directory, the standby warm-up sweep, stats out."""
+    from tensorflowonspark_tpu import util
     from tensorflowonspark_tpu.models.serving import (ContinuousBatcher,
                                                       DraftModel)
     from tensorflowonspark_tpu.serving.aot import AOTExecutableCache
     from tensorflowonspark_tpu.serving.standby import _warm_batcher
 
-    cache = AOTExecutableCache(cache_dir)
+    util.enable_compilation_cache()
+    cache = AOTExecutableCache(util.aot_cache_dir())
     cfg, params = builder({"seed": seed})
     kwargs = {}
     if spec_k is not None:
@@ -99,10 +103,8 @@ def warm_once(builder, cache_dir: str, *, max_batch: int, seed: int,
 
 def main():
     ap = argparse.ArgumentParser(
-        description="Pre-bake serving AOT executables into a cache dir.")
-    ap.add_argument("--cache-dir", required=True,
-                    help="AOT cache directory (created if missing); point "
-                         "ServingCluster.run(aot_cache=...) at it")
+        description="Pre-bake serving AOT executables into the tier's "
+                    "AOT cache (placed by JAX_COMPILATION_CACHE_DIR).")
     ap.add_argument("--builder", default=None,
                     help="module:function serving model builder "
                          "(default: the tiny bench GPT)")
@@ -130,7 +132,7 @@ def main():
     runs = []
     for i in range(max(1, args.runs)):
         stats = warm_once(
-            builder, args.cache_dir, max_batch=args.max_batch,
+            builder, max_batch=args.max_batch,
             seed=args.seed, spec_k=args.spec_k,
             draft_window=args.draft_window,
             kv_page_tokens=args.kv_page_tokens,

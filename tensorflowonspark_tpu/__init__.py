@@ -23,14 +23,10 @@ Inside ``map_fun(args, ctx)`` the user pulls data with ``ctx.get_data_feed()``
 
 __version__ = "0.1.0"
 
-from tensorflowonspark_tpu.util import apply_jax_platforms_env as _apply_env
-
-# A sitecustomize may import jax at interpreter startup, freezing the
-# platform choice before user code runs; re-apply JAX_PLATFORMS so env-var
-# platform selection keeps working for every entry point that imports us.
-_apply_env()
-
-from tensorflowonspark_tpu.cluster import (InputMode, TPUCluster,  # noqa: F401,E402
+# Importing this package never imports jax: the driver process stays off
+# the accelerator, which belongs to one process at a time — the worker
+# that runs the user's map_fun (tests/test_chip_ownership.py pins this).
+from tensorflowonspark_tpu.cluster import (InputMode, TPUCluster,  # noqa: F401
                                            run_with_recovery)
 from tensorflowonspark_tpu.datafeed import DataFeed  # noqa: F401
 from tensorflowonspark_tpu.health import (ClusterFailure, ClusterMonitor,  # noqa: F401

@@ -86,7 +86,6 @@ def _worker_entry(executor_id: int, env: dict, fn, tf_args, cluster_meta: dict,
         os.dup2(f.fileno(), 2)
         sys.stdout = os.fdopen(1, "w", buffering=1, closefd=False)
         sys.stderr = os.fdopen(2, "w", buffering=1, closefd=False)
-    util.apply_jax_platforms_env()
     import logging as _logging
 
     _logging.basicConfig(level=_logging.INFO,

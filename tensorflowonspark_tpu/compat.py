@@ -50,86 +50,56 @@ def is_gpu_available() -> bool:
         return False
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
-    """``jax.shard_map`` across jax versions (the rebuild's own API churn).
+# -- the rebuild's own API churn --------------------------------------------
+# One installation is supported: jax/jaxlib 0.9.0 (libtpu 0.0.34, flax
+# 0.12.3).  The names below stay the single place the ``parallel/`` modules
+# reach jax's shard_map family through (``tfos-check``'s compat-discipline
+# rule keys on them), so the next jax drift is absorbed here — but they
+# carry no branch for a release that is not installed.
 
-    Newer jax promotes ``shard_map`` to the top-level namespace (renaming
-    the replication check ``check_rep`` → ``check_vma`` on the way);
-    older releases only have ``jax.experimental.shard_map.shard_map``.
-    Every ``parallel/`` call site goes through this shim so the package
-    imports (and the examples run) on both: the top-level symbol is
-    preferred when it exists, otherwise ``check_vma`` is translated back
-    to the experimental API's ``check_rep``.  Keyword-only beyond ``f``,
-    matching the strictest signature of the two.
-    """
+def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kwargs):
+    """``jax.shard_map``; ``check_vma=None`` leaves jax's default."""
     import jax
 
-    if hasattr(jax, "shard_map"):
-        if check_vma is not None:
-            kwargs["check_vma"] = check_vma
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, **kwargs)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    # the experimental API's replication checker (check_rep) predates the
-    # vma system the parallel/ modules are written against (explicit
-    # pcast/psum pairs, varying-carry declarations); translate an explicit
-    # choice, and default it OFF otherwise — the old checker rejects
-    # vma-idiomatic programs it cannot type
-    kwargs["check_rep"] = bool(check_vma) if check_vma is not None else False
-    return _shard_map(f, mesh, in_specs, out_specs, **kwargs)
+    if check_vma is not None:
+        kwargs["check_vma"] = check_vma
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)
 
 
 def axis_size(name):
-    """``jax.lax.axis_size`` where it exists; ``lax.psum(1, name)`` — the
-    classic spelling, identical semantics including the ``NameError`` on
-    an unbound axis outside ``shard_map`` — on older jax."""
+    """``jax.lax.axis_size`` (``NameError`` on an axis no enclosing
+    ``shard_map`` binds)."""
     from jax import lax
 
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(name)
-    return lax.psum(1, name)
+    return lax.axis_size(name)
 
 
 def pcast(x, axes, *, to: str = "varying"):
-    """``jax.lax.pcast`` when the vma system exists; identity otherwise
-    (older jax has no varying-axes types, so there is nothing to mark —
-    the shim above also disables the incompatible ``check_rep`` there)."""
+    """``jax.lax.pcast``."""
     from jax import lax
 
-    if hasattr(lax, "pcast"):
-        return lax.pcast(x, axes, to=to)
-    return x
+    return lax.pcast(x, axes, to=to)
 
 
 def vma_of(x) -> frozenset:
-    """The varying-manual-axes set of ``x`` (``jax.typeof(x).vma``), or an
-    empty set on jax versions without the vma system."""
+    """The varying-manual-axes set of ``x`` (``jax.typeof(x).vma``) — empty
+    outside ``shard_map`` and under ``check_vma=False``."""
     import jax
 
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return frozenset()
-    return frozenset(getattr(typeof(x), "vma", frozenset()))
+    return frozenset(jax.typeof(x).vma)
 
 
 def has_vma() -> bool:
-    """True when this jax has the varying-manual-axes type system
-    (``jax.typeof`` + ``lax.pcast``); callers that introspect vma must
-    fall back to static knowledge of their own collectives elsewhere."""
-    import jax
-
-    return hasattr(jax, "typeof") and hasattr(jax.lax, "pcast")
+    """Whether this jax has the varying-manual-axes type system
+    (``jax.typeof`` + ``lax.pcast``): the installed one does."""
+    return True
 
 
 def bound_axes() -> tuple:
-    """Axis names bound by an enclosing ``shard_map``/``pmap`` trace on
-    jax versions that still carry a global axis env (empty elsewhere) —
-    the fallback "am I inside shard_map" probe for code that otherwise
-    reads ``typeof(x).vma``, which pre-vma jax cannot answer."""
-    try:
-        from jax._src import core as _core
+    """Axis names an enclosing ``shard_map`` binds (empty outside one) —
+    the "am I inside shard_map" probe for code whose inputs carry no vma
+    (``check_vma=False``)."""
+    import jax
 
-        return tuple(_core.get_axis_env().axis_sizes.keys())
-    except (ImportError, AttributeError):
-        return ()
+    return tuple(jax.sharding.get_abstract_mesh().manual_axes)

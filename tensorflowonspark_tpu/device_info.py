@@ -14,10 +14,52 @@ from __future__ import annotations
 
 import logging
 import os
+import sys
 
 logger = logging.getLogger(__name__)
 
 MAX_RETRIES = 3  # API parity with gpu_info.MAX_RETRIES; unused on TPU.
+
+
+class ChipOwnershipError(RuntimeError):
+    """A process that must stay off the accelerator touched it, or a
+    worker found its chip held by another process."""
+
+
+def assert_off_accelerator(who: str) -> None:
+    """Raise :class:`ChipOwnershipError` if this process has imported jax.
+
+    A chip belongs to one process at a time (libtpu holds a host-wide
+    lock from backend initialisation until the process exits).  Processes
+    that only coordinate — the driver, the shard members of a serving
+    gang whose leader owns every chip of the host — therefore never
+    import jax at all: a coordinator that initialised a backend would
+    make the process that runs the model fail at start-up."""
+    if "jax" in sys.modules:
+        raise ChipOwnershipError(
+            f"{who} imported jax: this process only coordinates and must "
+            "stay off the accelerator, which belongs to the one process "
+            "that runs the model")
+
+
+def chip_busy_hint(traceback_text: str) -> str | None:
+    """Name the failure when ``traceback_text`` is a worker dying because
+    another process holds the chip, else None.
+
+    Measured on the attached v5e (jax 0.9.0, libtpu 0.0.34): the second
+    process to initialise the TPU backend fails within seconds — it does
+    not hang — with ``Unable to initialize backend 'tpu': ABORTED:
+    Internal error when accessing libtpu multi-process lockfile``, and
+    libtpu's own message advises deleting the lock file, which would let
+    two processes fight over one chip."""
+    if "libtpu multi-process lockfile" not in traceback_text:
+        return None
+    return ("ChipOwnershipError: another process on this host already "
+            "holds the TPU — a chip belongs to ONE process at a time.  "
+            "Usual causes: the driver (or a parent) imported jax and "
+            "initialised the backend before starting this worker, or two "
+            "workers were started on a host whose chips one process owns.  "
+            "Do NOT remove /tmp/libtpu_lockfile; stop the other process.")
 
 
 def num_local_devices() -> int:

@@ -33,13 +33,9 @@ def _context_mesh():
     or None when tracing outside any mesh context (single-device use,
     ``eval_shape``) — where a bare-PartitionSpec sharding constraint would
     raise."""
-    # older jax has no abstract-mesh tracking at all; fall through to the
-    # physical-mesh probe below (compat.py documents the jax-drift policy)
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        m = get_abstract()
-        if not m.empty:
-            return m
+    m = jax.sharding.get_abstract_mesh()
+    if not m.empty:
+        return m
     try:
         import warnings
 

@@ -287,7 +287,7 @@ class ContinuousBatcher:
         #: host sees identical tokens — the scan body is the plain step
         #: — but pays one dispatch per BLOCK instead of per token: the
         #: lever for deployments where dispatch latency rivals step time
-        #: (remote dispatch / tunnels; even local PJRT costs ~0.1 ms
+        #: (remote dispatch; even local PJRT costs ~0.1 ms
         #: against the ~2 ms steps of small-model decode)
         self.decode_block_steps = decode_block_steps
         #: prompt-lookup speculative decoding INSIDE continuous batching:
@@ -1668,7 +1668,7 @@ class ContinuousBatcher:
         every active slot, and return every request id that finished —
         whether during decode or already at admission.
 
-        If a device dispatch raises (OOM, preemption, a dead tunnel),
+        If a device dispatch raises (OOM, preemption, a lost device),
         the batcher is marked unusable — the failing executable had
         already donated the cache buffer, so the instance cannot be
         resumed — and every later call raises ``RuntimeError`` naming

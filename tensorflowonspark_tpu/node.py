@@ -27,12 +27,11 @@ from __future__ import annotations
 
 import logging
 import os
-import tempfile
 import time
 import traceback
 
 from tensorflowonspark_tpu import chaos as chaos_mod
-from tensorflowonspark_tpu import preemption, util
+from tensorflowonspark_tpu import device_info, preemption, util
 from tensorflowonspark_tpu.datafeed import DataFeed
 from tensorflowonspark_tpu.health import HeartbeatReporter
 from tensorflowonspark_tpu.queues import DEFAULT_QUEUES, QueueServer
@@ -324,20 +323,15 @@ def run(fn, tf_args, cluster_meta: dict, queues=DEFAULT_QUEUES):
             # relaunched worker (preemption recovery, run_with_recovery)
             # reuses its predecessor's compiles instead of paying the
             # tens-of-seconds TPU compile again.  Set via env (honored by
-            # jax at its first import) rather than enable_compilation_cache
-            # so no jax import happens before the user's map_fun — fn may
-            # set JAX_* env vars itself, and non-JAX workers shouldn't pay
-            # the import.  setdefault: explicit user env always wins.
-            # default cache dir is per-user: a world-shared /tmp path
-            # breaks when another user owns it, and loading serialized
-            # executables from a dir any local user can pre-create is a
-            # trust surface (ADVICE r3)
-            os.environ.setdefault(
-                "JAX_COMPILATION_CACHE_DIR",
-                os.environ.get(
-                    "TFOS_COMPILATION_CACHE",
-                    os.path.join(tempfile.gettempdir(),
-                                 f"tfos_jax_cache_{os.getuid()}")))
+            # jax at its first import) so no jax import happens before the
+            # user's map_fun — fn may set JAX_* env vars itself, and
+            # non-JAX workers shouldn't pay the import.  setdefault: where
+            # the environment already places the cache, nothing here moves
+            # it; otherwise it is the one fixed in-checkout directory
+            # (util.compilation_cache_dir), the same for every worker,
+            # replica and standby of every run.
+            os.environ.setdefault("JAX_COMPILATION_CACHE_DIR",
+                                  util.compilation_cache_dir())
             os.environ.setdefault(
                 "JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
                 os.environ.get("TFOS_CACHE_MIN_COMPILE_SECS", "1.0"))
@@ -351,6 +345,11 @@ def run(fn, tf_args, cluster_meta: dict, queues=DEFAULT_QUEUES):
             logger.info("node %d map_fun finished", executor_id)
         except Exception:
             tb = traceback.format_exc()
+            # a worker that found its chip held by another process gets
+            # the failure named, ahead of libtpu's own (wrong) advice
+            hint = device_info.chip_busy_hint(tb)
+            if hint:
+                tb = f"{hint}\n{tb}"
             logger.error("node %d failed:\n%s", executor_id, tb)
             if crash_file:
                 try:

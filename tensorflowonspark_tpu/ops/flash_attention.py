@@ -5,17 +5,21 @@ class — SURVEY.md §5 "long-context: absent"); this kernel is part of the
 rebuild's TPU-first long-context story, alongside
 ``parallel.ring_attention``.  Design, per the Pallas guide:
 
-- grid ``(batch, heads, seq_blocks)``; the query block lives in VMEM, the
-  K/V sequence streams through it in ``block_k`` chunks inside a
-  ``fori_loop`` with an online (numerically stable, one-pass) softmax, so
-  the O(T²) score matrix is never materialised in HBM;
+- grid ``(batch, heads, q_blocks, k_blocks)``; the query block and its
+  accumulators live in VMEM while the K/V blocks stream past with an
+  online (numerically stable, one-pass) softmax, so the O(T²) score
+  matrix is never materialised in HBM;
 - scores/accumulators in float32 (MXU ``preferred_element_type``),
   activations bf16-friendly;
-- causal masking trims the K loop's trip count per query block instead of
-  computing masked blocks;
+- causal masking skips the K blocks a query block cannot see (no
+  compute, and no DMA: the block index is clamped to one already held);
 - the backward pass recomputes probabilities from the saved logsumexp
   (flash-attention-2 style): one kernel for dQ (grid over query blocks),
   one for dK/dV (grid over key blocks) — no O(T²) residuals;
+- K/V (and, in the dK/dV kernel, Q/dO) stream through the innermost grid
+  axis one block at a time, so fast-memory use is independent of the
+  sequence length; logsumexp/delta travel as lane-dense ``[B, H, 1, T]``
+  rows;
 - off-TPU the same kernels run under ``interpret=True`` so CPU tests
   exercise the identical code path.
 
@@ -78,10 +82,12 @@ def _tuned_blocks() -> tuple[int, int]:
         return 512, 512
 
 
-def _causal_mask(s, q_block, block_k, qi, j, window=None):
-    bq, bk = s.shape
-    q_pos = qi * q_block + lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-    k_pos = j * block_k + lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
+def _causal_mask(s, q0, k0, window=None, q_axis=0):
+    """Mask ``s`` by absolute position; ``q0``/``k0`` are the first query
+    and key position of the tile, ``q_axis`` the axis queries run along
+    (0 for ``[q, k]`` scores, 1 for the dK/dV kernel's ``[k, q]``)."""
+    q_pos = q0 + lax.broadcasted_iota(jnp.int32, s.shape, q_axis)
+    k_pos = k0 + lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_axis)
     keep = q_pos >= k_pos
     if window is not None:  # sliding window: only the last `window` keys
         keep &= k_pos > q_pos - window
@@ -95,93 +101,155 @@ def _k_span(Tk, causal, window, block_k):
     return max(block_k, Tk // 2) if causal else Tk
 
 
-def _k_lo(qi, bq, block_k, window):
-    """First K block a query block can see under a sliding window."""
+def _k_range(qi, block_q, block_k, nk, causal, window):
+    """``(first, last)`` K block a query block attends to (inclusive):
+    causal trims from above at the diagonal, a sliding window from
+    below.  ``first > last`` means the query block sees no key at all."""
+    if not causal:
+        return 0, nk - 1
+    last = jnp.minimum(nk - 1, (qi * block_q + block_q - 1) // block_k)
     if window is None:
-        return 0
-    return jnp.maximum(0, (qi * bq - (window - 1)) // block_k)
+        return 0, last
+    return jnp.maximum(0, (qi * block_q - (window - 1)) // block_k), last
+
+
+def _q_range(ki, block_q, block_k, nq, causal, window):
+    """``(first, last)`` Q block that can see key block ``ki``."""
+    if not causal:
+        return 0, nq - 1
+    first = (ki * block_k) // block_q
+    if window is None:
+        return first, nq - 1
+    # queries beyond k_pos + window - 1 can't see this key block
+    return first, jnp.minimum(
+        nq - 1, (ki * block_k + block_k - 1 + window - 1) // block_q)
+
+
+def _stream(rng):
+    """Index of the block to fetch at inner grid step ``j``: ``j`` clamped
+    into the visited range, so the steps a tile skips re-name a block that
+    is already in fast memory and cost no DMA."""
+    def index(j, outer):
+        first, last = rng(outer)
+        # last is always a valid block; an empty range (first > last)
+        # clamps onto it
+        return jnp.minimum(jnp.maximum(j, first), last)
+    return index
+
+
+_GRID_SEMANTICS = ("parallel", "parallel", "parallel", "arbitrary")
+
+
+def _compiler_params(interpret):
+    # K/V (and Q/dO in the dK/dV kernel) stream through the innermost grid
+    # axis, which carries the accumulators and so must run in order
+    if interpret:
+        return {}
+    from jax.experimental.pallas import tpu as pltpu
+
+    return {"compiler_params": pltpu.CompilerParams(
+        dimension_semantics=_GRID_SEMANTICS)}
+
+
+def _scratch(*shapes):
+    from jax.experimental.pallas import tpu as pltpu
+
+    return [pltpu.VMEM(s, jnp.float32) for s in shapes]
 
 
 # ---------------------------------------------------------------- forward
 
-def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_k,
-                has_bias, window):
-    bias_ref, o_ref, lse_ref = rest if has_bias else (None, *rest)
-    bq = q_ref.shape[2]
-    T = k_ref.shape[2]
-    q = q_ref[0, 0]                                       # (bq, D)
+def _fwd_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
+                nk, has_bias, window):
+    bias_ref, o_ref, lse_ref, acc, m_s, l_s = \
+        rest if has_bias else (None, *rest)
     qi = pl.program_id(2)
-    nk = T // block_k
-    j0 = 0
-    if causal:  # only K blocks at or below this Q block's diagonal
-        nk = jnp.minimum(nk, (qi * bq + bq - 1) // block_k + 1)
-        j0 = _k_lo(qi, bq, block_k, window)  # window trims from below
+    j = pl.program_id(3)
+    first, last = _k_range(qi, block_q, block_k, nk, causal, window)
 
-    def body(j, carry):
-        o, m, l = carry
-        k_blk = k_ref[0, 0, pl.ds(j * block_k, block_k), :]
-        v_blk = v_ref[0, 0, pl.ds(j * block_k, block_k), :]
+    @pl.when(j == 0)
+    def _init():
+        acc[...] = jnp.zeros_like(acc)
+        m_s[...] = jnp.full_like(m_s, NEG_INF)
+        l_s[...] = jnp.zeros_like(l_s)
+
+    def step():
+        q = q_ref[0, 0]                                   # (bq, D)
+        k_blk = k_ref[0, 0]                               # (bk, D)
+        v_blk = v_ref[0, 0]
         s = lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
         if bias_ref is not None:  # key-padding mask: one VPU pass over s
-            s = s + bias_ref[0, 0, pl.ds(j * block_k, block_k)][None, :]
+            s = s + bias_ref[0]                           # (1, bk)
         if causal:
-            s = _causal_mask(s, bq, block_k, qi, j, window)
+            s = _causal_mask(s, qi * block_q, j * block_k, window)
+        m = m_s[...]
         m_new = jnp.maximum(m, s.max(axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
-        l = alpha * l + p.sum(axis=-1, keepdims=True)
-        pv = lax.dot_general(p.astype(v_blk.dtype), v_blk,
-                             (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        return o * alpha + pv, m_new, l
+        l_s[...] = alpha * l_s[...] + p.sum(axis=-1, keepdims=True)
+        m_s[...] = m_new
+        acc[...] = acc[...] * alpha + lax.dot_general(
+            p.astype(v_blk.dtype), v_blk, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    D = q_ref.shape[3]
-    o0 = jnp.zeros((bq, D), jnp.float32)
-    m0 = jnp.full((bq, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    o, m, l = lax.fori_loop(j0, nk, body, (o0, m0, l0))
-    # A row whose keys are ALL masked keeps m pinned at NEG_INF (any real
-    # score sits far above NEG_INF/2): without this check the online softmax
-    # degenerates to p=exp(0)=1 on the masked scores and the row silently
-    # returns the mean of V.  Emit zeros instead, and push the row's lse to
-    # -NEG_INF so the backward's exp(s - lse) underflows to exact zeros
-    # (delta is also 0 there since out==0, so dq/dk/dv get no garbage).
-    valid = m > NEG_INF * 0.5
-    l = jnp.maximum(l, _EPS)
-    o_ref[0, 0] = jnp.where(valid, o / l, 0.0).astype(o_ref.dtype)
-    lse_ref[0, 0] = jnp.where(valid, m + jnp.log(l), -NEG_INF)
+    if causal:  # K blocks above the diagonal / below the window: skipped
+        pl.when((j >= first) & (j <= last))(step)
+    else:
+        step()
+
+    @pl.when(j == nk - 1)
+    def _finish():
+        # A row whose keys are ALL masked keeps m pinned at NEG_INF (any
+        # real score sits far above NEG_INF/2): without this check the
+        # online softmax degenerates to p=exp(0)=1 on the masked scores and
+        # the row silently returns the mean of V.  Emit zeros instead, and
+        # push the row's lse to -NEG_INF so the backward's exp(s - lse)
+        # underflows to exact zeros (delta is also 0 there since out==0, so
+        # dq/dk/dv get no garbage).
+        m = m_s[...]
+        valid = m > NEG_INF * 0.5
+        l = jnp.maximum(l_s[...], _EPS)
+        o_ref[0, 0] = jnp.where(valid, acc[...] / l, 0.0).astype(o_ref.dtype)
+        lse = jnp.where(valid, m + jnp.log(l), -NEG_INF)  # (bq, 1)
+        lse_ref[0, 0] = lse.reshape(1, block_q)           # lane-dense row
 
 
 def _fwd_impl(q, k, v, bias, causal, scale, block_q, block_k, interpret,
               window=None):
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
-    grid = (B, H, Tq // block_q)
+    nq, nk = Tq // block_q, Tk // block_k
+    kj = _stream(lambda qi: _k_range(qi, block_q, block_k, nk, causal,
+                                     window))
     blk = lambda bs, im: pl.BlockSpec(bs, im)  # noqa: E731
     in_specs = [
-        blk((1, 1, block_q, D), lambda b, h, qi: (b, h, qi, 0)),
-        blk((1, 1, Tk, D), lambda b, h, qi: (b, h, 0, 0)),
-        blk((1, 1, Tk, D), lambda b, h, qi: (b, h, 0, 0)),
+        blk((1, 1, block_q, D), lambda b, h, qi, j: (b, h, qi, 0)),
+        blk((1, 1, block_k, D), lambda b, h, qi, j: (b, h, kj(j, qi), 0)),
+        blk((1, 1, block_k, D), lambda b, h, qi, j: (b, h, kj(j, qi), 0)),
     ]
     args = (q, k, v)
     if bias is not None:
-        in_specs.append(blk((1, 1, Tk), lambda b, h, qi: (b, 0, 0)))
+        in_specs.append(blk((1, 1, block_k),
+                            lambda b, h, qi, j: (b, 0, kj(j, qi))))
         args += (bias,)
     out, lse = pl.pallas_call(
         functools.partial(_fwd_kernel, scale=scale, causal=causal,
-                          block_k=block_k, has_bias=bias is not None,
-                          window=window),
-        grid=grid,
+                          block_q=block_q, block_k=block_k, nk=nk,
+                          has_bias=bias is not None, window=window),
+        grid=(B, H, nq, nk),
         in_specs=in_specs,
         out_specs=[
-            blk((1, 1, block_q, D), lambda b, h, qi: (b, h, qi, 0)),
-            blk((1, 1, block_q, 1), lambda b, h, qi: (b, h, qi, 0)),
+            blk((1, 1, block_q, D), lambda b, h, qi, j: (b, h, qi, 0)),
+            blk((1, 1, 1, block_q), lambda b, h, qi, j: (b, h, 0, qi)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
-            jax.ShapeDtypeStruct((B, H, Tq, 1), jnp.float32),
+            # logsumexp rides the lane axis: a [..., T, 1] operand is
+            # padded 128x in fast memory
+            jax.ShapeDtypeStruct((B, H, 1, Tq), jnp.float32),
         ],
+        scratch_shapes=_scratch((block_q, D), (block_q, 1), (block_q, 1)),
         cost_estimate=pl.CostEstimate(
             # banded paths do O(Tq·(window+block)) work, not O(Tq·Tk);
             # causal halves it — keep the scheduler's intensity model honest
@@ -189,161 +257,178 @@ def _fwd_impl(q, k, v, bias, causal, scale, block_q, block_k, interpret,
             transcendentals=B * H * Tq * _k_span(Tk, causal, window, block_k),
             bytes_accessed=q.dtype.itemsize * B * H * (Tq + Tk) * D * 2),
         interpret=interpret,
+        **_compiler_params(interpret),
     )(*args)
     return out, lse
 
 
 # --------------------------------------------------------------- backward
 
-def _dq_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_k,
-               has_bias, window):
-    (bias_ref, do_ref, lse_ref, delta_ref, dq_ref) = \
+def _dq_kernel(q_ref, k_ref, v_ref, *rest, scale, causal, block_q, block_k,
+               nk, has_bias, window):
+    (bias_ref, do_ref, lse_ref, delta_ref, dq_ref, dq_acc) = \
         rest if has_bias else (None, *rest)
-    bq = q_ref.shape[2]
-    T = k_ref.shape[2]
-    q = q_ref[0, 0]
-    do = do_ref[0, 0].astype(jnp.float32)
-    lse = lse_ref[0, 0]                                   # (bq, 1)
-    delta = delta_ref[0, 0]
     qi = pl.program_id(2)
-    nk = T // block_k
-    j0 = 0
-    if causal:
-        nk = jnp.minimum(nk, (qi * bq + bq - 1) // block_k + 1)
-        j0 = _k_lo(qi, bq, block_k, window)
+    j = pl.program_id(3)
+    first, last = _k_range(qi, block_q, block_k, nk, causal, window)
 
-    def body(j, dq):
-        k_blk = k_ref[0, 0, pl.ds(j * block_k, block_k), :]
-        v_blk = v_ref[0, 0, pl.ds(j * block_k, block_k), :]
+    @pl.when(j == 0)
+    def _init():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+
+    def step():
+        q = q_ref[0, 0]
+        k_blk = k_ref[0, 0]
+        v_blk = v_ref[0, 0]
+        do = do_ref[0, 0]
+        lse = lse_ref[0, 0].reshape(block_q, 1)           # row -> column
+        delta = delta_ref[0, 0].reshape(block_q, 1)
         s = lax.dot_general(q, k_blk, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32) * scale
         if bias_ref is not None:
-            s = s + bias_ref[0, 0, pl.ds(j * block_k, block_k)][None, :]
+            s = s + bias_ref[0]
         if causal:
-            s = _causal_mask(s, bq, block_k, qi, j, window)
+            s = _causal_mask(s, qi * block_q, j * block_k, window)
         p = jnp.exp(s - lse)                               # (bq, bk)
-        dp = lax.dot_general(do, v_blk.astype(jnp.float32),
-                             (((1,), (1,)), ((), ())),
+        dp = lax.dot_general(do, v_blk, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)
         ds = p * (dp - delta)
-        return dq + lax.dot_general(ds.astype(k_blk.dtype), k_blk,
-                                    (((1,), (0,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
+        dq_acc[...] += lax.dot_general(ds.astype(k_blk.dtype), k_blk,
+                                       (((1,), (0,)), ((), ())),
+                                       preferred_element_type=jnp.float32)
 
-    D = q_ref.shape[3]
-    dq = lax.fori_loop(j0, nk, body, jnp.zeros((bq, D), jnp.float32))
-    dq_ref[0, 0] = (dq * scale).astype(dq_ref.dtype)
+    if causal:
+        pl.when((j >= first) & (j <= last))(step)
+    else:
+        step()
+
+    @pl.when(j == nk - 1)
+    def _finish():
+        dq_ref[0, 0] = (dq_acc[...] * scale).astype(dq_ref.dtype)
 
 
-def _dkv_kernel(k_ref, v_ref, q_ref, *rest, scale, causal, block_q,
-                has_bias, window):
-    (bias_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref) = \
-        rest if has_bias else (None, *rest)
-    bk = k_ref.shape[2]
-    T = q_ref.shape[2]
-    k_blk = k_ref[0, 0]
-    v_blk = v_ref[0, 0].astype(jnp.float32)
+def _dkv_kernel(k_ref, v_ref, q_ref, *rest, scale, causal, block_q, block_k,
+                nq, has_bias, window):
+    (bias_ref, do_ref, lse_ref, delta_ref, dk_ref, dv_ref, dk_acc,
+     dv_acc) = rest if has_bias else (None, *rest)
     ki = pl.program_id(2)
-    bias = None if bias_ref is None \
-        else bias_ref[0, 0, pl.ds(ki * bk, bk)][None, :]   # (1, bk)
-    nq = T // block_q
-    start = (ki * bk) // block_q if causal else 0
-    if causal and window is not None:
-        # queries beyond k_pos + window - 1 can't see this key block
-        nq = jnp.minimum(nq, (ki * bk + bk - 1 + window - 1) // block_q + 1)
+    i = pl.program_id(3)
+    first, last = _q_range(ki, block_q, block_k, nq, causal, window)
 
-    def body(i, carry):
-        dk, dv = carry
-        q_blk = q_ref[0, 0, pl.ds(i * block_q, block_q), :]
-        do_blk = do_ref[0, 0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        lse = lse_ref[0, 0, pl.ds(i * block_q, block_q), :]
-        delta = delta_ref[0, 0, pl.ds(i * block_q, block_q), :]
-        s = lax.dot_general(q_blk, k_blk, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32) * scale
-        if bias is not None:
-            s = s + bias
+    @pl.when(i == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def step():
+        # scores are held transposed, [k, q]: logsumexp and delta arrive
+        # as lane-dense rows and every product is a plain matmul
+        k_blk = k_ref[0, 0]
+        v_blk = v_ref[0, 0]
+        q_blk = q_ref[0, 0]
+        do_blk = do_ref[0, 0]
+        st = lax.dot_general(k_blk, q_blk, (((1,), (1,)), ((), ())),
+                             preferred_element_type=jnp.float32) * scale
+        if bias_ref is not None:
+            st = st + bias_ref[0].reshape(block_k, 1)
         if causal:
-            s = _causal_mask(s, block_q, bk, i, ki, window)
-        p = jnp.exp(s - lse)                               # (bq, bk)
-        dv = dv + lax.dot_general(p, do_blk, (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        dp = lax.dot_general(do_blk, v_blk, (((1,), (1,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-        ds = p * (dp - delta)
-        dk = dk + lax.dot_general(ds, q_blk.astype(jnp.float32),
-                                  (((0,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        return dk, dv
+            st = _causal_mask(st, i * block_q, ki * block_k, window,
+                              q_axis=1)
+        pt = jnp.exp(st - lse_ref[0, 0])                   # (bk, bq)
+        dv_acc[...] += lax.dot_general(
+            pt.astype(do_blk.dtype), do_blk, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dpt = lax.dot_general(v_blk, do_blk, (((1,), (1,)), ((), ())),
+                              preferred_element_type=jnp.float32)
+        dst = pt * (dpt - delta_ref[0, 0])
+        dk_acc[...] += lax.dot_general(
+            dst.astype(q_blk.dtype), q_blk, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
 
-    D = k_ref.shape[3]
-    z = jnp.zeros((bk, D), jnp.float32)
-    dk, dv = lax.fori_loop(start, nq, body, (z, z))
-    dk_ref[0, 0] = (dk * scale).astype(dk_ref.dtype)
-    dv_ref[0, 0] = dv.astype(dv_ref.dtype)
+    if causal:
+        pl.when((i >= first) & (i <= last))(step)
+    else:
+        step()
+
+    @pl.when(i == nq - 1)
+    def _finish():
+        dk_ref[0, 0] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[0, 0] = dv_acc[...].astype(dv_ref.dtype)
 
 
 def _bwd_impl(q, k, v, bias, out, lse, g, causal, scale, block_q, block_k,
               interpret, window=None):
     B, H, Tq, D = q.shape
     Tk = k.shape[2]
+    nq, nk = Tq // block_q, Tk // block_k
     delta = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32),
-                    axis=-1, keepdims=True)
+                    axis=-1)[:, :, None, :]               # (B, H, 1, Tq)
     blk = lambda bs, im: pl.BlockSpec(bs, im)  # noqa: E731
+    has_bias = bias is not None
 
+    kj = _stream(lambda qi: _k_range(qi, block_q, block_k, nk, causal,
+                                     window))
     dq_specs = [
-        blk((1, 1, block_q, D), lambda b, h, qi: (b, h, qi, 0)),
-        blk((1, 1, Tk, D), lambda b, h, qi: (b, h, 0, 0)),
-        blk((1, 1, Tk, D), lambda b, h, qi: (b, h, 0, 0)),
+        blk((1, 1, block_q, D), lambda b, h, qi, j: (b, h, qi, 0)),
+        blk((1, 1, block_k, D), lambda b, h, qi, j: (b, h, kj(j, qi), 0)),
+        blk((1, 1, block_k, D), lambda b, h, qi, j: (b, h, kj(j, qi), 0)),
     ]
     dq_args = (q, k, v)
-    if bias is not None:
-        dq_specs.append(blk((1, 1, Tk), lambda b, h, qi: (b, 0, 0)))
+    if has_bias:
+        dq_specs.append(blk((1, 1, block_k),
+                            lambda b, h, qi, j: (b, 0, kj(j, qi))))
         dq_args += (bias,)
     dq_specs += [
-        blk((1, 1, block_q, D), lambda b, h, qi: (b, h, qi, 0)),
-        blk((1, 1, block_q, 1), lambda b, h, qi: (b, h, qi, 0)),
-        blk((1, 1, block_q, 1), lambda b, h, qi: (b, h, qi, 0)),
+        blk((1, 1, block_q, D), lambda b, h, qi, j: (b, h, qi, 0)),
+        blk((1, 1, 1, block_q), lambda b, h, qi, j: (b, h, 0, qi)),
+        blk((1, 1, 1, block_q), lambda b, h, qi, j: (b, h, 0, qi)),
     ]
     dq = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          block_k=block_k, has_bias=bias is not None,
-                          window=window),
-        grid=(B, H, Tq // block_q),
+                          block_q=block_q, block_k=block_k, nk=nk,
+                          has_bias=has_bias, window=window),
+        grid=(B, H, nq, nk),
         in_specs=dq_specs,
-        out_specs=blk((1, 1, block_q, D), lambda b, h, qi: (b, h, qi, 0)),
+        out_specs=blk((1, 1, block_q, D), lambda b, h, qi, j: (b, h, qi, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, Tq, D), q.dtype),
+        scratch_shapes=_scratch((block_q, D)),
         interpret=interpret,
+        **_compiler_params(interpret),
     )(*dq_args, g, lse, delta)
 
+    qi_ = _stream(lambda ki: _q_range(ki, block_q, block_k, nq, causal,
+                                      window))
     dkv_specs = [
-        blk((1, 1, block_k, D), lambda b, h, ki: (b, h, ki, 0)),
-        blk((1, 1, block_k, D), lambda b, h, ki: (b, h, ki, 0)),
-        blk((1, 1, Tq, D), lambda b, h, ki: (b, h, 0, 0)),
+        blk((1, 1, block_k, D), lambda b, h, ki, i: (b, h, ki, 0)),
+        blk((1, 1, block_k, D), lambda b, h, ki, i: (b, h, ki, 0)),
+        blk((1, 1, block_q, D), lambda b, h, ki, i: (b, h, qi_(i, ki), 0)),
     ]
     dkv_args = (k, v, q)
-    if bias is not None:
-        dkv_specs.append(blk((1, 1, Tk), lambda b, h, ki: (b, 0, 0)))
+    if has_bias:
+        dkv_specs.append(blk((1, 1, block_k), lambda b, h, ki, i: (b, 0, ki)))
         dkv_args += (bias,)
     dkv_specs += [
-        blk((1, 1, Tq, D), lambda b, h, ki: (b, h, 0, 0)),
-        blk((1, 1, Tq, 1), lambda b, h, ki: (b, h, 0, 0)),
-        blk((1, 1, Tq, 1), lambda b, h, ki: (b, h, 0, 0)),
+        blk((1, 1, block_q, D), lambda b, h, ki, i: (b, h, qi_(i, ki), 0)),
+        blk((1, 1, 1, block_q), lambda b, h, ki, i: (b, h, 0, qi_(i, ki))),
+        blk((1, 1, 1, block_q), lambda b, h, ki, i: (b, h, 0, qi_(i, ki))),
     ]
     dk, dv = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          block_q=block_q, has_bias=bias is not None,
-                          window=window),
-        grid=(B, H, Tk // block_k),
+                          block_q=block_q, block_k=block_k, nq=nq,
+                          has_bias=has_bias, window=window),
+        grid=(B, H, nk, nq),
         in_specs=dkv_specs,
         out_specs=[
-            blk((1, 1, block_k, D), lambda b, h, ki: (b, h, ki, 0)),
-            blk((1, 1, block_k, D), lambda b, h, ki: (b, h, ki, 0)),
+            blk((1, 1, block_k, D), lambda b, h, ki, i: (b, h, ki, 0)),
+            blk((1, 1, block_k, D), lambda b, h, ki, i: (b, h, ki, 0)),
         ],
         out_shape=[
             jax.ShapeDtypeStruct((B, H, Tk, D), k.dtype),
             jax.ShapeDtypeStruct((B, H, Tk, D), v.dtype),
         ],
+        scratch_shapes=_scratch((block_k, D), (block_k, D)),
         interpret=interpret,
+        **_compiler_params(interpret),
     )(*dkv_args, g, lse, delta)
     return dq, dk, dv
 
@@ -401,10 +486,13 @@ def flash_attention(q, k, v, mask=None, causal: bool = False,
       block_q, block_k: kernel tile sizes (clamped to the padded seq len).
         Default None = the best point of the committed on-chip block
         sweep (``bench_artifacts/flash_sweep.json``) when one exists,
-        else 512x512.  Measured speedups vs XLA dense attention live in
-        ``bench_artifacts/flash_attention.json`` (produced by ``bench.py``
-        on the real chip).
-      interpret: force Pallas interpreter mode; default auto (on ≠ TPU).
+        else 512x512.  (That sweep and ``bench_artifacts/
+        flash_attention.json`` predate the block-streaming kernel and the
+        attached chip; the tile has not been re-tuned — ROADMAP C10.)
+      interpret: run under the Pallas interpreter.  Default: only where
+        the default backend is not a TPU (CPU tests); on a TPU the kernel
+        is compiled by Mosaic or the call fails — there is no dense
+        fallback.
     """
     B, Tq, H, D = q.shape
     Tk = k.shape[1]
@@ -463,9 +551,13 @@ def _pick_block(T: int, requested: int) -> tuple[int, int]:
     Padding straight to a multiple of a large block nearly doubles compute
     for lengths just past a block boundary (T=520 → 1024 with 512-blocks);
     instead pad T to the next 128 multiple and take the largest block ≤
-    ``requested`` that divides it.
+    ``requested`` that divides it.  Every block this yields is a multiple
+    of 128, so the bias and logsumexp rows tile the lane axis exactly
+    (a short ragged T=100 pads to one 128 block).  Only an explicit
+    ``requested < 128`` gets a smaller, 8-aligned tile — what the
+    interpret-mode tests use to walk several blocks of a tiny sequence.
     """
-    if T <= 128 or requested <= 128:
+    if requested < 128:
         block = min(requested, _round_up(T, 8))
         return block, _round_up(T, block)
     T_p = _round_up(T, 128)

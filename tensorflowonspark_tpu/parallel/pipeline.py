@@ -411,13 +411,6 @@ def pipeline_value_and_grad(mesh, stage_fn, head_fn, stage_params,
 
         def fit(g, allowed):
             have = compat.vma_of(g)
-            if not have and not compat.has_vma():
-                # pre-vma jax cannot answer "which axes does g still vary
-                # on"; statically it is the schedule's vary_axes minus pp
-                # — every caller either masked-psum'd pp to invariance
-                # already or allows it outright — so data/declared axes
-                # get the intended global mean and size-1 axes are no-ops
-                have = frozenset(a for a in vary_axes if a != axis_name)
             extra = tuple(a for a in have if a not in allowed)
             for a in extra:
                 # data axes and declared activation axes average away

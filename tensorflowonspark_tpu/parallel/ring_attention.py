@@ -55,8 +55,7 @@ def ring_attention(q, k, v, mask=None, axis_name: str = "sp",
     # The accumulators become axis-varying inside the loop (they mix with
     # this device's q/k blocks), so their init must carry q's varying axes
     # (sp plus any sharded batch axes) for shard_map's varying-axes check.
-    # empty on jax versions without the vma system (compat.vma_of) and
-    # outside shard_map (single-device testing)
+    # empty outside shard_map (single-device testing)
     vma = tuple(compat.vma_of(q))
 
     def _vary(x):

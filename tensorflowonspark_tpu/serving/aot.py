@@ -14,8 +14,11 @@ a cold replica, a warm standby paying its bucket×group sweep, the
 ``scripts/tfos_warmcache.py`` pre-bake CLI — resolves the same site to a
 ``deserialize_and_load`` call: a cache READ, no tracing, no XLA.
 
-Keying: one file per (jax version, backend platform, device count,
-call-site id, caller context, argument avals) — the caller context is
+Keying: one file per (jax version, backend platform, the ids of the
+devices the executable runs on, call-site id, caller context, argument
+avals) — an entry is loaded onto exactly those devices
+(``execution_devices``), never spread over every device of the host, so
+a one-chip replica's executable stays on its chip.  The caller context is
 the batcher's config/mesh identity (``ContinuousBatcher`` passes its
 ``GPTConfig`` repr + batch/speculation knobs; a gang leader's cache adds
 the mesh axes), so two models or two shardings never collide.  A corrupt
@@ -25,7 +28,7 @@ either fails loudly or yields the byte-identical program).
 
 Opt-in: a batcher built without ``aot_cache=`` uses plain ``jax.jit``
 exactly as before.  ``ServingCluster.run(aot_cache=...)`` arms the whole
-tier (default directory ``<working_dir>/jax_cache_aot``).
+tier (directory: ``util.aot_cache_dir()``, beside the persistent XLA cache).
 """
 
 from __future__ import annotations
@@ -74,7 +77,7 @@ class AOTExecutableCache:
         return _AOTCallable(self, site, fn, tuple(donate_argnums))
 
     # -- internals ---------------------------------------------------------
-    def _entry_path(self, site, args) -> str:
+    def _entry_path(self, site, args, devices) -> str:
         import jax
         import numpy as np
 
@@ -83,14 +86,15 @@ class AOTExecutableCache:
                   str(getattr(x, "dtype", type(x).__name__)))
                  for x in leaves]
         key = repr((_FORMAT, jax.__version__, jax.default_backend(),
-                    jax.device_count(), repr(self.extra_key), repr(site),
-                    str(treedef), avals))
+                    [d.id for d in devices], repr(self.extra_key),
+                    repr(site), str(treedef), avals))
         digest = hashlib.sha256(key.encode()).hexdigest()
         return os.path.join(self.cache_dir, f"v{_FORMAT}-{digest}.aotx")
 
-    def _load(self, path: str):
-        """Deserialize one entry, or None (counting the error) when the
-        file is missing/corrupt/incompatible — the caller compiles."""
+    def _load(self, path: str, devices):
+        """Deserialize one entry onto ``devices``, or None (counting the
+        error) when the file is missing/corrupt/incompatible — the
+        caller compiles."""
         if not os.path.exists(path):
             return None
         from jax.experimental.serialize_executable import \
@@ -99,7 +103,8 @@ class AOTExecutableCache:
         try:
             with open(path, "rb") as f:
                 payload, in_tree, out_tree = pickle.load(f)
-            compiled = deserialize_and_load(payload, in_tree, out_tree)
+            compiled = deserialize_and_load(payload, in_tree, out_tree,
+                                            execution_devices=devices)
             self.loads += 1
             return compiled
         # tfos: ignore[broad-except] — a corrupt or cross-version entry
@@ -111,7 +116,7 @@ class AOTExecutableCache:
                            os.path.basename(path), exc_info=True)
             return None
 
-    def _store(self, path: str, compiled) -> None:
+    def _store(self, path: str, compiled, devices) -> None:
         """Serialize + verify + atomic-rename; a failed write only costs
         the next process a compile.  The verify round-trip
         (``deserialize_and_load`` on the fresh payload) guarantees no
@@ -123,7 +128,8 @@ class AOTExecutableCache:
 
         try:
             payload, in_tree, out_tree = serialize(compiled)
-            deserialize_and_load(payload, in_tree, out_tree)
+            deserialize_and_load(payload, in_tree, out_tree,
+                                 execution_devices=devices)
             fd, tmp = tempfile.mkstemp(dir=self.cache_dir, suffix=".tmp")
             try:
                 with os.fdopen(fd, "wb") as f:
@@ -140,6 +146,24 @@ class AOTExecutableCache:
             self.errors += 1
             logger.warning("AOT cache write for %s failed",
                            os.path.basename(path), exc_info=True)
+
+
+def _execution_devices(args) -> list:
+    """The devices a jitted call on ``args`` runs on, in device-assignment
+    order: those of the widest-sharded argument (a gang's mesh), else the
+    process's first device — what ``jax.jit`` itself would pick."""
+    import jax
+
+    widest = None
+    for leaf in jax.tree_util.tree_leaves(args):
+        sharding = getattr(leaf, "sharding", None)
+        if sharding is not None and (
+                widest is None
+                or len(sharding.device_set) > len(widest.device_set)):
+            widest = sharding
+    if widest is None:
+        return [jax.local_devices()[0]]
+    return list(widest._device_assignment)
 
 
 class _AOTCallable:
@@ -168,8 +192,9 @@ class _AOTCallable:
     def _resolve(self, args):
         import jax
 
-        path = self.cache._entry_path(self.site, args)
-        compiled = self.cache._load(path)
+        devices = _execution_devices(args)
+        path = self.cache._entry_path(self.site, args, devices)
+        compiled = self.cache._load(path, devices)
         if compiled is None:
             from jax.experimental.compilation_cache.compilation_cache import \
                 reset_cache
@@ -192,6 +217,6 @@ class _AOTCallable:
                 jax.config.update("jax_enable_compilation_cache", prev)
                 reset_cache()
             self.cache.compiles += 1
-            self.cache._store(path, compiled)
+            self.cache._store(path, compiled, devices)
         self._compiled = compiled
         return compiled

@@ -405,7 +405,7 @@ class ServingCluster:
             drain_timeout: float = 60.0, mesh: dict | None = None,
             gang_size: int | None = None, shard_params=None,
             warm_standbys: int = 0, standby_clone: bool = True,
-            compile_cache=None, aot_cache=None, draft_model=None,
+            aot_cache=None, draft_model=None,
             disagg: dict | None = None,
             model: tuple | None = None, registry=None,
             **cluster_kwargs) -> "ServingCluster":
@@ -477,17 +477,18 @@ class ServingCluster:
         checkpoint-restore fallback).  ``replace_failed`` spawns a
         replacement for CRASH/HANG deaths too (cold when no pool), so
         the tier never shrinks by failure; with a warm pool, crash heals
-        promote regardless.  ``compile_cache`` overrides the
-        fleet-shared persistent XLA compilation cache directory (default
-        ``<working_dir>/jax_cache``; ``False`` disables it).
+        promote regardless.  Every worker keeps XLA's persistent
+        compilation cache at ``util.compilation_cache_dir()`` —
+        ``JAX_COMPILATION_CACHE_DIR`` where set, else the fixed
+        ``<checkout>/.jax_cache``.
 
-        ``aot_cache`` arms the tier's AOT serialized-executable cache
-        (docs/performance.md "Decode speed"): every replica, gang
+        ``aot_cache=True`` arms the tier's AOT serialized-executable
+        cache (docs/performance.md "Decode speed"): every replica, gang
         leader, and warm standby resolves its serve-step executables by
-        ``deserialize_and_load`` from ``<working_dir>/jax_cache_aot``
-        (``True``; a string overrides the directory — point it at a
-        ``scripts/tfos_warmcache.py`` pre-baked dir for compile-free
-        cold starts and standby warm-ups).
+        ``deserialize_and_load`` from the ``aot/`` sub-directory of that
+        same cache (``util.aot_cache_dir()``; pre-bake it with
+        ``scripts/tfos_warmcache.py`` for compile-free cold starts and
+        standby warm-ups).
 
         ``draft_model`` arms DRAFT-MODEL SPECULATIVE DECODING on every
         decode-capable replica: a picklable ``builder(args) -> (cfg,
@@ -541,10 +542,8 @@ class ServingCluster:
             raise ValueError(
                 "no model builder: pass model_builder=, or registry= + "
                 "model= naming a registered version")
-        if compile_cache is not None:
-            args["serve_compile_cache"] = compile_cache
-        if aot_cache is not None:
-            args["serve_aot_cache"] = aot_cache
+        if aot_cache:
+            args["serve_aot_cache"] = True
         if draft_model is not None:
             if isinstance(draft_model, tuple):
                 if registry is None:

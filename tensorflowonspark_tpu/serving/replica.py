@@ -59,7 +59,6 @@ tier:
 from __future__ import annotations
 
 import logging
-import os
 import queue as _queue
 import threading
 import time as _time
@@ -74,43 +73,23 @@ from tensorflowonspark_tpu.serving.scheduler import (REQUEST_QUEUE,
 logger = logging.getLogger(__name__)
 
 
-def enable_serving_compile_cache(args, ctx) -> None:
-    """Persistent XLA compilation cache shared across the serving fleet.
-
-    Every replica, gang leader, and warm standby of one tier points at
-    the same on-disk cache (default: ``<working_dir>/jax_cache``), so the
-    first process to compile a serve-step executable pays for the whole
-    fleet — a cold spawn or standby warm-up after that is a cache read,
-    not a recompile.  ``args["serve_compile_cache"]``: ``False`` disables,
-    a string overrides the directory (e.g. a cross-job persistent path)."""
-    spec = args.get("serve_compile_cache")
-    if spec is False:
-        return
-    from tensorflowonspark_tpu import util as _util
-
-    _util.enable_compilation_cache(
-        spec if isinstance(spec, str)
-        else os.path.join(ctx.working_dir, "jax_cache"))
-
-
-def serving_aot_cache(args, ctx):
+def serving_aot_cache(args):
     """The tier's AOT serialized-executable cache (``serving/aot.py``),
-    or None when not armed.  ``args["serve_aot_cache"]``: truthy enables
-    (``ServingCluster.run(aot_cache=...)``), a string overrides the
-    directory (default ``<working_dir>/jax_cache_aot`` — shared by every
+    or None when not armed (``args["serve_aot_cache"]`` truthy —
+    ``ServingCluster.run(aot_cache=True)``).  It lives beside the
+    persistent XLA cache (``util.aot_cache_dir``: one fixed directory,
+    placeable with ``JAX_COMPILATION_CACHE_DIR``) — shared by every
     replica, gang leader, standby, and the ``tfos_warmcache.py``
-    pre-bake CLI of one tier).  The gang's mesh spec is mixed into every
+    pre-bake CLI of every run.  The gang's mesh spec is mixed into every
     entry key so differently-sharded tiers never collide in one
     directory."""
-    spec = args.get("serve_aot_cache")
-    if not spec:
+    if not args.get("serve_aot_cache"):
         return None
+    from tensorflowonspark_tpu import util as _util
     from tensorflowonspark_tpu.serving.aot import AOTExecutableCache
 
-    return AOTExecutableCache(
-        spec if isinstance(spec, str)
-        else os.path.join(ctx.working_dir, "jax_cache_aot"),
-        extra_key=repr(args.get("serve_mesh")))
+    return AOTExecutableCache(_util.aot_cache_dir(),
+                              extra_key=repr(args.get("serve_mesh")))
 
 
 def build_draft_model(args):
@@ -372,7 +351,7 @@ def serve_replica(args, ctx) -> None:
     driver sends ``EndOfFeed``."""
     # jax (and the model stack) import inside the worker process only —
     # the harness contract is that no jax import happens before map_fun
-    enable_serving_compile_cache(args, ctx)
+    # (node.run already placed the persistent compile cache via the env)
     from tensorflowonspark_tpu.models.serving import ContinuousBatcher
 
     cfg, params = args["serve_model_builder"](args)
@@ -380,7 +359,7 @@ def serve_replica(args, ctx) -> None:
         cfg, params,
         max_batch=int(args.get("serve_max_batch", 4)),
         eos_id=args.get("serve_eos_id"),
-        aot_cache=serving_aot_cache(args, ctx),
+        aot_cache=serving_aot_cache(args),
         **serving_batcher_kwargs(args))
     arm_draft(batcher, args)
     run_serve_loop(args, ctx, batcher, role=args.get("serve_role"))

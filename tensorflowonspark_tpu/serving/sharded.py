@@ -72,6 +72,7 @@ import math
 import queue as _queue
 import time as _time
 
+from tensorflowonspark_tpu import device_info
 from tensorflowonspark_tpu import metrics as _metrics
 from tensorflowonspark_tpu.marker import EndOfFeed, Marker
 from tensorflowonspark_tpu.preemption import PreemptionGuard
@@ -88,6 +89,12 @@ class GangShardLost(RuntimeError):
     """A gang member stopped answering the step barrier: the sharded
     replica can no longer run its mesh program at full width, so the
     leader crashes loudly and the driver fails the WHOLE gang over."""
+
+
+#: ``parallel.mesh.AXES``, restated: the driver and the gang's shard
+#: members validate a spec without importing the ``parallel`` package,
+#: which imports jax (tests/test_chip_ownership.py pins the two equal)
+AXES = ("pp", "dp", "fsdp", "ep", "sp", "tp")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -108,8 +115,6 @@ class GangSpec:
     gang_size: int | None = None
 
     def __post_init__(self):
-        from tensorflowonspark_tpu.parallel.mesh import AXES
-
         axes = dict(self.axes)
         unknown = set(axes) - set(AXES)
         if unknown:
@@ -319,10 +324,8 @@ def serve_sharded_replica(args, ctx) -> None:
         _member_loop(args, ctx, spec, leader_eid, rank)
         return
     # leader: jax/model imports stay inside the worker process
-    from tensorflowonspark_tpu.serving.replica import (
-        arm_draft, enable_serving_compile_cache, serving_aot_cache)
-
-    enable_serving_compile_cache(args, ctx)
+    from tensorflowonspark_tpu.serving.replica import (arm_draft,
+                                                       serving_aot_cache)
     from tensorflowonspark_tpu.models.serving import ContinuousBatcher
 
     mesh = build_gang_mesh(spec)
@@ -350,7 +353,7 @@ def serve_sharded_replica(args, ctx) -> None:
             cfg, params,
             max_batch=int(args.get("serve_max_batch", 4)),
             eos_id=args.get("serve_eos_id"),
-            aot_cache=serving_aot_cache(args, ctx),
+            aot_cache=serving_aot_cache(args),
             **serving_batcher_kwargs(args))
         # inside the mesh context: the draft's params stay REPLICATED
         # (a tiny model has nothing worth sharding) and its propose
@@ -379,6 +382,11 @@ def _member_loop(args, ctx, spec: GangSpec, leader_eid: int,
     if mgr is None:
         raise RuntimeError("the serving loop needs the node queue server "
                            "(InputMode.SPARK)")
+    # on one host the leader's process owns every chip: a member that
+    # touched jax would take the libtpu lock from under it (checked on
+    # the way in and on the way out)
+    who = f"gang {leader_eid} member rank {rank}"
+    device_info.assert_off_accelerator(who)
     reg = _metrics.get_registry()
     m_acks = reg.counter("tfos_gang_member_acks_total",
                          "Step barriers acked by this gang member.")
@@ -427,6 +435,7 @@ def _member_loop(args, ctx, spec: GangSpec, leader_eid: int,
                 continue
             logger.warning("gang member %d: ignoring unexpected item %r",
                            ctx.executor_id, type(item))
+    device_info.assert_off_accelerator(who)
     logger.info("gang %d member rank %d stopped%s", leader_eid, rank,
                 " (preempted)" if guard.preempted else "")
 

@@ -11,9 +11,8 @@ unregistered, so a heal becomes *promote + load weights* instead of
 Worker side (:func:`serve_standby`, the standby map_fun):
 
 - boots like a serving replica — process up, mesh built for sharded
-  gangs, the fleet-shared persistent compilation cache enabled
-  (:func:`~tensorflowonspark_tpu.serving.replica.
-  enable_serving_compile_cache`), model constructed, and the serve-step
+  gangs, the persistent compilation cache every worker shares
+  (``util.compilation_cache_dir``), model constructed, and the serve-step
   dispatches COMPILED via a throwaway warm-up decode — then **unloads
   the parameters** (:meth:`~tensorflowonspark_tpu.models.serving.
   ContinuousBatcher.unload_params`) and idles in heartbeat phase
@@ -103,14 +102,13 @@ def serve_standby(args, ctx) -> None:
 
 def _standby_leader(args, ctx, spec) -> None:
     from tensorflowonspark_tpu.serving.replica import (
-        arm_draft, enable_serving_compile_cache, run_serve_loop,
-        serving_aot_cache, serving_batcher_kwargs)
+        arm_draft, run_serve_loop, serving_aot_cache,
+        serving_batcher_kwargs)
 
     mgr = ctx.mgr
     if mgr is None:
         raise RuntimeError("the standby loop needs the node queue server "
                            "(InputMode.SPARK)")
-    enable_serving_compile_cache(args, ctx)
     ctx.report_step(0, phase=STANDBY_WARMUP_PHASE)
     from tensorflowonspark_tpu.models.serving import ContinuousBatcher
 
@@ -140,7 +138,7 @@ def _standby_leader(args, ctx, spec) -> None:
             cfg, params,
             max_batch=int(args.get("serve_max_batch", 4)),
             eos_id=args.get("serve_eos_id"),
-            aot_cache=serving_aot_cache(args, ctx),
+            aot_cache=serving_aot_cache(args),
             **serving_batcher_kwargs(args))
         # arm the tier's draft BEFORE the warm-up sweep, so the draft
         # propose + fused verify executables are part of what the

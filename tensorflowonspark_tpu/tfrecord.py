@@ -16,6 +16,7 @@ The format is byte-identical to TensorFlow's, so files written here load in
 from __future__ import annotations
 
 import ctypes
+import hashlib
 import logging
 import os
 import struct
@@ -66,17 +67,24 @@ def _trusted_dir(target_dir: str, private: bool) -> bool:
 
 
 def _build_library() -> str | None:
-    """Compile native/tfrecord.cc → libtfrecord.so (cached beside the source,
-    falling back to a per-user cache dir when the package is read-only)."""
+    """Compile the tracked ``native/tfrecord.cc`` and nothing else.
+
+    The binary is cached as ``libtfrecord-<sha256 of the source>.so`` —
+    beside the source, falling back to a per-user cache dir when the
+    package is read-only — so a cached build is reused exactly when it
+    was built from this source: a stale or foreign ``.so`` (a copied
+    checkout does not preserve mtimes) can never be picked up."""
     try:
-        source_mtime = os.path.getmtime(_SOURCE)
+        with open(_SOURCE, "rb") as f:
+            digest = hashlib.sha256(f.read()).hexdigest()[:16]
     except OSError:
-        source_mtime = None  # source not shipped: accept any valid prebuilt
+        return None  # no source shipped: nothing to build, nothing to trust
+    so_name = f"libtfrecord-{digest}.so"
     user_cache = os.path.join(tempfile.gettempdir(),
                               f"tfos_tpu_native_{os.getuid()}")
     for target_dir in (_NATIVE_DIR, user_cache):
         private = target_dir == user_cache
-        so_path = os.path.join(target_dir, "libtfrecord.so")
+        so_path = os.path.join(target_dir, so_name)
         try:
             os.makedirs(target_dir, mode=0o700, exist_ok=True)
         except OSError:
@@ -84,12 +92,8 @@ def _build_library() -> str | None:
         if not _trusted_dir(target_dir, private):
             logger.debug("cache dir %s not trusted; skipping", target_dir)
             continue
-        if (os.path.exists(so_path) and _trusted_so(so_path)
-                and (source_mtime is None
-                     or os.path.getmtime(so_path) >= source_mtime)):
+        if os.path.exists(so_path) and _trusted_so(so_path):
             return so_path
-        if source_mtime is None:
-            continue  # nothing to build from
         tmp = None
         try:
             # unpredictable temp name (mkstemp) → no symlink-clobber window
@@ -113,6 +117,15 @@ def _build_library() -> str | None:
                 except OSError:
                     pass
     return None
+
+
+def codec() -> str:
+    """Which codec this process uses: ``"native:<library file>"`` (built
+    from the tracked source) or ``"python"`` (no compiler on the host) —
+    the pure-Python path is never taken silently (``chip_smoke.py`` prints
+    this)."""
+    lib = _native()
+    return "python" if lib is None else f"native:{os.path.basename(lib._name)}"
 
 
 def _native():

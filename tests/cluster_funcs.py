@@ -569,8 +569,8 @@ def fn_distributed_pipeline_train(args, ctx):
 
 def fn_write_cache_env(args, ctx):
     """Record the worker-side compile-cache env contract (node.run must
-    export the JAX cache vars before the user fn, honoring the TFOS_*
-    knobs)."""
+    export the JAX cache vars before the user fn: the directory the
+    environment names, else the one fixed in-checkout path)."""
     path = os.path.join(ctx.working_dir, f"cacheenv.{ctx.executor_id}")
     with open(path, "w") as f:
         f.write(os.environ.get("JAX_COMPILATION_CACHE_DIR", "MISSING") + ":"
@@ -662,6 +662,19 @@ def serving_tiny_gpt_builder(args):
     params = GPT(cfg).init(jax.random.key(int(args.get("seed", 0))),
                            jnp.ones((1, 4), jnp.int32))["params"]
     return cfg, params
+
+
+def serving_cache_probe_builder(args):
+    """``serving_tiny_gpt_builder`` that also records where this replica
+    process keeps its compile caches (``args["cache_report"]``)."""
+    import jax
+
+    from tensorflowonspark_tpu import util
+
+    with open(args["cache_report"], "w") as f:
+        f.write(f"{jax.config.jax_compilation_cache_dir}\n"
+                f"{util.aot_cache_dir()}")
+    return serving_tiny_gpt_builder(args)
 
 
 def shm_crash_server(pipe):

@@ -1,0 +1,103 @@
+"""Real-size compiles for a *described* TPU v5e — no chip attached.
+
+The TPU compiler is installed in the sandbox and compiles for a topology
+that is described, not attached (``on-chip-measurement`` guide §2.3): what
+Mosaic/XLA would refuse on the chip — an unaligned lane slice, a kernel
+over the 16 MiB scoped fast-memory limit — it refuses here, at no chip
+time.  These are compiles only: nothing runs, so they say nothing about
+results or speed.
+
+This is the ONE test file that describes a topology.  The call loads
+libtpu, which one process at a time may hold, so it lives in a
+module-scoped fixture (never at import/collection time) and every compile
+happens in the test's own process.
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from tensorflowonspark_tpu.ops import flash_attention
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler on this host
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """A one-chip sharding on the described v5e, with the persistent
+    compilation cache off for the module: a compile for a described
+    device is written to the cache but cannot be read back without a
+    chip, so the next run would warn and recompile anyway."""
+    from jax.experimental.compilation_cache.compilation_cache import \
+        reset_cache
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    reset_cache()
+    try:
+        yield SingleDeviceSharding(topo.devices[0])
+    finally:
+        jax.config.update("jax_enable_compilation_cache", prev)
+        reset_cache()
+
+
+def _compile_flash(one_chip, B, T, H, D, *, backward, causal=False,
+                   masked=False):
+    shape = jax.ShapeDtypeStruct((B, T, H, D), jnp.bfloat16,
+                                 sharding=one_chip)
+    args = [shape, shape, shape]
+    if masked:
+        args.append(jax.ShapeDtypeStruct((B, T), jnp.bool_,
+                                         sharding=one_chip))
+
+    def fwd(q, k, v, mask=None):
+        # interpret=False: the default would pick the interpreter here,
+        # because the default backend of the test process is the CPU
+        return flash_attention(q, k, v, mask=mask, causal=causal,
+                               interpret=False)
+
+    def loss(q, k, v, mask=None):
+        return fwd(q, k, v, mask).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else fwd
+    return jax.jit(fn).lower(*args).compile()
+
+
+#: the widths the repo's own models use (ISSUE 21 item 6)
+_SHAPES = {
+    "gpt350m_T2048_D64": dict(B=8, T=2048, H=16, D=64, causal=True),
+    "gpt2_T1024_D64": dict(B=8, T=1024, H=12, D=64, causal=True),
+    "bert_T384_D64_mask": dict(B=24, T=384, H=12, D=64, masked=True),
+    "ragged_T100_D64": dict(B=4, T=100, H=12, D=64),
+    "llama_T4096_D128": dict(B=1, T=4096, H=32, D=128, causal=True),
+}
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
+@pytest.mark.parametrize("name", sorted(_SHAPES))
+def test_flash_attention_compiles_for_v5e(one_chip, name, backward):
+    compiled = _compile_flash(one_chip, backward=backward, **_SHAPES[name])
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("T,H,backward", [
+    (8192, 32, True), (32768, 8, False), (32768, 8, True),
+], ids=["bwd_T8192_D128", "fwd_T32768_D128", "bwd_T32768_D128"])
+def test_flash_attention_long_context_fits_scoped_vmem(one_chip, T, H,
+                                                       backward):
+    """Shapes the whole-sequence kernel was refused at (scoped allocation
+    32.75M > 16M): K/V and Q/dO now stream by block."""
+    compiled = _compile_flash(one_chip, B=1, T=T, H=H, D=128, causal=True,
+                              backward=backward)
+    assert "tpu_custom_call" in compiled.as_text()

@@ -219,21 +219,42 @@ def test_run_with_recovery_gives_up_after_max_restarts(tmp_path):
             reservation_timeout=60, shutdown_timeout=60)
 
 
-def test_worker_compile_cache_env_contract(tmp_path, monkeypatch):
-    """node.run exports the persistent-compile-cache env (honoring the
-    TFOS_COMPILATION_CACHE / TFOS_CACHE_MIN_COMPILE_SECS knobs) before
-    the user's map_fun — the relaunch-reuses-compiles contract."""
-    # a pre-set JAX_* env would win (by design); test from a clean slate
-    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+def test_worker_compile_cache_follows_the_environment(tmp_path, monkeypatch):
+    """Where JAX_COMPILATION_CACHE_DIR is set, every spawned worker keeps
+    its compile cache THERE (node.run exports it untouched, with the
+    TFOS_CACHE_MIN_COMPILE_SECS threshold, before the user's map_fun) —
+    the relaunch-reuses-compiles contract, placeable from outside."""
+    placed = str(tmp_path / "placed")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
     monkeypatch.delenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
                        raising=False)
     cluster = _run(funcs.fn_write_cache_env, 2, tmp_path,
-                   worker_env={"TFOS_COMPILATION_CACHE": "/tmp/tfos_ct_cache",
-                               "TFOS_CACHE_MIN_COMPILE_SECS": "0.7"})
+                   worker_env={"TFOS_CACHE_MIN_COMPILE_SECS": "0.7"})
     cluster.shutdown(timeout=60)
     for i in range(2):
         with open(os.path.join(str(tmp_path), f"cacheenv.{i}")) as f:
-            assert f.read() == "/tmp/tfos_ct_cache:0.7"
+            assert f.read() == f"{placed}:0.7"
+
+
+def test_worker_compile_cache_default_never_moves(tmp_path, monkeypatch):
+    """Unset, the cache is ONE fixed directory inside the checkout — the
+    same for two runs with different working_dirs (the directory is part
+    of XLA's cache key: a cache under mkdtemp can never hit)."""
+    from tensorflowonspark_tpu import util
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert util.compilation_cache_dir() == os.path.join(repo, ".jax_cache")
+    assert util.aot_cache_dir() == os.path.join(repo, ".jax_cache", "aot")
+    seen = set()
+    for name in ("run_a", "run_b"):
+        wd = tmp_path / name
+        wd.mkdir()
+        cluster = _run(funcs.fn_write_cache_env, 1, wd)
+        cluster.shutdown(timeout=60)
+        with open(os.path.join(str(wd), "cacheenv.0")) as f:
+            seen.add(f.read().rsplit(":", 1)[0])
+    assert seen == {os.path.join(repo, ".jax_cache")}
 
 
 def test_raise_worker_errors_aggregates_all_crashes(tmp_path):

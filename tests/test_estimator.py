@@ -263,18 +263,32 @@ def test_empty_input_fn_raises(tmp_path):
 
 
 @_isolated
-def test_enable_compilation_cache(tmp_path):
+def test_enable_compilation_cache(tmp_path, monkeypatch):
+    """One rule for the cache's place: the environment's directory where
+    it names one — then nothing in code sets another — else the fixed
+    in-checkout path."""
     import jax
 
-    from tensorflowonspark_tpu.util import enable_compilation_cache
+    from tensorflowonspark_tpu import util
 
     old = jax.config.jax_compilation_cache_dir
+    old_secs = jax.config.jax_persistent_cache_min_compile_time_secs
     try:
-        d = enable_compilation_cache(str(tmp_path / "cache"))
-        assert os.path.isdir(d)
-        assert jax.config.jax_compilation_cache_dir == d
+        placed = str(tmp_path / "cache")
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", placed)
+        assert util.enable_compilation_cache() == placed
+        assert os.path.isdir(placed)
+        assert jax.config.jax_compilation_cache_dir == placed
+        assert util.aot_cache_dir() == os.path.join(placed, "aot")
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        fixed = util.compilation_cache_dir()
+        assert fixed == os.path.join(
+            os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+            ".jax_cache")
     finally:
         jax.config.update("jax_compilation_cache_dir", old)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                          old_secs)
 
 
 @_isolated

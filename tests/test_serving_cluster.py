@@ -13,6 +13,7 @@ Two layers, mirroring the health tests' split:
   is ``-m slow``).
 """
 
+import os
 import queue as _queue
 import threading
 import time
@@ -1220,6 +1221,42 @@ def test_serving_cluster_end_to_end(tmp_path, worker_env):
         assert stats["e2e"]["p99_secs"] is not None
     finally:
         serving.shutdown(timeout=120)
+
+
+@pytest.mark.integration
+@pytest.mark.parametrize("placed", [True, False], ids=["env", "default"])
+def test_replica_compile_caches_have_one_place(tmp_path, worker_env,
+                                               monkeypatch, placed):
+    """A serve_replica boot keeps XLA's persistent cache AND the AOT
+    cache where JAX_COMPILATION_CACHE_DIR says; unset, at the one fixed
+    in-checkout path — never under the run's (moving) working_dir."""
+    from tests.cluster_funcs import serving_cache_probe_builder
+
+    from tensorflowonspark_tpu import util
+    from tensorflowonspark_tpu.serving import ServingCluster
+
+    if placed:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR",
+                           str(tmp_path / "placed"))
+    else:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    want = util.compilation_cache_dir()
+    reports = set()
+    for name in ("run_a", "run_b"):
+        wd = tmp_path / name
+        wd.mkdir()
+        report = wd / "cache_report"
+        serving = ServingCluster.run(
+            serving_cache_probe_builder, 1, max_batch=2,
+            replica_args={"cache_report": str(report)},
+            worker_env=worker_env, working_dir=str(wd),
+            reservation_timeout=120)
+        serving.shutdown(timeout=120)
+        reports.add(report.read_text())
+        assert not (wd / "jax_cache").exists()
+        assert not (wd / "jax_cache_aot").exists()
+    assert reports == {f"{want}\n{os.path.join(want, 'aot')}"}
+    assert str(tmp_path / "run_a") not in want
 
 
 @pytest.mark.integration

@@ -167,6 +167,35 @@ def test_aot_cache_hit_miss_corrupt(tmp_path):
     use((1, 0))                                 # ...which re-stored it
 
 
+@pytest.mark.parametrize("dev", [0, 3], ids=["device0", "device3"])
+def test_aot_entry_runs_on_its_own_device_of_eight(tmp_path, dev):
+    """An entry compiled for ONE device loads and runs on exactly that
+    device while eight exist (jax 0.9 ``deserialize_and_load`` defaults
+    to every device of the backend: 'expected 8 shards, got [1]'), and
+    entries for different devices never share a file."""
+    devices = jax.devices()
+    assert len(devices) == 8
+    x = jax.device_put(jnp.arange(8, dtype=jnp.float32), devices[dev])
+
+    def use(arg):
+        c = AOTExecutableCache(str(tmp_path))
+        out = c.wrap("site", lambda a: a * 3)(arg)
+        np.testing.assert_allclose(np.asarray(out), np.arange(8) * 3)
+        return c, out
+
+    c, _ = use(x)
+    assert (c.loads, c.compiles, c.errors) == (0, 1, 0)
+    c, out = use(x)
+    assert (c.loads, c.compiles, c.errors) == (1, 0, 0)
+    assert out.sharding.device_set == {devices[dev]}
+    # the same site on another device is a different entry: a miss,
+    # not a load of this device's executable
+    other = jax.device_put(x, devices[(dev + 1) % 8])
+    c, out = use(other)
+    assert (c.loads, c.compiles) == (0, 1)
+    assert out.sharding.device_set == {devices[(dev + 1) % 8]}
+
+
 def test_batcher_aot_identical_workload_compiles_zero(tmp_path):
     """A second batcher process-equivalent (fresh handles, same cache
     dir) over the identical workload resolves every serve-step site from

@@ -7,6 +7,8 @@ byte-compatibility is cross-checked against TensorFlow where available
 (test-only dependency — the package itself never imports TF).
 """
 
+import os
+
 import numpy as np
 import pytest
 
@@ -458,3 +460,35 @@ def test_native_decoder_accepts_bytearray_and_last_value_wins():
     _write_len_field(ex, 1, bytes(fmap))
     assert decode_example(bytes(ex)) == decode_example_py(bytes(ex)) \
         == {"k": ("float", [2.0])}
+
+
+def test_native_codec_is_built_from_the_tracked_source_only(tmp_path,
+                                                            monkeypatch):
+    """The cached binary is keyed on the source's CONTENT: a stale or
+    foreign ``libtfrecord.so`` lying beside the source (``*.so`` is
+    git-ignored, and a copied checkout does not preserve mtimes) is never
+    loaded, and ``codec()`` says which codec this process uses."""
+    import hashlib
+    import shutil
+
+    if shutil.which("g++") is None:
+        pytest.skip("no g++ on this host")
+    native = tmp_path / "native"
+    native.mkdir()
+    shutil.copy(tfrecord._SOURCE, native / "tfrecord.cc")
+    (native / "libtfrecord.so").write_bytes(b"not a library")  # stale plant
+    monkeypatch.setattr(tfrecord, "_NATIVE_DIR", str(native))
+    monkeypatch.setattr(tfrecord, "_SOURCE", str(native / "tfrecord.cc"))
+    digest = hashlib.sha256(
+        (native / "tfrecord.cc").read_bytes()).hexdigest()[:16]
+    built = tfrecord._build_library()
+    assert built == str(native / f"libtfrecord-{digest}.so")
+    assert tfrecord._build_library() == built          # cached: same file
+    # another source is another binary; the old one is not reused
+    with open(native / "tfrecord.cc", "a") as f:
+        f.write("\n// edited\n")
+    assert tfrecord._build_library() != built
+    # no source shipped: nothing to build and nothing to trust
+    os.remove(native / "tfrecord.cc")
+    assert tfrecord._build_library() is None
+    assert tfrecord.codec().startswith(("native:libtfrecord-", "python"))

@@ -30,6 +30,7 @@ import time
 import numpy as np
 
 from tensorflowonspark_tpu import metrics as _metrics
+from tensorflowonspark_tpu import observability as _obs
 from tensorflowonspark_tpu.marker import EndOfFeed, EndPartition, Marker
 
 logger = logging.getLogger(__name__)
@@ -87,8 +88,10 @@ class DataFeed:
                 continue
             wait_start = time.monotonic()
             try:
-                item = self.mgr.queue_get(self.qname_in,
-                                          timeout=max(0.1, deadline - time.monotonic()))
+                with _obs.span(_obs.FEED_WAIT):
+                    item = self.mgr.queue_get(
+                        self.qname_in,
+                        timeout=max(0.1, deadline - time.monotonic()))
             except (_queue.Empty, TimeoutError):
                 if batch:
                     break
@@ -141,10 +144,11 @@ class DataFeed:
         while True:
             wait_start = time.monotonic()
             try:
-                item = self.mgr.queue_get(
-                    self.qname_in,
-                    timeout=5.0 if deadline is None
-                    else max(0.1, deadline - time.monotonic()))
+                with _obs.span(_obs.FEED_WAIT):
+                    item = self.mgr.queue_get(
+                        self.qname_in,
+                        timeout=5.0 if deadline is None
+                        else max(0.1, deadline - time.monotonic()))
             except (_queue.Empty, TimeoutError):
                 if deadline is None:
                     self._m_wait.record(time.monotonic() - wait_start)

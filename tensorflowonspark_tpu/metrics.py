@@ -36,7 +36,7 @@ catalog (docs/observability.md) cannot drift into inconsistency.
 
 ``TFOS_NO_TELEMETRY=1`` turns the process registry into a no-op (every
 instrument swallows its updates) — the bench A/B switch for measuring
-the plane's own overhead (``scripts/bench_telemetry.py``).
+the plane's own overhead (docs/observability.md "Overhead").
 """
 
 from __future__ import annotations
@@ -152,6 +152,10 @@ class _BoundCounter:
     def inc(self, n: float = 1.0) -> None:
         with self._fam._lock:
             self._fam._vals[self._key] += n
+
+    def value(self) -> float:
+        with self._fam._lock:
+            return self._fam._vals.get(self._key, 0.0)
 
 
 class Gauge(_Metric):

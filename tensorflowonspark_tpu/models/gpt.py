@@ -217,11 +217,17 @@ class CausalSelfAttention(nn.Module):
             raise ValueError(
                 f"num_heads ({H}) must be divisible by num_kv_heads ({Hkv})")
         G = H // Hkv  # query heads per K/V head (1 = MHA, H = MQA)
-        q = _dense(H * D, (None, "tp"), cfg.dtype, "query")(x).reshape(B, T, H, D)
-        k = _dense(Hkv * D, (None, "tp"), cfg.dtype, "key")(x) \
-            .reshape(B, T, Hkv, D)
-        v = _dense(Hkv * D, (None, "tp"), cfg.dtype, "value")(x) \
-            .reshape(B, T, Hkv, D)
+        # named scopes (metadata only: the compiled code does not change)
+        # name what flax's per-module scopes leave unnamed INSIDE attention:
+        # qkv, kv_store, kv_gather, scores, context (docs/observability.md
+        # "Profiler spans")
+        with jax.named_scope("qkv"):
+            q = _dense(H * D, (None, "tp"), cfg.dtype, "query")(x) \
+                .reshape(B, T, H, D)
+            k = _dense(Hkv * D, (None, "tp"), cfg.dtype, "key")(x) \
+                .reshape(B, T, Hkv, D)
+            v = _dense(Hkv * D, (None, "tp"), cfg.dtype, "value")(x) \
+                .reshape(B, T, Hkv, D)
 
         per_row = cfg.per_row_positions and self.decode
         ci = self.variable(
@@ -242,19 +248,22 @@ class CausalSelfAttention(nn.Module):
             """``q [B,T,H,D]`` vs ``k/v [B,S,Hkv,D]``: query heads attend
             in groups of G per K/V head — the broadcast happens inside the
             einsum, so the repeated K/V never materialise."""
-            qg = q.reshape(B, T, Hkv, G, D).astype(jnp.float32)
-            s = jnp.einsum("btkgd,bskd->bkgts", qg,
-                           k_all.astype(jnp.float32)) * (D ** -0.5)
-            # mask: [T, S] shared, or [B, T, S] per-row (per_row_positions)
-            m = mask[None, None, None] if mask.ndim == 2 \
-                else mask[:, None, None]
-            s = jnp.where(m, s, -1e30)
-            p = nn.softmax(s, axis=-1)
+            with jax.named_scope("scores"):
+                qg = q.reshape(B, T, Hkv, G, D).astype(jnp.float32)
+                s = jnp.einsum("btkgd,bskd->bkgts", qg,
+                               k_all.astype(jnp.float32)) * (D ** -0.5)
+                # mask: [T, S] shared, or [B, T, S] per-row
+                # (per_row_positions)
+                m = mask[None, None, None] if mask.ndim == 2 \
+                    else mask[:, None, None]
+                s = jnp.where(m, s, -1e30)
+                p = nn.softmax(s, axis=-1)
             if not self.decode:
                 p = nn.Dropout(cfg.dropout_rate, deterministic=not train)(p)
-            ctx = jnp.einsum("bkgts,bskd->btkgd", p,
-                             v_all.astype(jnp.float32))
-            return ctx.reshape(B, T, H, D)
+            with jax.named_scope("context"):
+                ctx = jnp.einsum("bkgts,bskd->btkgd", p,
+                                 v_all.astype(jnp.float32))
+                return ctx.reshape(B, T, H, D)
 
         if self.decode:
             # Static-shape KV cache: [B, C, Hkv, D] per layer; `index` is
@@ -288,17 +297,22 @@ class CausalSelfAttention(nn.Module):
 
                 def store(ref, x):
                     Tw = x.shape[1]
-                    pos = idx[:, None] + jnp.arange(Tw)[None, :]  # [B, Tw]
-                    page = jnp.take_along_axis(
-                        cbt.value, jnp.clip(pos // pt, 0, npg - 1), axis=1)
-                    phys = jnp.where(pos < C, page * pt + pos % pt, P * pt)
-                    ref.value = ref.value.at[phys].set(
-                        x.astype(ref.value.dtype), mode="drop")
-                    pool = ref.value.reshape(P, pt, *ref.value.shape[1:])
-                    return pool[cbt.value].reshape(B, C,
-                                                   *ref.value.shape[1:])
+                    with jax.named_scope("kv_store"):
+                        pos = idx[:, None] + jnp.arange(Tw)[None, :]  # [B,Tw]
+                        page = jnp.take_along_axis(
+                            cbt.value, jnp.clip(pos // pt, 0, npg - 1),
+                            axis=1)
+                        phys = jnp.where(pos < C, page * pt + pos % pt,
+                                         P * pt)
+                        ref.value = ref.value.at[phys].set(
+                            x.astype(ref.value.dtype), mode="drop")
+                    with jax.named_scope("kv_gather"):
+                        pool = ref.value.reshape(P, pt,
+                                                 *ref.value.shape[1:])
+                        return pool[cbt.value].reshape(
+                            B, C, *ref.value.shape[1:])
             else:
-                def store(ref, x):
+                def dense_store(ref, x):
                     """Write positions idx..idx+T-1 (keeping only the last
                     C under rolling; slot indices stay unique so the
                     scatter is well-defined).  Per-row mode scatters each
@@ -320,6 +334,10 @@ class CausalSelfAttention(nn.Module):
                         slots = (idx + jnp.arange(Tw)) % C
                     ref.value = ref.value.at[:, slots].set(x)
                     return ref.value
+
+                def store(ref, x):
+                    with jax.named_scope("kv_store"):
+                        return dense_store(ref, x)
 
             if paged:
                 ck = self.variable("cache", "k", jnp.zeros,
@@ -478,7 +496,8 @@ class GPT(nn.Module):
                            nn.initializers.normal(0.02), cfg.emb_spec))
         if cfg.pos_encoding == "rope":
             # positions live in the attention rotations; no table at all
-            x = tok(input_ids)
+            with jax.named_scope("embed"):
+                x = tok(input_ids)
         else:
             if self.decode:
                 per_row = cfg.per_row_positions
@@ -497,7 +516,8 @@ class GPT(nn.Module):
                 nn.with_partitioning(nn.initializers.normal(0.02),
                                      (None, None)),
                 (cfg.max_position_embeddings, cfg.hidden_size))
-            x = tok(input_ids) + pos_emb[positions].astype(cfg.dtype)
+            with jax.named_scope("embed"):
+                x = tok(input_ids) + pos_emb[positions].astype(cfg.dtype)
         x = nn.Dropout(cfg.dropout_rate, deterministic=not train)(x)
         if cfg.scan_layers:
             block_cls = _ScanBlock
@@ -533,8 +553,9 @@ class GPT(nn.Module):
         x = self.hidden(input_ids, train=train)
         table = self.get_variable("params", "tok_emb")["embedding"]
         table = getattr(table, "value", table)  # unbox partitioned param
-        return jnp.einsum("bth,vh->btv", x.astype(jnp.float32),
-                          table.astype(jnp.float32))
+        with jax.named_scope("lm_head"):
+            return jnp.einsum("bth,vh->btv", x.astype(jnp.float32),
+                              table.astype(jnp.float32))
 
 
 def init_cache(cfg: GPTConfig, params, batch: int):

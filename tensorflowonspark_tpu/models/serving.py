@@ -72,13 +72,36 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tensorflowonspark_tpu import observability as _obs
 from tensorflowonspark_tpu.models.gpt import (GPT, GPTConfig, init_cache,
                                               nucleus_filter, rewind_cache)
 from tensorflowonspark_tpu.models.kv_pages import KVPagePool, hash_page_data
 
+#: compile site -> the program's name, by ROLE and never by shape: the
+#: profiler reads ``jit_<name>``, and a reduction that selects a program
+#: by name must find the same name at every bucket, group and block size
+#: (docs/observability.md "Profiler spans").  A new compile site picks its
+#: name here.
+PROGRAM_NAMES = {
+    "step": "tfos_decode", "step_sample": "tfos_decode_sampled",
+    "block": "tfos_decode_block", "verify": "tfos_verify",
+    "final": "tfos_prefill", "pfinal": "tfos_prefill",
+    "chunk": "tfos_prefill_chunk", "pchunk": "tfos_prefill_chunk",
+    "zeros": "tfos_kv_zeros", "scatter": "tfos_kv_scatter",
+    "padopt": "tfos_kv_seat", "park": "tfos_kv_park",
+    "pexport": "tfos_kv_export", "draft_propose": "tfos_draft"}
+
 
 def _next_pow2(n: int) -> int:
     return 1 << (n - 1).bit_length()
+
+
+def _named(site, fn):
+    """``fn`` named for its compile site's role (:data:`PROGRAM_NAMES`);
+    ``jax.jit`` names the compiled module ``jit_<fn.__name__>``."""
+    fn.__name__ = fn.__qualname__ = PROGRAM_NAMES[
+        site if isinstance(site, str) else site[0]]
+    return fn
 
 
 @dataclass
@@ -127,7 +150,8 @@ def _select_tokens(logits, seeds, steps, temps, top_ps):
         sampled = jax.random.categorical(key, nucleus_filter(scaled, top_p))
         return jnp.where(temp <= 0.0, greedy, sampled)
 
-    return jax.vmap(pick)(logits, seeds, steps, temps, top_ps)
+    with jax.named_scope("sample"):
+        return jax.vmap(pick)(logits, seeds, steps, temps, top_ps)
 
 
 class DraftModel:
@@ -166,6 +190,7 @@ class DraftModel:
         self.dispatches = 0
         self._aot = None               # set by ContinuousBatcher.set_draft
         self._jits: dict = {}
+        self._spans = _obs.PhaseSpans()
 
     def _propose_jit(self, B: int, L: int, k: int):
         key = (B, L, k)
@@ -187,6 +212,7 @@ class DraftModel:
             (_, _), seq = jax.lax.scan(body, (buf, lens), None, length=k)
             return seq.swapaxes(0, 1)                          # [B, k]
 
+        _named("draft_propose", propose_fn)
         if self._aot is None:
             fn = jax.jit(propose_fn)
         else:
@@ -205,8 +231,11 @@ class DraftModel:
         ignored (the verify mask ``d`` is what gates commitment)."""
         B, L = buf.shape
         self.dispatches += 1
-        return np.asarray(self._propose_jit(B, L, int(k))(
-            self.params, jnp.asarray(buf), jnp.asarray(lens)))
+        with self._spans(_obs.BATCHER_DECODE_DISPATCH):
+            seq = self._propose_jit(B, L, int(k))(
+                self.params, jnp.asarray(buf), jnp.asarray(lens))
+        with self._spans(_obs.BATCHER_DECODE_FETCH):
+            return np.asarray(seq)
 
 
 class ContinuousBatcher:
@@ -450,6 +479,9 @@ class ContinuousBatcher:
         #: context string disambiguates entries across models/knobs
         #: sharing one cache directory.
         self._aot = aot_cache
+        #: the loop thread's phase spans (docs/observability.md "Profiler
+        #: spans"): admit, prefill/decode dispatch and fetch, emit
+        self._spans = _obs.PhaseSpans()
         self._aot_ctx = None if aot_cache is None else repr(
             (self.cfg, self.max_batch, self.spec_k, self.spec_ngram,
              self.prefill_chunk, self.decode_block_steps))
@@ -471,7 +503,10 @@ class ContinuousBatcher:
         """THE compile-site chokepoint: plain ``jax.jit`` without an AOT
         cache, else the cache's load-or-compile wrapper keyed on (site,
         this batcher's config context, arg avals).  Both are lazy and
-        call-compatible, so the executable registry stores either."""
+        call-compatible, so the executable registry stores either.  The
+        program is named for the site's role (:data:`PROGRAM_NAMES`); the
+        AOT cache keys on ``site``, never on that name."""
+        _named(site, fn)
         if self._aot is None:
             return jax.jit(fn, donate_argnums=donate_argnums)
         return self._aot.wrap((site, self._aot_ctx), fn,
@@ -508,8 +543,9 @@ class ContinuousBatcher:
 
             self._prefill_jit[key] = self._jit(key, scatter_fn,
                                                donate_argnums=(0,))
-        self.cache = self._prefill_jit[key](
-            self.cache, row_cache, jnp.asarray(slot_idx, jnp.int32))
+        with self._spans(_obs.BATCHER_PREFILL_DISPATCH):
+            self.cache = self._prefill_jit[key](
+                self.cache, row_cache, jnp.asarray(slot_idx, jnp.int32))
 
     def _check_usable(self) -> None:
         if self._poisoned is not None:
@@ -1171,8 +1207,10 @@ class ContinuousBatcher:
         n_full = (prompt.size - 1) // C   # >= 1 token left for the final
         i = inf["done_chunks"]
         if i < n_full:
-            inf["cache"] = self._chunk_jit()(
-                self.params, inf["cache"], prompt[None, i * C:(i + 1) * C])
+            with self._spans(_obs.BATCHER_PREFILL_DISPATCH):
+                inf["cache"] = self._chunk_jit()(
+                    self.params, inf["cache"],
+                    prompt[None, i * C:(i + 1) * C])
             inf["done_chunks"] += 1
             return []
         first, row_cache = self._prefill_final(
@@ -1182,7 +1220,8 @@ class ContinuousBatcher:
         self._reserved.discard(slot)
         self._scatter_rows(row_cache, [slot])
         self._inflight = None
-        tok = int(np.asarray(first)[0])
+        with self._spans(_obs.BATCHER_PREFILL_FETCH):
+            tok = int(np.asarray(first)[0])
         self._emit_token(rid, tok)
         s = _Slot(request_id=rid, remaining=budget - 1, tokens=[tok],
                   temperature=temp, top_p=top_p, seed=seed)
@@ -1220,19 +1259,6 @@ class ContinuousBatcher:
             1 if self.cfg.scan_layers else 0]    # cache row count (pow2)
         Tp = min(_next_pow2(max(r.size for r in rests)),
                  self.cfg.max_position_embeddings)
-        padded = np.zeros((rp, Tp), np.int32)
-        true_len = np.ones((rp,), np.int32)
-        for j, r in enumerate(rests):
-            padded[j, :r.size] = r
-            true_len[j] = r.size
-        tot = np.ones((rp,), np.int32)
-        tot[:R] = true_totals
-        seed_a = np.zeros((rp,), np.int32)
-        seed_a[:R] = seeds
-        temp_a = np.zeros((rp,), np.float32)
-        temp_a[:R] = temps
-        top_a = np.ones((rp,), np.float32)
-        top_a[:R] = top_ps
         key = ("final", Tp, rp)
         if key not in self._prefill_jit:
             def final_fn(params, cache, tokens, true_len, true_tot,
@@ -1248,10 +1274,24 @@ class ContinuousBatcher:
             self._prefill_jit[key] = self._jit(key, final_fn,
                                                donate_argnums=(1,))
         self.prefill_dispatches += 1
-        return self._prefill_jit[key](
-            self.params, cache, padded,
-            jnp.asarray(true_len), jnp.asarray(tot),
-            jnp.asarray(seed_a), jnp.asarray(temp_a), jnp.asarray(top_a))
+        with self._spans(_obs.BATCHER_PREFILL_DISPATCH):
+            padded = np.zeros((rp, Tp), np.int32)
+            true_len = np.ones((rp,), np.int32)
+            for j, r in enumerate(rests):
+                padded[j, :r.size] = r
+                true_len[j] = r.size
+            tot = np.ones((rp,), np.int32)
+            tot[:R] = true_totals
+            seed_a = np.zeros((rp,), np.int32)
+            seed_a[:R] = seeds
+            temp_a = np.zeros((rp,), np.float32)
+            temp_a[:R] = temps
+            top_a = np.ones((rp,), np.float32)
+            top_a[:R] = top_ps
+            return self._prefill_jit[key](
+                self.params, cache, padded,
+                jnp.asarray(true_len), jnp.asarray(tot),
+                jnp.asarray(seed_a), jnp.asarray(temp_a), jnp.asarray(top_a))
 
     def _admit(self) -> list[int]:
         """Fill free slots from the pending queue; returns the ids of
@@ -1327,7 +1367,8 @@ class ContinuousBatcher:
                 # pad rows target slot max_batch: out of bounds, dropped
                 self._scatter_rows(rows,
                                    slots + [self.max_batch] * (rp - len(reqs)))
-                firsts = np.asarray(firsts)
+                with self._spans(_obs.BATCHER_PREFILL_FETCH):
+                    firsts = np.asarray(firsts)
                 for j, (rid, _, budget, temp, top_p, seed) in enumerate(reqs):
                     admitted.append((slots[j], (rid, budget, temp, top_p,
                                                 seed), int(firsts[j])))
@@ -1468,27 +1509,6 @@ class ContinuousBatcher:
         Tp = min(_next_pow2(max(req[1].size - start
                                 for req, _, start in entries)), cfgC)
         rp = _next_pow2(len(entries))
-        row_bt = np.full((rp, npg), P, np.int32)
-        row_start = np.zeros((rp,), np.int32)
-        tokens = np.zeros((rp, Tp), np.int32)
-        true_len = np.ones((rp,), np.int32)
-        true_tot = np.ones((rp,), np.int32)
-        slot_a = np.full((rp,), self.max_batch, np.int32)
-        seed_a = np.zeros((rp,), np.int32)
-        temp_a = np.zeros((rp,), np.float32)
-        top_a = np.ones((rp,), np.float32)
-        for j, (req, lease, start) in enumerate(entries):
-            _, prompt, _, temp, top_p, seed = req
-            tail = prompt[start:]
-            row_bt[j, :len(lease.page_ids)] = lease.page_ids
-            row_start[j] = start
-            tokens[j, :tail.size] = tail
-            true_len[j] = tail.size
-            true_tot[j] = prompt.size
-            slot_a[j] = slots[j]
-            seed_a[j] = seed
-            temp_a[j] = temp
-            top_a[j] = top_p
         key = ("pfinal", Tp, rp)
         if key not in self._prefill_jit:
             model = self.model
@@ -1543,14 +1563,38 @@ class ContinuousBatcher:
             self._prefill_jit[key] = self._jit(key, pfinal_fn,
                                                donate_argnums=(1,))
         self.prefill_dispatches += 1
-        firsts, self.cache = self._prefill_jit[key](
-            self.params, self.cache, tokens, row_bt,
-            jnp.asarray(row_start), jnp.asarray(true_len),
-            jnp.asarray(true_tot), jnp.asarray(slot_a),
-            jnp.asarray(seed_a), jnp.asarray(temp_a), jnp.asarray(top_a))
+        with self._spans(_obs.BATCHER_PREFILL_DISPATCH):
+            row_bt = np.full((rp, npg), P, np.int32)
+            row_start = np.zeros((rp,), np.int32)
+            tokens = np.zeros((rp, Tp), np.int32)
+            true_len = np.ones((rp,), np.int32)
+            true_tot = np.ones((rp,), np.int32)
+            slot_a = np.full((rp,), self.max_batch, np.int32)
+            seed_a = np.zeros((rp,), np.int32)
+            temp_a = np.zeros((rp,), np.float32)
+            top_a = np.ones((rp,), np.float32)
+            for j, (req, lease, start) in enumerate(entries):
+                _, prompt, _, temp, top_p, seed = req
+                tail = prompt[start:]
+                row_bt[j, :len(lease.page_ids)] = lease.page_ids
+                row_start[j] = start
+                tokens[j, :tail.size] = tail
+                true_len[j] = tail.size
+                true_tot[j] = prompt.size
+                slot_a[j] = slots[j]
+                seed_a[j] = seed
+                temp_a[j] = temp
+                top_a[j] = top_p
+            firsts, self.cache = self._prefill_jit[key](
+                self.params, self.cache, tokens, row_bt,
+                jnp.asarray(row_start), jnp.asarray(true_len),
+                jnp.asarray(true_tot), jnp.asarray(slot_a),
+                jnp.asarray(seed_a), jnp.asarray(temp_a),
+                jnp.asarray(top_a))
         for _, lease, _ in entries:
             self._pages.commit(lease)
-        return np.asarray(firsts)
+        with self._spans(_obs.BATCHER_PREFILL_FETCH):
+            return np.asarray(firsts)
 
     def _pchunk_jit(self):
         """One fixed-chunk paged prefill executable: streams a chunk of
@@ -1607,9 +1651,10 @@ class ContinuousBatcher:
                 // self.cfg.kv_page_tokens
             row_bt = np.full((1, npg), self.cfg.kv_pool_pages, np.int32)
             row_bt[0, :len(lease.page_ids)] = lease.page_ids
-            self.cache = self._pchunk_jit()(
-                self.params, self.cache, prompt[None, start:start + C],
-                row_bt, np.asarray([start], np.int32))
+            with self._spans(_obs.BATCHER_PREFILL_DISPATCH):
+                self.cache = self._pchunk_jit()(
+                    self.params, self.cache, prompt[None, start:start + C],
+                    row_bt, np.asarray([start], np.int32))
             inf["done_chunks"] += 1
             return []
         slot = inf["slot"]
@@ -1760,40 +1805,41 @@ class ContinuousBatcher:
         K = self.spec_k
         B = self.max_batch
         dm = self._draft_model
-        toks = np.zeros((B, K + 1), np.int32)
-        d = np.zeros((B,), np.int32)
-        elig: list[int] = []
-        if dm is not None:
-            buf = np.zeros((B, dm.window + K), np.int32)
-            lens = np.ones((B,), np.int32)
-        for i, s in enumerate(self.slots):
-            if s is None:
-                continue
-            toks[i, :] = s.tokens[-1]
-            if s.temperature <= 0 and s.remaining > 1:
-                # sampled rows keep the draft-0 fallback: their token
-                # still comes from the verify dispatch's boundary logits
-                if dm is not None:
-                    h = self._history(s, self._prompts[s.request_id],
-                                      dm.window)
-                    buf[i, :h.size] = h
-                    lens[i] = h.size
-                    elig.append(i)
+        with self._spans(_obs.BATCHER_DECODE_DISPATCH):
+            toks = np.zeros((B, K + 1), np.int32)
+            d = np.zeros((B,), np.int32)
+            elig: list[int] = []
+            if dm is not None:
+                buf = np.zeros((B, dm.window + K), np.int32)
+                lens = np.ones((B,), np.int32)
+            for i, s in enumerate(self.slots):
+                if s is None:
                     continue
-                dr = self._draft(s, self._prompts[s.request_id])
-                di = min(dr.size, s.remaining - 1)
-                if di > 0:
-                    toks[i, 1:1 + dr.size] = dr
-                    d[i] = di
-        if dm is not None and elig:
-            # ONE scanned draft dispatch proposes K tokens for every
-            # eligible row; ineligible rows ride along masked (d=0)
-            props = dm.propose(buf, lens, K)
-            self.draft_dispatches += 1
-            for i in elig:
-                s = self.slots[i]
-                toks[i, 1:1 + K] = props[i]
-                d[i] = min(K, s.remaining - 1)
+                toks[i, :] = s.tokens[-1]
+                if s.temperature <= 0 and s.remaining > 1:
+                    # sampled rows keep the draft-0 fallback: their token
+                    # still comes from the verify dispatch's boundary logits
+                    if dm is not None:
+                        h = self._history(s, self._prompts[s.request_id],
+                                          dm.window)
+                        buf[i, :h.size] = h
+                        lens[i] = h.size
+                        elig.append(i)
+                        continue
+                    dr = self._draft(s, self._prompts[s.request_id])
+                    di = min(dr.size, s.remaining - 1)
+                    if di > 0:
+                        toks[i, 1:1 + dr.size] = dr
+                        d[i] = di
+            if dm is not None and elig:
+                # ONE scanned draft dispatch proposes K tokens for every
+                # eligible row; ineligible rows ride along masked (d=0)
+                props = dm.propose(buf, lens, K)
+                self.draft_dispatches += 1
+                for i in elig:
+                    s = self.slots[i]
+                    toks[i, 1:1 + K] = props[i]
+                    d[i] = min(K, s.remaining - 1)
         if not d.any():
             # nothing drafted anywhere (all-sampled traffic, novel text,
             # or every slot at its last token): fall through to the plain
@@ -1802,53 +1848,59 @@ class ContinuousBatcher:
             return self._plain_step()
         self.decode_dispatches += 1
         self.decode_steps += 1
-        a, bonus, self.cache = self._verify_jit()(
-            self.params, self.cache, jnp.asarray(toks), jnp.asarray(d),
-            jnp.asarray([s.seed if s else 0 for s in self.slots],
-                        jnp.int32),
-            jnp.asarray([len(s.tokens) if s else 0 for s in self.slots],
-                        jnp.int32),
-            jnp.asarray([s.temperature if s else 0.0 for s in self.slots],
-                        jnp.float32),
-            jnp.asarray([s.top_p if s else 1.0 for s in self.slots],
-                        jnp.float32))
-        a, bonus = np.asarray(a), np.asarray(bonus)
-        self.spec_proposed += int(d.sum())
-        self.spec_accepted += int(a.sum())
-        for i in np.flatnonzero(d):
-            self._accept_lens.append(int(a[i]))
-        if len(self._accept_lens) > 65536:   # unmetered batcher: bound it
-            del self._accept_lens[:-4096]
+        with self._spans(_obs.BATCHER_DECODE_DISPATCH):
+            a, bonus, self.cache = self._verify_jit()(
+                self.params, self.cache, jnp.asarray(toks), jnp.asarray(d),
+                jnp.asarray([s.seed if s else 0 for s in self.slots],
+                            jnp.int32),
+                jnp.asarray([len(s.tokens) if s else 0 for s in self.slots],
+                            jnp.int32),
+                jnp.asarray([s.temperature if s else 0.0
+                             for s in self.slots], jnp.float32),
+                jnp.asarray([s.top_p if s else 1.0 for s in self.slots],
+                            jnp.float32))
+        with self._spans(_obs.BATCHER_DECODE_FETCH):
+            a, bonus = np.asarray(a), np.asarray(bonus)
         done = []
-        for i, s in enumerate(self.slots):
-            if s is None:
-                continue
-            new = list(toks[i, 1:1 + a[i]]) + [int(bonus[i])]
-            for tok in new:
-                s.tokens.append(int(tok))
-                self._emit_token(s.request_id, int(tok))
-                s.remaining -= 1
-                if s.remaining <= 0 or tok == self.eos_id:
-                    done.append(s.request_id)
-                    self._finish(i, s)
-                    break
+        with self._spans(_obs.BATCHER_EMIT):
+            self.spec_proposed += int(d.sum())
+            self.spec_accepted += int(a.sum())
+            for i in np.flatnonzero(d):
+                self._accept_lens.append(int(a[i]))
+            if len(self._accept_lens) > 65536:   # unmetered batcher: bound
+                del self._accept_lens[:-4096]
+            for i, s in enumerate(self.slots):
+                if s is None:
+                    continue
+                new = list(toks[i, 1:1 + a[i]]) + [int(bonus[i])]
+                for tok in new:
+                    s.tokens.append(int(tok))
+                    self._emit_token(s.request_id, int(tok))
+                    s.remaining -= 1
+                    if s.remaining <= 0 or tok == self.eos_id:
+                        done.append(s.request_id)
+                        self._finish(i, s)
+                        break
         return done
 
     def _step_inner(self) -> list[int]:
-        done = self._admit()
+        # the prefill spans inside suspend this one (observability.span)
+        with self._spans(_obs.BATCHER_ADMIT):
+            done = self._admit()
         if self.prefill_only:
             # prefill-pool posture: a seated request's prompt KV is
             # computed — export the session for handoff instead of ever
             # decode-stepping it.  The release inside _finish keeps the
             # pool's prefix index warm (full prompt pages park in the
             # LRU, matchable by the next same-system-prompt admission).
-            for i, s in enumerate(self.slots):
-                if s is None or i in self._reserved:
-                    continue
-                self._sessions.append((s.request_id,
-                                       self._export_session(s)))
-                self.sessions_exported += 1
-                self._finish(i, s)
+            with self._spans(_obs.BATCHER_EMIT):
+                for i, s in enumerate(self.slots):
+                    if s is None or i in self._reserved:
+                        continue
+                    self._sessions.append((s.request_id,
+                                           self._export_session(s)))
+                    self.sessions_exported += 1
+                    self._finish(i, s)
             return done
         if not any(self.slots):
             return done
@@ -1932,67 +1984,73 @@ class ContinuousBatcher:
         done: list[int] = []
         self.decode_dispatches += 1
         self.decode_steps += K
-        tokens = jnp.asarray([s.tokens[-1] if s else 0
-                              for s in self.slots], jnp.int32)
-        if any(s is not None and s.temperature > 0 for s in self.slots):
-            seq, self.cache = self._block_jit(K, True)(
-                self.params, self.cache, tokens,
-                jnp.asarray([s.seed if s else 0 for s in self.slots],
-                            jnp.int32),
-                jnp.asarray([len(s.tokens) if s else 0
-                             for s in self.slots], jnp.int32),
-                jnp.asarray([s.temperature if s else 0.0
-                             for s in self.slots], jnp.float32),
-                jnp.asarray([s.top_p if s else 1.0 for s in self.slots],
-                            jnp.float32))
-        else:
-            seq, self.cache = self._block_jit(K, False)(
-                self.params, self.cache, tokens)
-        seq = np.asarray(seq)
-        for i, s in enumerate(self.slots):
-            if s is None:
-                continue
-            for tok in seq[i]:
-                tok = int(tok)
-                s.tokens.append(tok)
-                self._emit_token(s.request_id, tok)
-                s.remaining -= 1
-                if s.remaining <= 0 or tok == self.eos_id:
-                    done.append(s.request_id)
-                    self._finish(i, s)
-                    break
+        with self._spans(_obs.BATCHER_DECODE_DISPATCH):
+            tokens = jnp.asarray([s.tokens[-1] if s else 0
+                                  for s in self.slots], jnp.int32)
+            if any(s is not None and s.temperature > 0 for s in self.slots):
+                seq, self.cache = self._block_jit(K, True)(
+                    self.params, self.cache, tokens,
+                    jnp.asarray([s.seed if s else 0 for s in self.slots],
+                                jnp.int32),
+                    jnp.asarray([len(s.tokens) if s else 0
+                                 for s in self.slots], jnp.int32),
+                    jnp.asarray([s.temperature if s else 0.0
+                                 for s in self.slots], jnp.float32),
+                    jnp.asarray([s.top_p if s else 1.0 for s in self.slots],
+                                jnp.float32))
+            else:
+                seq, self.cache = self._block_jit(K, False)(
+                    self.params, self.cache, tokens)
+        with self._spans(_obs.BATCHER_DECODE_FETCH):
+            seq = np.asarray(seq)
+        with self._spans(_obs.BATCHER_EMIT):
+            for i, s in enumerate(self.slots):
+                if s is None:
+                    continue
+                for tok in seq[i]:
+                    tok = int(tok)
+                    s.tokens.append(tok)
+                    self._emit_token(s.request_id, tok)
+                    s.remaining -= 1
+                    if s.remaining <= 0 or tok == self.eos_id:
+                        done.append(s.request_id)
+                        self._finish(i, s)
+                        break
         return done
 
     def _plain_step(self) -> list[int]:
         done: list[int] = []
         self.decode_dispatches += 1
         self.decode_steps += 1
-        tokens = jnp.asarray([s.tokens[-1] if s else 0
-                              for s in self.slots], jnp.int32)
-        if any(s is not None and s.temperature > 0 for s in self.slots):
-            nxt, self.cache = self._step_sample(
-                self.params, self.cache, tokens,
-                jnp.asarray([s.seed if s else 0 for s in self.slots],
-                            jnp.int32),
-                jnp.asarray([len(s.tokens) if s else 0 for s in self.slots],
-                            jnp.int32),
-                jnp.asarray([s.temperature if s else 0.0
-                             for s in self.slots], jnp.float32),
-                jnp.asarray([s.top_p if s else 1.0 for s in self.slots],
-                            jnp.float32))
-        else:
-            nxt, self.cache = self._step(self.params, self.cache, tokens)
-        nxt = np.asarray(nxt)
-        for i, s in enumerate(self.slots):
-            if s is None:
-                continue
-            tok = int(nxt[i])
-            s.tokens.append(tok)
-            self._emit_token(s.request_id, tok)
-            s.remaining -= 1
-            if s.remaining <= 0 or tok == self.eos_id:
-                done.append(s.request_id)
-                self._finish(i, s)
+        with self._spans(_obs.BATCHER_DECODE_DISPATCH):
+            tokens = jnp.asarray([s.tokens[-1] if s else 0
+                                  for s in self.slots], jnp.int32)
+            if any(s is not None and s.temperature > 0 for s in self.slots):
+                nxt, self.cache = self._step_sample(
+                    self.params, self.cache, tokens,
+                    jnp.asarray([s.seed if s else 0 for s in self.slots],
+                                jnp.int32),
+                    jnp.asarray([len(s.tokens) if s else 0
+                                 for s in self.slots], jnp.int32),
+                    jnp.asarray([s.temperature if s else 0.0
+                                 for s in self.slots], jnp.float32),
+                    jnp.asarray([s.top_p if s else 1.0 for s in self.slots],
+                                jnp.float32))
+            else:
+                nxt, self.cache = self._step(self.params, self.cache, tokens)
+        with self._spans(_obs.BATCHER_DECODE_FETCH):
+            nxt = np.asarray(nxt)
+        with self._spans(_obs.BATCHER_EMIT):
+            for i, s in enumerate(self.slots):
+                if s is None:
+                    continue
+                tok = int(nxt[i])
+                s.tokens.append(tok)
+                self._emit_token(s.request_id, tok)
+                s.remaining -= 1
+                if s.remaining <= 0 or tok == self.eos_id:
+                    done.append(s.request_id)
+                    self._finish(i, s)
         return done
 
     def result(self, request_id: int, *, pop: bool = False) \

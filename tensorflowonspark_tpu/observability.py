@@ -32,6 +32,7 @@ import threading
 import time
 from collections import defaultdict
 
+from tensorflowonspark_tpu import metrics as _metrics
 from tensorflowonspark_tpu import util
 
 logger = logging.getLogger(__name__)
@@ -128,11 +129,177 @@ def profile_trace(logdir: str):
         yield
 
 
-def annotate(name: str):
-    """Named sub-trace for the profiler timeline (``TraceAnnotation``)."""
-    import jax
+# ------------------------------------------------------------------- spans
+#
+# Host spans of the program's own.  The names are module constants (nothing
+# formats a string on the hot path) and start ``tfos/``: the benchmark's
+# trace reduction (``benchmark/trace.py``) lays every idle gap of the device
+# against host spans named ``bench/...`` or ``tfos/...`` and gives the gap
+# WHOLE to the one span that covers most of it.  Two rules follow:
+#
+# (i)  ``tfos/`` spans are leaves: they never nest (an enclosing
+#      ``tfos/serve/step`` would win every gap).  :class:`span` holds a
+#      thread to that itself: entering one suspends the span the thread has
+#      open and resumes it on exit, so no two spans of a thread overlap.
+# (ii) a whole turn of the serving loop is marked with
+#      ``jax.profiler.StepTraceAnnotation(SERVE_STEP, step_num=...)``: its
+#      name does not start ``tfos/``, the reduction ignores it, and
+#      TensorBoard's step views use it.
+#
+# The ten serve-side names partition the time of the replica's loop thread,
+# and ten are what the reduction's ``idle_gaps`` keeps.
 
-    return jax.profiler.TraceAnnotation(name)
+SERVE_INTAKE = "tfos/serve/intake"
+SERVE_IDLE = "tfos/serve/idle"
+BATCHER_ADMIT = "tfos/batcher/admit"
+BATCHER_PREFILL_DISPATCH = "tfos/batcher/prefill_dispatch"
+BATCHER_PREFILL_FETCH = "tfos/batcher/prefill_fetch"
+BATCHER_DECODE_DISPATCH = "tfos/batcher/decode_dispatch"
+BATCHER_DECODE_FETCH = "tfos/batcher/decode_fetch"
+BATCHER_EMIT = "tfos/batcher/emit"
+SERVE_PUBLISH = "tfos/serve/publish"
+SERVE_FLUSH = "tfos/serve/flush"
+#: the spans of the replica's loop thread, in the order of a loop turn
+REPLICA_PHASES = (SERVE_INTAKE, SERVE_IDLE, BATCHER_ADMIT,
+                  BATCHER_PREFILL_DISPATCH, BATCHER_PREFILL_FETCH,
+                  BATCHER_DECODE_DISPATCH, BATCHER_DECODE_FETCH,
+                  BATCHER_EMIT, SERVE_PUBLISH, SERVE_FLUSH)
+#: train side: the two blocking ``queue_get`` sites of ``datafeed.py`` and
+#: ``MeshStrategy.shard_batch``
+FEED_WAIT = "tfos/feed/wait"
+TRAIN_SHARD_BATCH = "tfos/train/shard_batch"
+#: ``StepTraceAnnotation`` name of one turn of the serving loop (rule ii)
+SERVE_STEP = "tfos_serve_step"
+
+_open_span = threading.local()   # .span: the span this thread has open
+
+
+def _jax_profiler():
+    """``jax.profiler`` where this process has imported jax, else None: a
+    process that never did has no profiler to annotate for, and is not
+    made to import jax for it."""
+    return getattr(sys.modules.get("jax"), "profiler", None)
+
+
+class span:
+    """``with span(name, seconds):`` — one host span, two sinks.
+
+    (a) A ``jax.profiler.TraceAnnotation(name)``: inert (about 0.1 us)
+    while no profiler session runs, and on the profiler's own clock, beside
+    the device planes, when one does.
+    (b) The elapsed ``time.perf_counter()`` seconds are added to
+    ``seconds``, a BOUND counter child (``Counter.labels(...)``, made once
+    outside the loop; :func:`phase_seconds`), when given.
+
+    Spans are leaves (rule i above): entering a span while this thread has
+    another open closes that one (annotation and clock) and reopens it on
+    exit, so the spans of one thread partition its time and never overlap.
+    ``TFOS_NO_TELEMETRY=1`` makes it a no-op, like the metrics plane.
+    """
+
+    __slots__ = ("name", "seconds", "_outer", "_ann", "_t0")
+
+    def __init__(self, name: str, seconds=None):
+        self.name = name
+        self.seconds = seconds
+        self._ann = None
+        self._t0 = None             # None: not entered, or inert
+
+    def _start(self) -> None:
+        profiler = _jax_profiler()
+        if profiler is not None:
+            self._ann = profiler.TraceAnnotation(self.name)
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+
+    def _stop(self) -> None:
+        elapsed = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
+        if self.seconds is not None:
+            self.seconds.inc(elapsed)
+
+    def __enter__(self) -> "span":
+        if not _metrics.get_registry().enabled:
+            return self
+        self._outer = getattr(_open_span, "span", None)
+        if self._outer is not None:
+            self._outer._stop()
+        _open_span.span = self
+        self._start()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        if self._t0 is None:
+            return False
+        self._stop()
+        self._t0 = None
+        _open_span.span = self._outer
+        if self._outer is not None:
+            self._outer._start()
+        return False
+
+
+class step_marks:
+    """Back-to-back ``jax.profiler.StepTraceAnnotation`` marks of a loop's
+    turns (rule ii above): ``marks.next(n)`` closes the open mark and opens
+    turn ``n``'s, ``marks.close()`` ends the last.  Inert where jax is not
+    imported or under ``TFOS_NO_TELEMETRY=1``."""
+
+    __slots__ = ("name", "_open")
+
+    def __init__(self, name: str):
+        self.name = name
+        self._open = None
+
+    def close(self) -> None:
+        if self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+    def __enter__(self) -> "step_marks":
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        self.close()
+        return False
+
+    def next(self, step_num: int) -> None:
+        self.close()
+        profiler = _jax_profiler()
+        if profiler is not None and _metrics.get_registry().enabled:
+            self._open = profiler.StepTraceAnnotation(self.name,
+                                                      step_num=step_num)
+            self._open.__enter__()
+
+
+class PhaseSpans:
+    """The spans of the replica's loop thread, each bound once (here, and
+    not in the loop) to its clock: ``spans = PhaseSpans()``, then ``with
+    spans(BATCHER_EMIT): ...``.  ``seconds[name]`` is the span's bound
+    child of ``tfos_replica_phase_seconds_total``; the serving loop and the
+    batcher it drives each make one and share the family."""
+
+    __slots__ = ("seconds",)
+
+    def __init__(self):
+        self.seconds = {name: phase_seconds(name) for name in REPLICA_PHASES}
+
+    def __call__(self, name: str) -> span:
+        return span(name, self.seconds[name])
+
+
+def phase_seconds(name: str):
+    """The bound child of ``tfos_replica_phase_seconds_total`` that the
+    span ``name`` (one of :data:`REPLICA_PHASES`) adds its seconds to; the
+    ``phase`` label is the name's last path part."""
+    return _metrics.get_registry().counter(
+        "tfos_replica_phase_seconds_total",
+        "Seconds the replica's loop thread spent in each phase of a loop "
+        "turn; the phases partition the thread's time, so their deltas "
+        "over a window sum to the window.",
+        labelnames=("phase",)).labels(phase=name.rsplit("/", 1)[1])
 
 
 # ------------------------------------------------------------ health events

@@ -31,6 +31,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from tensorflowonspark_tpu import observability as _obs
 from tensorflowonspark_tpu.parallel import sharding as sh
 from tensorflowonspark_tpu.parallel.mesh import MeshSpec, make_mesh
 from tensorflowonspark_tpu.parallel.sharding import PartitionRules
@@ -90,7 +91,8 @@ class MeshStrategy:
 
     # -- data --------------------------------------------------------------
     def shard_batch(self, batch):
-        return sh.shard_batch(self.mesh, batch)
+        with _obs.span(_obs.TRAIN_SHARD_BATCH):
+            return sh.shard_batch(self.mesh, batch)
 
     def batch_sharding(self) -> NamedSharding:
         return NamedSharding(self.mesh, sh.batch_pspec())
@@ -185,14 +187,17 @@ class MeshStrategy:
             grad_fn = jax.value_and_grad(loss_fn, has_aux=has_aux)
             args = (params, batch, extras) if takes_extras else (params, batch)
             kwargs = {"rng": rng} if takes_rng else {}
-            if has_aux:
-                (loss, aux), grads = grad_fn(*args, **kwargs)
-            else:
-                loss, grads = grad_fn(*args, **kwargs)
-                aux = {}
+            with jax.named_scope("loss_and_grad"):
+                if has_aux:
+                    (loss, aux), grads = grad_fn(*args, **kwargs)
+                else:
+                    loss, grads = grad_fn(*args, **kwargs)
+                    aux = {}
             return loss, aux, grads
 
-        def step(state: TrainState, batch):
+        # the compiled program's name: the profiler reads
+        # ``jit_tfos_train_step`` (docs/observability.md "Profiler spans")
+        def tfos_train_step(state: TrainState, batch):
             import optax
 
             step_rng = jax.random.fold_in(base_rng, state.step) \
@@ -244,15 +249,17 @@ class MeshStrategy:
                 extras = carry["extras"]
                 aux = jax.tree.map(lambda a: a[-1], aux_stack)
 
-            updates, opt_state = tx.update(grads, state.opt_state, state.params)
-            params = optax.apply_updates(state.params, updates)
+            with jax.named_scope("optimizer_update"):
+                updates, opt_state = tx.update(grads, state.opt_state,
+                                               state.params)
+                params = optax.apply_updates(state.params, updates)
             new_state = TrainState(params=params, opt_state=opt_state,
                                    step=state.step + 1, extras=extras)
             metrics = {"loss": loss, **aux}
             return new_state, metrics
 
         donate_argnums = (0,) if donate else ()
-        return jax.jit(step, donate_argnums=donate_argnums)
+        return jax.jit(tfos_train_step, donate_argnums=donate_argnums)
 
     def run(self, fn, *args):
         """Execute ``fn`` under this strategy's mesh context (for explicit

@@ -58,12 +58,15 @@ tier:
 
 from __future__ import annotations
 
+import collections
 import logging
 import queue as _queue
+import statistics
 import threading
 import time as _time
 
 from tensorflowonspark_tpu import metrics as _metrics
+from tensorflowonspark_tpu import observability as _obs
 from tensorflowonspark_tpu import tracing
 from tensorflowonspark_tpu.marker import EndOfFeed, Marker
 from tensorflowonspark_tpu.preemption import PreemptionGuard
@@ -346,6 +349,62 @@ def serving_batcher_kwargs(args) -> dict:
     return kwargs
 
 
+class _SlowTurns:
+    """The one fixed rule on the loop's phase clocks (docs/observability.md
+    "Reading a stall"): a loop turn longer than 3x the median of the last
+    32 turns and longer than 0.5 s (once 8 turns are known) increments
+    ``tfos_replica_slow_steps_total{phase=<the phase that took most of
+    it>}``, logs one WARNING with the turn's phase split, and emits
+    ``replica_slow_step`` into ``trace_events.jsonl``.  A turn's length
+    leaves out its ``idle`` seconds: waiting for requests is no stall.  A
+    steady turn costs one read of each phase clock."""
+
+    FACTOR, MIN_SECS, HISTORY, MIN_HISTORY = 3.0, 0.5, 32, 8
+
+    def __init__(self, reg, spans, tracer, replica: int):
+        self._phases = [name.rsplit("/", 1)[1]
+                        for name in _obs.REPLICA_PHASES]
+        self._clocks = [spans.seconds[name] for name in _obs.REPLICA_PHASES]
+        self._idle = _obs.REPLICA_PHASES.index(_obs.SERVE_IDLE)
+        self._m_slow = reg.counter(
+            "tfos_replica_slow_steps_total",
+            "Loop turns longer than 3x the median of the last 32 and "
+            "longer than 0.5 s, by the phase that took most of the turn.",
+            labelnames=("phase",))
+        self._tracer, self._replica = tracer, replica
+        self._recent: collections.deque = collections.deque(
+            maxlen=self.HISTORY)
+        self._last = [c.value() for c in self._clocks]
+        self._t = _time.perf_counter()
+
+    def turn_done(self, step: int) -> None:
+        now = _time.perf_counter()
+        vals = [c.value() for c in self._clocks]
+        if vals[0] is None:         # TFOS_NO_TELEMETRY=1: no clocks
+            return
+        split = [v - p for v, p in zip(vals, self._last)]
+        turn = now - self._t - split[self._idle]
+        self._last, self._t = vals, now
+        recent = self._recent
+        median = statistics.median(recent) \
+            if turn > self.MIN_SECS and len(recent) >= self.MIN_HISTORY \
+            else None
+        if median is not None and turn > self.FACTOR * median:
+            split[self._idle] = 0.0
+            phase = self._phases[split.index(max(split))]
+            by_phase = {p: round(s, 6)
+                        for p, s in zip(self._phases, split) if s > 0}
+            self._m_slow.inc(phase=phase)
+            logger.warning(
+                "replica %d: slow loop turn at step %d: %.3f s against a "
+                "median of %.3f s; most of it in %s; phase split %s",
+                self._replica, step, turn, median, phase, by_phase)
+            self._tracer.event("replica_slow_step", None,
+                               replica=self._replica, step=step,
+                               seconds=turn, phase=phase, split=by_phase)
+        recent.append(turn)
+
+
 def serve_replica(args, ctx) -> None:
     """The serving-tier ``map_fun``: serve generate requests until the
     driver sends ``EndOfFeed``."""
@@ -532,6 +591,26 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
     def busy() -> bool:
         return batcher.load()["total"] > 0
 
+    # the loop thread's phase spans (docs/observability.md "Profiler
+    # spans"): leaves that partition a loop turn; the batcher's own
+    # (admit, prefill/decode dispatch and fetch, emit) fall inside
+    # batcher.step() and share the clocks' family
+    spans = _obs.PhaseSpans()
+    slow_turns = _SlowTurns(reg, spans, tracer, ctx.executor_id)
+    turn_marks = _obs.step_marks(_obs.SERVE_STEP)
+
+    def next_item(free: bool, draining: bool):
+        """One read of the request queue.  With every slot busy the wait
+        is near zero (a control sweep); with nothing seated the replica
+        is waiting for requests, which is the ``idle`` phase."""
+        seated = busy()
+        timeout = (busy_poll if seated
+                   else (0.05 if draining else idle_poll)) if free else 0.001
+        if seated:
+            return mgr.queue_get(REQUEST_QUEUE, timeout=timeout)
+        with spans(_obs.SERVE_IDLE):
+            return mgr.queue_get(REQUEST_QUEUE, timeout=timeout)
+
     swap_base = base_args if base_args is not None else args
     #: pristine-base cache for delta-only adapter swaps (see
     #: resolve_version_params) — lives for the serve loop's lifetime
@@ -617,158 +696,159 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
     draining = False
     drain_started = 0.0
     guard = PreemptionGuard()
-    with guard:
+    with guard, turn_marks:
+        turn_marks.next(steps)
         while True:
-            if guard.preempted and not draining:
-                draining = True
-                drain_started = _time.monotonic()
-                logger.warning(
-                    "replica %d preempted: draining in-flight work, then "
-                    "exiting cleanly (grace poll %.1fs)", ctx.executor_id,
-                    preempt_grace)
-                tracer.event("replica_preempted", None,
-                             replica=ctx.executor_id,
-                             inflight=batcher.load()["total"])
-            if pending_swap is not None and not stopping \
-                    and carry is None and not busy():
-                # the driver drained this gang first, so the batcher is
-                # idle here; a swap racing early-routed work simply
-                # waits for the next idle step
-                item, pending_swap = pending_swap, None
-                got = apply_model_swap(item, step_delay)
-                if got is None:     # EndOfFeed landed mid-clone
-                    stopping = True
-                    break
-                step_delay = got
-            queue_idle = False
-            while not stopping:
-                free = batcher.has_free_slot()
-                if carry is not None:
-                    if not free:
+            with spans(_obs.SERVE_INTAKE):
+                if guard.preempted and not draining:
+                    draining = True
+                    drain_started = _time.monotonic()
+                    logger.warning(
+                        "replica %d preempted: draining in-flight work, then "
+                        "exiting cleanly (grace poll %.1fs)", ctx.executor_id,
+                        preempt_grace)
+                    tracer.event("replica_preempted", None,
+                                 replica=ctx.executor_id,
+                                 inflight=batcher.load()["total"])
+                if pending_swap is not None and not stopping \
+                        and carry is None and not busy():
+                    # the driver drained this gang first, so the batcher is
+                    # idle here; a swap racing early-routed work simply
+                    # waits for the next idle step
+                    item, pending_swap = pending_swap, None
+                    got = apply_model_swap(item, step_delay)
+                    if got is None:     # EndOfFeed landed mid-clone
+                        stopping = True
                         break
-                    item, carry = carry, None
-                else:
-                    try:
-                        # even with every slot busy, sweep the queue with
-                        # a near-zero timeout: CONTROL messages (clone,
-                        # EndOfFeed) must not starve behind a full batch
-                        # — a promoted standby's weight clone would
-                        # otherwise wait out the whole decode convoy
-                        item = mgr.queue_get(
-                            REQUEST_QUEUE,
-                            timeout=(busy_poll if busy()
-                                     else (0.05 if draining else idle_poll))
-                            if free else 0.001)
-                    except (_queue.Empty, TimeoutError):
-                        queue_idle = True
-                        break
-                    if not free and isinstance(item, dict) \
-                            and item.get("op") == "gen":
-                        # a gen request read during the control sweep:
-                        # hold it for the next free slot (it would have
-                        # sat at the queue head anyway)
-                        carry = item
-                        break
-                if isinstance(item, EndOfFeed):
-                    stopping = True
-                    break
-                if isinstance(item, Marker):
-                    continue
-                if isinstance(item, dict) and item.get("op") == "clone":
-                    # a promoted standby asks for this replica's weights
-                    serve_clone_request(
-                        batcher, item, ctx,
-                        export_pages=not args.get("serve_mesh"))
-                    continue
-                if isinstance(item, dict) and item.get("op") == "model":
-                    ev = item.get("event")
-                    if ev == "swap":
-                        # a hot swap: applied at the loop top once the
-                        # batcher is idle (the driver drained first, so
-                        # normally it already is)
-                        pending_swap = item
-                    elif ev == "cancel":
-                        # the driver's swap call gave up (ack timeout):
-                        # drop a swap not yet applied.  One already
-                        # applied (or mid-apply) acks late instead, and
-                        # the scheduler relabels on the late ack — the
-                        # routing label always tracks the served
-                        # version.
-                        pending_swap = None
-                    continue
-                if isinstance(item, dict) and item.get("op") == "prefix":
-                    ev = item.get("event")
-                    if ev == "export":
-                        # a decode gang asks for this pool's prefix
-                        # pages (cross-pool donation)
-                        serve_prefix_donation(batcher, item, ctx)
-                    elif ev == "pages":
-                        # a donated page set arrives: import as cached,
-                        # refcount-0, evictable pages — matchable by
-                        # the very next admission/adopt
+                    step_delay = got
+                queue_idle = False
+                while not stopping:
+                    free = batcher.has_free_slot()
+                    if carry is not None:
+                        if not free:
+                            break
+                        item, carry = carry, None
+                    else:
                         try:
-                            importer = getattr(batcher,
-                                               "import_prefix_cache",
-                                               None)
-                            n = (0 if importer is None
-                                 else importer(item.get("export")))
-                            if n:
-                                _donation_counter().inc(
-                                    n, direction="imported")
-                            logger.info(
-                                "replica %d imported %d donated prefix "
-                                "page(s) from %s", ctx.executor_id, n,
-                                item.get("src"))
-                        # tfos: ignore[broad-except] — a corrupt/
-                        # mismatched donation is rejected by the hash/
-                        # layout checks; the replica serves on
-                        except Exception:
-                            logger.exception(
-                                "replica %d: donated prefix-page import "
-                                "failed", ctx.executor_id)
-                    continue
-                if isinstance(item, dict) and item.get("op") == "adopt":
-                    # a handed-off session: seat it without re-prefilling.
-                    # adopt_session verifies layout + per-page content
-                    # hashes HERE — a corrupt or raced transfer raises
-                    # before any device write and bounces back typed,
-                    # the engine stays healthy
+                            # even with every slot busy, sweep the queue with
+                            # a near-zero timeout: CONTROL messages (clone,
+                            # EndOfFeed) must not starve behind a full batch
+                            # — a promoted standby's weight clone would
+                            # otherwise wait out the whole decode convoy
+                            item = next_item(free, draining)
+                        except (_queue.Empty, TimeoutError):
+                            queue_idle = True
+                            break
+                        if not free and isinstance(item, dict) \
+                                and item.get("op") == "gen":
+                            # a gen request read during the control sweep:
+                            # hold it for the next free slot (it would have
+                            # sat at the queue head anyway)
+                            carry = item
+                            break
+                    if isinstance(item, EndOfFeed):
+                        stopping = True
+                        break
+                    if isinstance(item, Marker):
+                        continue
+                    if isinstance(item, dict) and item.get("op") == "clone":
+                        # a promoted standby asks for this replica's weights
+                        serve_clone_request(
+                            batcher, item, ctx,
+                            export_pages=not args.get("serve_mesh"))
+                        continue
+                    if isinstance(item, dict) and item.get("op") == "model":
+                        ev = item.get("event")
+                        if ev == "swap":
+                            # a hot swap: applied at the loop top once the
+                            # batcher is idle (the driver drained first, so
+                            # normally it already is)
+                            pending_swap = item
+                        elif ev == "cancel":
+                            # the driver's swap call gave up (ack timeout):
+                            # drop a swap not yet applied.  One already
+                            # applied (or mid-apply) acks late instead, and
+                            # the scheduler relabels on the late ack — the
+                            # routing label always tracks the served
+                            # version.
+                            pending_swap = None
+                        continue
+                    if isinstance(item, dict) and item.get("op") == "prefix":
+                        ev = item.get("event")
+                        if ev == "export":
+                            # a decode gang asks for this pool's prefix
+                            # pages (cross-pool donation)
+                            serve_prefix_donation(batcher, item, ctx)
+                        elif ev == "pages":
+                            # a donated page set arrives: import as cached,
+                            # refcount-0, evictable pages — matchable by
+                            # the very next admission/adopt
+                            try:
+                                importer = getattr(batcher,
+                                                   "import_prefix_cache",
+                                                   None)
+                                n = (0 if importer is None
+                                     else importer(item.get("export")))
+                                if n:
+                                    _donation_counter().inc(
+                                        n, direction="imported")
+                                logger.info(
+                                    "replica %d imported %d donated prefix "
+                                    "page(s) from %s", ctx.executor_id, n,
+                                    item.get("src"))
+                            # tfos: ignore[broad-except] — a corrupt/
+                            # mismatched donation is rejected by the hash/
+                            # layout checks; the replica serves on
+                            except Exception:
+                                logger.exception(
+                                    "replica %d: donated prefix-page import "
+                                    "failed", ctx.executor_id)
+                        continue
+                    if isinstance(item, dict) and item.get("op") == "adopt":
+                        # a handed-off session: seat it without re-prefilling.
+                        # adopt_session verifies layout + per-page content
+                        # hashes HERE — a corrupt or raced transfer raises
+                        # before any device write and bounces back typed,
+                        # the engine stays healthy
+                        try:
+                            brid = batcher.adopt_session(item["session"],
+                                                         on_token=on_token)
+                        except ValueError as e:
+                            mgr.queue_put(RESPONSE_QUEUE,
+                                          {"rid": item.get("rid"),
+                                           "event": "error", "error": str(e),
+                                           **role_extra})
+                            continue
+                        rid_map[brid] = (item["rid"], item.get("trace"))
+                        tracer.event(
+                            "replica_adopt", item.get("trace"),
+                            rid=item["rid"], replica=ctx.executor_id,
+                            pages=int(item["session"].get("pages", 0)))
+                        continue
+                    if not (isinstance(item, dict)
+                            and item.get("op") == "gen"):
+                        logger.warning(
+                            "replica %d: ignoring non-request item %r",
+                            ctx.executor_id, type(item))
+                        continue
                     try:
-                        brid = batcher.adopt_session(item["session"],
-                                                     on_token=on_token)
+                        brid = batcher.submit(
+                            item["prompt"], int(item["max_new_tokens"]),
+                            temperature=float(item.get("temperature", 0.0)),
+                            top_p=float(item.get("top_p", 1.0)),
+                            seed=int(item.get("seed", 0)), on_token=on_token)
                     except ValueError as e:
+                        # a malformed request must not kill the replica; bounce
+                        # the typed error back to the scheduler
                         mgr.queue_put(RESPONSE_QUEUE,
                                       {"rid": item.get("rid"),
-                                       "event": "error", "error": str(e),
-                                       **role_extra})
+                                       "event": "error",
+                                       "error": str(e), **role_extra})
                         continue
                     rid_map[brid] = (item["rid"], item.get("trace"))
-                    tracer.event(
-                        "replica_adopt", item.get("trace"),
-                        rid=item["rid"], replica=ctx.executor_id,
-                        pages=int(item["session"].get("pages", 0)))
-                    continue
-                if not (isinstance(item, dict) and item.get("op") == "gen"):
-                    logger.warning("replica %d: ignoring non-request item %r",
-                                   ctx.executor_id, type(item))
-                    continue
-                try:
-                    brid = batcher.submit(
-                        item["prompt"], int(item["max_new_tokens"]),
-                        temperature=float(item.get("temperature", 0.0)),
-                        top_p=float(item.get("top_p", 1.0)),
-                        seed=int(item.get("seed", 0)), on_token=on_token)
-                except ValueError as e:
-                    # a malformed request must not kill the replica; bounce
-                    # the typed error back to the scheduler
-                    mgr.queue_put(RESPONSE_QUEUE,
-                                  {"rid": item.get("rid"), "event": "error",
-                                   "error": str(e), **role_extra})
-                    continue
-                rid_map[brid] = (item["rid"], item.get("trace"))
-                tracer.event("replica_intake", item.get("trace"),
-                             rid=item["rid"], replica=ctx.executor_id,
-                             prompt_tokens=len(item["prompt"]))
+                    tracer.event("replica_intake", item.get("trace"),
+                                 rid=item["rid"], replica=ctx.executor_id,
+                                 prompt_tokens=len(item["prompt"]))
             if not busy():
                 if stopping:
                     break
@@ -778,83 +858,91 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
                 continue
             done = batcher.step()
             if step_delay:
-                _time.sleep(step_delay)
+                # a stand-in for a slow model's device step: the loop
+                # waits on it as it waits on the device
+                with spans(_obs.BATCHER_DECODE_FETCH):
+                    _time.sleep(step_delay)
             steps += 1
-            # serving-phase heartbeat: arms the hang watchdog on the decode
-            # loop and gives chaos its at_step trigger.  A draining replica
-            # reports phase 'preempted' — every step would otherwise clobber
-            # the preemption flip back to 'serving' and the driver would
-            # never see the grace window (it drains-and-replaces off this).
-            # guard.preempted, not just `draining`: a SIGTERM landing MID-
-            # iteration (after the loop-top check) must not have this very
-            # step publish 'serving' over note_preempted's flip — if the
-            # batcher idles right after, no later step would ever correct it
-            ctx.report_step(steps,
-                            phase="preempted" if (draining or guard.preempted)
-                            else "serving")
-            ld = batcher.load()
-            load = ld["total"]
-            free_pages = int(ld.get("free_pages", 0))
-            # acceptance piggyback: cumulative proposed/accepted ride
-            # every response message of a speculating replica, so the
-            # scheduler's metrics()["replicas"] shows tokens-per-
-            # dispatch without log scraping
-            spec_extra = {} if getattr(batcher, "spec_k", None) is None \
-                else {"spec": {"proposed": batcher.spec_proposed,
-                               "accepted": batcher.spec_accepted}}
-            m_steps.inc()
-            g_load.set(load)
-            g_pages.set(free_pages)
-            publish_engine_counters()
-            for brid, toks in deltas.items():
-                rid, trace = rid_map[brid]
-                if brid not in first_sent:
-                    first_sent.add(brid)
-                    tracer.event("replica_first_token", trace, rid=rid,
-                                 replica=ctx.executor_id)
-                m_tokens.inc(len(toks))
-                mgr.queue_put(RESPONSE_QUEUE,
-                              {"rid": rid, "event": "tok",
-                               "tokens": toks, "load": load,
-                               "free_pages": free_pages, **spec_extra,
-                               **role_extra})
-            deltas.clear()
-            for brid in done:
-                batcher.result(brid, pop=True)  # tokens already streamed
-                rid, trace = rid_map.pop(brid)
-                first_sent.discard(brid)
-                tracer.event("replica_done", trace, rid=rid,
-                             replica=ctx.executor_id)
-                m_served.inc()
-                mgr.queue_put(RESPONSE_QUEUE,
-                              {"rid": rid, "event": "done", "load": load,
-                               "free_pages": free_pages, **spec_extra,
-                               **role_extra})
-                served += 1
-            if role == "prefill":
-                # prefill pool: flush each admitted request's exported
-                # session AFTER its first-token delta (same queue, FIFO:
-                # the driver sees TTFT close before the handoff).  The
-                # session's KV pages ride the queue/shm plane like any
-                # bulk tensor — zero-copy on a shared host.
-                for brid, session in batcher.take_sessions():
+            with spans(_obs.SERVE_PUBLISH):
+                # serving-phase heartbeat: arms the hang watchdog on the
+                # decode loop and gives chaos its at_step trigger.  A
+                # draining replica reports phase 'preempted' — every step
+                # would otherwise clobber the preemption flip back to
+                # 'serving' and the driver would never see the grace window
+                # (it drains-and-replaces off this).  guard.preempted, not
+                # just `draining`: a SIGTERM landing MID-iteration (after
+                # the loop-top check) must not have this very step publish
+                # 'serving' over note_preempted's flip — if the batcher
+                # idles right after, no later step would ever correct it
+                ctx.report_step(
+                    steps, phase="preempted"
+                    if (draining or guard.preempted) else "serving")
+                ld = batcher.load()
+                load = ld["total"]
+                free_pages = int(ld.get("free_pages", 0))
+                # acceptance piggyback: cumulative proposed/accepted ride
+                # every response message of a speculating replica, so the
+                # scheduler's metrics()["replicas"] shows tokens-per-
+                # dispatch without log scraping
+                spec_extra = {} if getattr(batcher, "spec_k", None) is None \
+                    else {"spec": {"proposed": batcher.spec_proposed,
+                                   "accepted": batcher.spec_accepted}}
+                m_steps.inc()
+                g_load.set(load)
+                g_pages.set(free_pages)
+                publish_engine_counters()
+            with spans(_obs.SERVE_FLUSH):
+                for brid, toks in deltas.items():
+                    rid, trace = rid_map[brid]
+                    if brid not in first_sent:
+                        first_sent.add(brid)
+                        tracer.event("replica_first_token", trace, rid=rid,
+                                     replica=ctx.executor_id)
+                    m_tokens.inc(len(toks))
+                    mgr.queue_put(RESPONSE_QUEUE,
+                                  {"rid": rid, "event": "tok",
+                                   "tokens": toks, "load": load,
+                                   "free_pages": free_pages, **spec_extra,
+                                   **role_extra})
+                deltas.clear()
+                for brid in done:
+                    batcher.result(brid, pop=True)  # tokens already streamed
                     rid, trace = rid_map.pop(brid)
                     first_sent.discard(brid)
-                    tracer.event(
-                        "replica_handoff", trace, rid=rid,
-                        replica=ctx.executor_id,
-                        pages=int(session.get("pages", 0)),
-                        bytes=int(sum(a.nbytes for a in session["kv"])))
+                    tracer.event("replica_done", trace, rid=rid,
+                                 replica=ctx.executor_id)
+                    m_served.inc()
                     mgr.queue_put(RESPONSE_QUEUE,
-                                  {"rid": rid, "event": "handoff",
-                                   "session": session, "load": load,
-                                   "free_pages": free_pages,
+                                  {"rid": rid, "event": "done", "load": load,
+                                   "free_pages": free_pages, **spec_extra,
                                    **role_extra})
                     served += 1
-            if step_hook is not None:
-                # gang barrier AFTER the step's deltas are flushed, so
-                # barrier latency never delays token delivery
-                step_hook(steps, load)
+                if role == "prefill":
+                    # prefill pool: flush each admitted request's exported
+                    # session AFTER its first-token delta (same queue, FIFO:
+                    # the driver sees TTFT close before the handoff).  The
+                    # session's KV pages ride the queue/shm plane like any
+                    # bulk tensor — zero-copy on a shared host.
+                    for brid, session in batcher.take_sessions():
+                        rid, trace = rid_map.pop(brid)
+                        first_sent.discard(brid)
+                        tracer.event(
+                            "replica_handoff", trace, rid=rid,
+                            replica=ctx.executor_id,
+                            pages=int(session.get("pages", 0)),
+                            bytes=int(sum(a.nbytes for a in session["kv"])))
+                        mgr.queue_put(RESPONSE_QUEUE,
+                                      {"rid": rid, "event": "handoff",
+                                       "session": session, "load": load,
+                                       "free_pages": free_pages,
+                                       **role_extra})
+                        served += 1
+                if step_hook is not None:
+                    # gang barrier AFTER the step's deltas are flushed, so
+                    # barrier latency never delays token delivery
+                    step_hook(steps, load)
+            slow_turns.turn_done(steps)
+            turn_marks.next(steps)
     logger.info("%s %d %s: %d requests over %d steps "
                 "(%d prefill + %d decode dispatches)", label,
                 ctx.executor_id,

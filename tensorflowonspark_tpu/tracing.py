@@ -59,10 +59,13 @@ def new_trace_id() -> str:
 
 
 class Tracer:
-    """Span emitter for one process: appends ``{"t", "kind", "trace",
-    ...}`` records to a ``trace_events.jsonl``.  Emission failures are
-    absorbed by :class:`~tensorflowonspark_tpu.observability.EventLog`'s
-    post-close degrade — tracing must never take down serving."""
+    """Event emitter for one process: appends ``{"t", "kind", "trace",
+    ...}`` records to a ``trace_events.jsonl``, on the wall clock
+    (``time.time()``) — where request X was, not what the host did while
+    the device idled (that is ``observability.span``, on the profiler's
+    clock).  Emission failures are absorbed by
+    :class:`~tensorflowonspark_tpu.observability.EventLog`'s post-close
+    degrade — tracing must never take down serving."""
 
     def __init__(self, path: str | None):
         # echo=False: spans fire per request on the decode loop — they
@@ -75,9 +78,14 @@ class Tracer:
         return self._log is not None
 
     def event(self, kind: str, trace: str | None, **fields) -> None:
-        if self._log is None or trace is None:
+        """Append one event.  ``trace=None`` is an event of the process and
+        of no one request (``replica_preempted``, ``replica_slow_step``):
+        recorded without a ``trace`` key, so no stitched timeline owns it."""
+        if self._log is None:
             return
-        self._log.emit(kind, trace=trace, **fields)
+        if trace is not None:
+            fields = {"trace": trace, **fields}
+        self._log.emit(kind, **fields)
 
     def close(self) -> None:
         if self._log is not None:

@@ -58,9 +58,12 @@ def test_profile_trace_writes_events(tmp_path):
     assert found, "no profiler output written"
 
 
-def test_annotate_smoke():
-    with observability.annotate("mystep"):
-        pass
+def test_span_smoke():
+    """The one span primitive (the rest is tests/test_phase_spans.py):
+    usable with no profiler session and no clock."""
+    with observability.span("tfos/test/mystep") as sp:
+        assert sp.name == "tfos/test/mystep"
+    assert getattr(observability._open_span, "span", None) is None
 
 
 # -- tensorboard spawn -----------------------------------------------------

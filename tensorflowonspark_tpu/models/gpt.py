@@ -109,8 +109,12 @@ class GPTConfig:
     # PAGED decode KV cache (vLLM-style): instead of a dense
     # ``[B, max_len]`` K/V block per layer, allocate a POOL of
     # ``kv_pool_pages`` fixed-size pages of ``kv_page_tokens`` tokens
-    # (``[pool_pages * page_tokens, Hkv, D]`` per layer — the head axis
-    # keeps its tp sharding) plus a per-row ``block_table`` cache
+    # (``[pool_pages * page_tokens, W]`` per layer, ``[L, ..]`` stacked
+    # under scan_layers: one token's Hkv heads of D side by side in a
+    # row of ``W = kv_row_width(Hkv, D)`` lanes, Hkv*D rounded up to
+    # whole 128-lane tiles so the device's own layout for the pool is
+    # row-major and the token scatter stores in place; an exported page
+    # is ``[page_tokens, W]``) plus a per-row ``block_table`` cache
     # variable mapping logical page -> physical page.  Each step WRITES
     # through the table (positions past a row's allocated pages, or past
     # max_position_embeddings, are dropped — the unallocated sentinel
@@ -182,6 +186,15 @@ class GPTConfig:
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_heads
+
+
+def kv_row_width(num_kv_heads: int, head_dim: int) -> int:
+    """Width ``W`` of one token's row in the paged K/V pool: the heads
+    side by side, rounded up to whole 128-lane tiles.  The device lays an
+    array out from its shape alone, to waste the least padding; a last
+    axis of whole lane tiles is what makes that layout row-major, the one
+    the token scatter writes in place (gpt2-xl 25 x 64 = 1600 -> 1664)."""
+    return -(-num_kv_heads * head_dim // 128) * 128
 
 
 def _rope(x, positions, base: float):
@@ -275,22 +288,25 @@ class CausalSelfAttention(nn.Module):
             idx = ci.value
             paged = cfg.kv_page_tokens is not None
             if paged:
-                # Paged pool: per-layer K/V is [P*pt, Hkv, D]; the per-row
-                # block table (a cache variable, written host-side by the
-                # batcher's admission scatter) maps logical page -> physical
-                # page, sentinel P = unallocated.  Writes route each
-                # position through the table and DROP out-of-range ones
-                # (unallocated page, or position >= max_len — e.g. a
-                # parked/finished row whose counter sits at C, or a
-                # speculative verify overshooting its budget); reads
-                # gather the row's full logical view [B, C, Hkv, D] back
-                # in ONE page gather (the sentinel clamps to garbage the
-                # positional mask hides), after which the shared per-row
-                # mask + grouped attention below apply unchanged — only
-                # the store/gather substrate differs from dense.
+                # Paged pool: per-layer K/V is [P*pt, W] — one token's
+                # heads side by side in a row of whole 128-lane tiles
+                # (kv_row_width); the per-row block table (a cache
+                # variable, written host-side by the batcher's admission
+                # scatter) maps logical page -> physical page, sentinel
+                # P = unallocated.  Writes route each position through
+                # the table and DROP out-of-range ones (unallocated page,
+                # or position >= max_len — e.g. a parked/finished row
+                # whose counter sits at C, or a speculative verify
+                # overshooting its budget); reads gather the row's full
+                # logical view [B, C, Hkv, D] back in ONE page gather
+                # (the sentinel clamps to garbage the positional mask
+                # hides), after which the shared per-row mask + grouped
+                # attention below apply unchanged — only the store/gather
+                # substrate differs from dense.
                 pt = cfg.kv_page_tokens
                 P = cfg.kv_pool_pages
                 npg = C // pt
+                W = kv_row_width(Hkv, D)
                 cbt = self.variable(
                     "cache", "block_table",
                     lambda: jnp.full((B, npg), P, jnp.int32))
@@ -304,13 +320,15 @@ class CausalSelfAttention(nn.Module):
                             axis=1)
                         phys = jnp.where(pos < C, page * pt + pos % pt,
                                          P * pt)
-                        ref.value = ref.value.at[phys].set(
-                            x.astype(ref.value.dtype), mode="drop")
+                        row = jnp.pad(
+                            x.astype(ref.value.dtype).reshape(
+                                B, Tw, Hkv * D),
+                            ((0, 0), (0, 0), (0, W - Hkv * D)))
+                        ref.value = ref.value.at[phys].set(row, mode="drop")
                     with jax.named_scope("kv_gather"):
-                        pool = ref.value.reshape(P, pt,
-                                                 *ref.value.shape[1:])
-                        return pool[cbt.value].reshape(
-                            B, C, *ref.value.shape[1:])
+                        pool = ref.value.reshape(P, pt, W)
+                        return pool[cbt.value][..., :Hkv * D].reshape(
+                            B, C, Hkv, D)
             else:
                 def dense_store(ref, x):
                     """Write positions idx..idx+T-1 (keeping only the last
@@ -341,9 +359,9 @@ class CausalSelfAttention(nn.Module):
 
             if paged:
                 ck = self.variable("cache", "k", jnp.zeros,
-                                   (P * pt, Hkv, D), cfg.dtype)
+                                   (P * pt, W), cfg.dtype)
                 cv = self.variable("cache", "v", jnp.zeros,
-                                   (P * pt, Hkv, D), cfg.dtype)
+                                   (P * pt, W), cfg.dtype)
                 k_all = store(ck, k.astype(cfg.dtype))
                 v_all = store(cv, v.astype(cfg.dtype))
             elif cfg.kv_cache_int8:

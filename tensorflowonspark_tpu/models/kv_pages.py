@@ -82,14 +82,15 @@ def hash_page_data(arrays, n_pages: int) -> list[bytes]:
     digest covers its slice of EVERY leaf (all layers, K and V), so a
     corrupt or torn transfer of any byte of a page fails verification.
     ``arrays`` are the batcher's gathered pool leaves — page axis at
-    ``ndim - 4`` (``[..., page, page_tokens, heads, head_dim]``)."""
+    ``-3`` (``[..., page, page_tokens, W]``, ``W`` the pool's token
+    row: ``models.gpt.kv_row_width``)."""
     out: list[bytes] = []
     for j in range(int(n_pages)):
         h = hashlib.blake2b(digest_size=16)
         for a in arrays:
             a = np.asarray(a)
             h.update(np.ascontiguousarray(
-                np.take(a, j, axis=a.ndim - 4)).tobytes())
+                np.take(a, j, axis=-3)).tobytes())
         out.append(h.digest())
     return out
 

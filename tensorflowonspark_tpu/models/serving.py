@@ -92,6 +92,19 @@ PROGRAM_NAMES = {
     "pexport": "tfos_kv_export", "draft_propose": "tfos_draft"}
 
 
+#: the page axis of a paged pool leaf seen as pages (:func:`_as_pages`)
+#: and of an exported page array ``[..., n, pt, W]``
+_PAGE_AXIS = -3
+
+
+def _as_pages(leaf, pages: int, page_tokens: int):
+    """A paged pool leaf ``[P*pt, W]`` (``[L, P*pt, W]`` under
+    ``scan_layers``; ``W`` = ``models.gpt.kv_row_width``) viewed as
+    ``[..., P, pt, W]``."""
+    return leaf.reshape(leaf.shape[:-2] + (pages, page_tokens,
+                                           leaf.shape[-1]))
+
+
 def _next_pow2(n: int) -> int:
     return 1 << (n - 1).bit_length()
 
@@ -769,9 +782,8 @@ class ContinuousBatcher:
         for path, leaf in jax.tree_util.tree_flatten_with_path(
                 self.cache)[0]:
             if getattr(path[-1], "key", None) in ("k", "v"):
-                ax = leaf.ndim - 3
-                out.append((tuple(int(d) for d in
-                            leaf.shape[:ax] + (pt,) + leaf.shape[ax + 1:]),
+                out.append((tuple(int(d) for d in leaf.shape[:-2]
+                                  + (pt, leaf.shape[-1])),
                             str(leaf.dtype)))
         return out
 
@@ -793,10 +805,8 @@ class ContinuousBatcher:
 
                 def walk(path, leaf):
                     if getattr(path[-1], "key", None) in ("k", "v"):
-                        ax = leaf.ndim - 3
-                        pool = leaf.reshape(leaf.shape[:ax] + (P, pt)
-                                            + leaf.shape[ax + 1:])
-                        out.append(jnp.take(pool, ids, axis=ax))
+                        out.append(jnp.take(_as_pages(leaf, P, pt), ids,
+                                            axis=_PAGE_AXIS))
                     return leaf
 
                 jax.tree_util.tree_map_with_path(walk, cache)
@@ -810,7 +820,7 @@ class ContinuousBatcher:
         for a in got:
             a = np.asarray(a)
             if npad != n:   # drop the pad pages (they gathered page 0)
-                a = np.take(a, range(n), axis=a.ndim - 4)
+                a = np.take(a, range(n), axis=_PAGE_AXIS)
             out.append(a)
         return out
 
@@ -836,14 +846,13 @@ class ContinuousBatcher:
                 def put(path, leaf):
                     k = getattr(path[-1], "key", None)
                     if k in ("k", "v"):
-                        ax = leaf.ndim - 3
-                        pool = leaf.reshape(leaf.shape[:ax] + (P, pt)
-                                            + leaf.shape[ax + 1:])
-                        m = jnp.moveaxis(pool, ax, 0)
+                        m = jnp.moveaxis(_as_pages(leaf, P, pt),
+                                         _PAGE_AXIS, 0)
                         blk = jnp.moveaxis(next(it).astype(leaf.dtype),
-                                           ax, 0)
+                                           _PAGE_AXIS, 0)
                         m = m.at[ids].set(blk, mode="drop")
-                        return jnp.moveaxis(m, 0, ax).reshape(leaf.shape)
+                        return jnp.moveaxis(m, 0, _PAGE_AXIS).reshape(
+                            leaf.shape)
                     if k == "block_table":
                         m = jnp.moveaxis(leaf, -2, 0)
                         v = jnp.broadcast_to(row_bt,
@@ -866,10 +875,9 @@ class ContinuousBatcher:
         ids[:n] = import_ids
         kv_pad = []
         for i, (shape, dt) in enumerate(self._kv_struct()):
-            ax = len(shape) - 3
-            buf = np.zeros(shape[:ax] + (npad,) + shape[ax:], dt)
+            buf = np.zeros(shape[:-2] + (npad,) + shape[-2:], dt)
             if n:
-                buf[(slice(None),) * ax + (slice(0, n),)] = kv_sel[i]
+                buf[..., :n, :, :] = kv_sel[i]
             kv_pad.append(buf)
         row_bt = np.full((npg,), P, np.int32)
         row_bt[:len(row_pages)] = row_pages
@@ -957,9 +965,8 @@ class ContinuousBatcher:
         if ok_shape:
             for a, (shape, dt) in zip(kv, struct):
                 a = np.asarray(a)
-                ax = a.ndim - 4
-                if a.ndim < 4 or a.shape[ax] != n_pp \
-                        or tuple(a.shape[:ax] + a.shape[ax + 1:]) != shape \
+                if a.ndim < 3 or a.shape[_PAGE_AXIS] != n_pp \
+                        or a.shape[:-3] + a.shape[-2:] != shape \
                         or str(a.dtype) != dt:
                     ok_shape = False
                     break
@@ -1020,8 +1027,8 @@ class ContinuousBatcher:
             kv_sel = []
             if import_ids:
                 sel = range(lease.n_shared, n_pp)
-                kv_sel = [np.take(np.asarray(a), sel, axis=a.ndim - 4)
-                          for a in (np.asarray(x) for x in sess["kv"])]
+                kv_sel = [np.take(np.asarray(a), sel, axis=_PAGE_AXIS)
+                          for a in sess["kv"]]
             # counters seat at prompt.size: the next decode step feeds
             # the session's first token and writes its K/V there, exactly
             # where a locally-prefilled slot would
@@ -1086,7 +1093,7 @@ class ContinuousBatcher:
         pos_of = {k: i for i, k in enumerate(export["keys"])}
         keys = list(mapping)
         sel = [pos_of[k] for k in keys]
-        kv_sel = [np.take(np.asarray(a), sel, axis=np.asarray(a).ndim - 4)
+        kv_sel = [np.take(np.asarray(a), sel, axis=_PAGE_AXIS)
                   for a in kv]
         # slot = max_batch: the seat drops — this dispatch only writes
         # the imported pages into the pools

@@ -101,3 +101,70 @@ def test_flash_attention_long_context_fits_scoped_vmem(one_chip, T, H,
     compiled = _compile_flash(one_chip, B=1, T=T, H=H, D=128, causal=True,
                               backward=backward)
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# -- the paged pool's layout (ISSUE 26) -----------------------------------
+# The device lays an array out from its shape alone.  A pool whose token
+# row is whole 128-lane tiles (``models.gpt.kv_row_width``) comes in
+# row-major, the layout the token scatter writes in, so the store runs on
+# the donated parameter; a pool of another shape is re-laid before the
+# scatter and back after it, once per pool per step.
+
+_POOL_WIDTHS = {"gpt2xl_25x64": dict(num_heads=25, hidden_size=1600),
+                "gpt2_12x64": dict(num_heads=12, hidden_size=768)}
+_POOL_PROGRAMS = {"decode_B16_T1": (16, 1), "prefill_B1_T256": (1, 256)}
+
+
+def _compile_paged_step(one_chip, B, T, **widths):
+    """The paged step through the repo's ``GPT`` (2 layers, the cell's
+    1024 x 16-token pool, cache donated), compiled for the described
+    chip from shapes alone."""
+    from tensorflowonspark_tpu.models.gpt import GPT, GPTConfig, init_cache
+
+    cfg = GPTConfig(num_layers=2, intermediate_size=4 * widths["hidden_size"],
+                    per_row_positions=True, kv_page_tokens=16,
+                    kv_pool_pages=1024, **widths)
+    model = GPT(cfg, decode=True)
+    params = jax.eval_shape(
+        lambda: GPT(cfg).init(jax.random.key(0),
+                              jnp.zeros((1, 8), jnp.int32))["params"])
+    cache = jax.eval_shape(lambda p: init_cache(cfg, p, B), params)
+
+    def step(params, cache, tokens):
+        logits, vars_ = model.apply({"params": params, "cache": cache},
+                                    tokens, mutable=["cache"])
+        return jnp.argmax(logits[:, -1], -1), vars_["cache"]
+
+    def on_chip(tree):
+        return jax.tree.map(lambda t: jax.ShapeDtypeStruct(
+            t.shape, t.dtype, sharding=one_chip), tree)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache),
+        jax.ShapeDtypeStruct((B, T), jnp.int32, sharding=one_chip)).compile()
+    return cfg, compiled.as_text()
+
+
+@pytest.mark.parametrize("program", sorted(_POOL_PROGRAMS))
+@pytest.mark.parametrize("widths", sorted(_POOL_WIDTHS))
+def test_paged_pool_is_stored_in_place_on_v5e(one_chip, widths, program):
+    import re
+
+    B, T = _POOL_PROGRAMS[program]
+    cfg, text = _compile_paged_step(one_chip, B, T, **_POOL_WIDTHS[widths])
+    pool_rows = cfg.kv_pool_pages * cfg.kv_page_tokens
+    entry = text[text.index("\nENTRY "):].splitlines()
+
+    pools = [ln for ln in entry if " parameter(" in ln
+             and re.search(rf"= bf16\[{pool_rows},\d+\]", ln)]
+    assert len(pools) == 2 * cfg.num_layers, pools
+    assert all(re.search(rf"= bf16\[{pool_rows},\d+\]\{{1,0[:}}]", ln)
+               for ln in pools), "a pool parameter does not enter row-major"
+
+    # a copy of the pool: its leading axes are the pool's tokens, flat or
+    # as pages (the embedding table, re-laid every step, is larger still
+    # and not this test's)
+    tokens = rf"{pool_rows}|{cfg.kv_pool_pages},{cfg.kv_page_tokens}"
+    copies = [ln.strip()[:120] for ln in entry
+              if re.search(rf"= \w+\[({tokens}),[\d,]+\]\S* copy\(", ln)]
+    assert not copies, copies

@@ -264,13 +264,16 @@ def test_adopt_cached_imports_in_order_and_respects_capacity():
     assert tiny.match_tokens(probe) == 8
 
 
-def test_hash_page_data_detects_single_byte_corruption():
+@pytest.mark.parametrize("stack", [(), (3,)], ids=["leaf", "scan_layers"])
+def test_hash_page_data_detects_single_byte_corruption(stack):
+    """Exported pages are ``[..., n, page_tokens, W]`` (one token's heads
+    in one row), a ``scan_layers`` stack leading: page axis -3."""
     from tensorflowonspark_tpu.models.kv_pages import hash_page_data
 
-    arrays = [np.arange(2 * 4 * 2 * 3, dtype=np.float32)
-              .reshape(2, 4, 2, 3)]
+    shape = stack + (2, 4, 6)
+    arrays = [np.arange(np.prod(shape), dtype=np.float32).reshape(shape)]
     good = hash_page_data(arrays, 2)
     bad = [np.array(arrays[0], copy=True)]
-    bad[0][1, 2, 1, 1] += 1e-3
+    bad[0][..., 1, 2, 3] += 1e-3
     hashes = hash_page_data(bad, 2)
     assert hashes[0] == good[0] and hashes[1] != good[1]

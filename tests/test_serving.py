@@ -1366,3 +1366,142 @@ def test_export_import_prefix_cache_roundtrip_exact():
     dense = ContinuousBatcher(cfg, params, max_batch=2)
     assert dense.export_prefix_cache() is None
     assert dense.import_prefix_cache(export) == 0
+
+
+# -- the pool's token row (models.gpt.kv_row_width) -----------------------
+# one token's K (or V) heads lie side by side in a row padded to whole
+# 128-lane tiles: [P*pt, W], [L, P*pt, W] under scan_layers
+
+_ROWS = {"pad_160_to_256": dict(hidden_size=160, num_heads=5),
+         "whole_128": dict(hidden_size=128, num_heads=4)}
+_row_params = pytest.mark.parametrize("row", sorted(_ROWS))
+_scan_params = pytest.mark.parametrize("scan", [False, True],
+                                       ids=["layers", "scan_layers"])
+
+
+def _make_row(row, scan=False):
+    cfg = GPTConfig(vocab_size=61, num_layers=2, intermediate_size=64,
+                    max_position_embeddings=32, dtype=jnp.float32,
+                    scan_layers=scan, **_ROWS[row])
+    params = GPT(cfg).init(jax.random.key(0),
+                           jnp.ones((1, 4), jnp.int32))["params"]
+    return cfg, params
+
+
+def _pool_leaves(cache):
+    return [leaf for path, leaf in
+            jax.tree_util.tree_flatten_with_path(cache)[0]
+            if getattr(path[-1], "key", None) in ("k", "v")]
+
+
+@_scan_params
+@_row_params
+def test_paged_logits_match_dense(row, scan):
+    """The same prefill and decode steps through the paged pool (rows
+    routed through a shuffled block table) and through the dense per-row
+    cache give the same logits: only the store/gather substrate differs.
+    The pool's leaves are ``[.., P*pt, W]`` and the pad lanes stay 0."""
+    import dataclasses
+
+    from tensorflowonspark_tpu.models.gpt import init_cache, kv_row_width
+
+    base, params = _make_row(row, scan)
+    B, pt = 2, 8
+    npg = base.max_position_embeddings // pt
+    dense = dataclasses.replace(base, per_row_positions=True)
+    paged = dataclasses.replace(dense, kv_page_tokens=pt,
+                                kv_pool_pages=B * npg)
+    W = kv_row_width(base.num_heads, base.head_dim)
+    assert W % 128 == 0 and 0 <= W - base.hidden_size < 128
+
+    table = np.random.default_rng(7).permutation(B * npg) \
+        .reshape(B, npg).astype(np.int32)
+    caches = {
+        "dense": init_cache(dense, params, B),
+        "paged": jax.tree_util.tree_map_with_path(
+            lambda path, leaf: jnp.broadcast_to(table, leaf.shape)
+            if getattr(path[-1], "key", None) == "block_table" else leaf,
+            init_cache(paged, params, B))}
+    lead = (base.num_layers,) if scan else ()
+    for leaf in _pool_leaves(caches["paged"]):
+        assert leaf.shape == lead + (B * npg * pt, W)
+
+    rng = np.random.default_rng(8)
+    feeds = [rng.integers(0, base.vocab_size, (B, t)).astype(np.int32)
+             for t in (5, 1, 1, 3, 1)]
+    for tokens in feeds:
+        logits = {}
+        for name, cfg in (("dense", dense), ("paged", paged)):
+            logits[name], vars_ = GPT(cfg, decode=True).apply(
+                {"params": params, "cache": caches[name]}, tokens,
+                mutable=["cache"])
+            caches[name] = vars_["cache"]
+        np.testing.assert_allclose(logits["paged"], logits["dense"],
+                                   rtol=1e-5, atol=1e-5)
+    for leaf in _pool_leaves(caches["paged"]):
+        assert np.any(np.asarray(leaf[..., :base.hidden_size]))
+        assert not np.any(np.asarray(leaf[..., base.hidden_size:]))
+
+
+@_scan_params
+@_row_params
+def test_export_then_seat_returns_the_same_pages(row, scan):
+    """Export -> seat between two batchers: the importer's pool gives
+    back the donor's pages byte for byte (``[.., n, pt, W]`` each), and
+    decoding against them is oracle-exact."""
+    from tensorflowonspark_tpu.models.gpt import kv_row_width
+
+    cfg, params = _make_row(row, scan)
+    rng = np.random.default_rng(4)
+    sysp = rng.integers(0, cfg.vocab_size, (16,)).astype(np.int32)
+    donor = ContinuousBatcher(cfg, params, max_batch=2, kv_page_tokens=8)
+    donor.submit(np.concatenate(
+        [sysp, rng.integers(0, cfg.vocab_size, (3,)).astype(np.int32)]), 4)
+    donor.run()
+    export = donor.export_prefix_cache()
+    n = export["pages"]
+    assert n >= 2
+    lead = (cfg.num_layers,) if scan else ()
+    page = lead + (n, 8, kv_row_width(cfg.num_heads, cfg.head_dim))
+    assert [a.shape for a in export["kv"]] == [page] * len(export["kv"])
+
+    imp = ContinuousBatcher(cfg, params, max_batch=2, kv_page_tokens=8)
+    assert imp.import_prefix_cache(export) == n
+    back = imp.export_prefix_cache()
+    assert back["keys"] == export["keys"]
+    assert back["page_hashes"] == export["page_hashes"]
+    for a, b in zip(back["kv"], export["kv"]):
+        np.testing.assert_array_equal(a, b)
+    probe = np.concatenate(
+        [sysp, rng.integers(0, cfg.vocab_size, (4,)).astype(np.int32)])
+    rid = imp.submit(probe, 5)
+    got = imp.run()[rid]
+    assert imp.prefix_stats()["hit"] == 1
+    np.testing.assert_array_equal(got, _oracle(cfg, params, probe, 5))
+
+
+@pytest.mark.parametrize("path", ["session", "prefix_cache"])
+def test_peer_with_another_row_width_is_refused(path):
+    """``_kv_struct`` carries the pool's row: pages from a peer whose
+    ``W`` differs never reach the device."""
+    prompt = np.asarray([5, 4, 3, 2, 1, 6, 7, 8, 9], np.int32)
+    cfg_a, params_a = _make_row("pad_160_to_256")
+    cfg_b, params_b = _make_row("whole_128")
+    a = ContinuousBatcher(cfg_a, params_a, max_batch=2, kv_page_tokens=8,
+                          prefill_only=(path == "session"))
+    b = ContinuousBatcher(cfg_b, params_b, max_batch=2, kv_page_tokens=8)
+    assert [s[-1] for s, _ in a._kv_struct()] == [256] * 4
+    assert [s[-1] for s, _ in b._kv_struct()] == [128] * 4
+    a.submit(prompt, 5)
+    if path == "session":
+        [(_, sess)] = _drive_handoff(a)
+        refused = lambda: b.adopt_session(sess)          # noqa: E731
+    else:
+        a.run()
+        export = a.export_prefix_cache()
+        refused = lambda: b.import_prefix_cache(export)  # noqa: E731
+    with pytest.raises(ValueError, match="layout mismatch"):
+        refused()
+    rid = b.submit(prompt, 5)     # the refusal never touched the engine
+    np.testing.assert_array_equal(b.run()[rid],
+                                  _oracle(cfg_b, params_b, prompt, 5))

@@ -127,6 +127,31 @@ class GPTConfig:
     # rolling_kv_cache and kv_cache_int8.
     kv_page_tokens: int | None = None
     kv_pool_pages: int | None = None
+    # Biases on the attention and MLP projections (GPT-2 has them; the
+    # Llama and LFM2 classes have none).
+    use_bias: bool = True
+    # RMSNorm over each head's values of q and of k (learned scale per
+    # head position), BEFORE the rotation.
+    qk_norm: bool = False
+    # Per-layer token mixer: ``"full_attention"`` or ``"conv"``, one entry
+    # per layer (None = attention everywhere).  A conv layer is a gated
+    # SHORT CONVOLUTION (LFM2): ``[b, c, v] = split3(W_in u)``, ``z = b *
+    # v``, a depthwise causal convolution of ``conv_L_cache`` taps over
+    # ``z``, ``W_out (c * conv)``.  On the decode path its per-sequence
+    # state is the last ``conv_L_cache - 1`` values of ``z``: a
+    # ``conv_state [B, conv_L_cache - 1, H]`` cache leaf, fixed size per
+    # row, no pages, no position counter (``cache_kinds``).
+    layer_types: tuple | None = None
+    conv_L_cache: int = 3
+    # Sparse experts (``models.moe.SparseMoE``): with ``num_experts`` set,
+    # layers ``>= num_dense_layers`` replace the SwiGLU MLP by
+    # ``num_experts`` SwiGLU experts of width ``moe_intermediate_size``,
+    # ``num_experts_per_tok`` per token, dropless.  The leading dense
+    # layers keep ``intermediate_size``.
+    num_dense_layers: int = 0
+    num_experts: int | None = None
+    num_experts_per_tok: int = 1
+    moe_intermediate_size: int | None = None
 
     def __post_init__(self):
         if self.pos_encoding not in ("learned", "rope"):
@@ -182,10 +207,76 @@ class GPTConfig:
             raise ValueError(
                 f"rope needs an even head_dim, got {self.head_dim} "
                 f"(hidden_size {self.hidden_size} / num_heads {self.num_heads})")
+        if self.layer_types is not None:
+            object.__setattr__(self, "layer_types", tuple(self.layer_types))
+            bad = sorted(set(self.layer_types) - set(LAYER_TYPES))
+            if bad or len(self.layer_types) != self.num_layers:
+                raise ValueError(
+                    f"layer_types needs num_layers ({self.num_layers}) "
+                    f"entries from {LAYER_TYPES}, got "
+                    f"{len(self.layer_types)} with unknown {bad}")
+            if self.has_conv and self.conv_L_cache < 2:
+                raise ValueError(
+                    f"conv_L_cache must be >= 2, got {self.conv_L_cache}")
+        if self.num_experts is not None:
+            if self.mlp != "swiglu" or self.moe_intermediate_size is None \
+                    or not 1 <= self.num_experts_per_tok <= self.num_experts \
+                    or not 0 <= self.num_dense_layers <= self.num_layers:
+                raise ValueError(
+                    "num_experts needs mlp='swiglu', moe_intermediate_size, "
+                    "1 <= num_experts_per_tok <= num_experts and 0 <= "
+                    f"num_dense_layers <= num_layers, got mlp={self.mlp!r}, "
+                    f"moe_intermediate_size={self.moe_intermediate_size}, "
+                    f"{self.num_experts_per_tok} of {self.num_experts} "
+                    f"experts, {self.num_dense_layers} dense layers")
+        if self.scan_layers and (self.has_conv or self.num_experts
+                                 is not None):
+            raise ValueError(
+                "scan_layers stacks ONE uniform block; layer_types with "
+                "conv layers and num_experts (dense layers before expert "
+                "layers) make the blocks differ — leave scan_layers off")
 
     @property
     def head_dim(self) -> int:
         return self.hidden_size // self.num_heads
+
+    def layer_type(self, layer: int) -> str:
+        return LAYER_TYPES[0] if self.layer_types is None \
+            else self.layer_types[layer]
+
+    @property
+    def has_conv(self) -> bool:
+        return self.layer_types is not None and "conv" in self.layer_types
+
+    def is_expert_layer(self, layer: int) -> bool:
+        return self.num_experts is not None \
+            and layer >= self.num_dense_layers
+
+    @property
+    def num_expert_layers(self) -> int:
+        return 0 if self.num_experts is None \
+            else self.num_layers - self.num_dense_layers
+
+    @property
+    def cache_kinds(self) -> str:
+        """The kinds of per-sequence decode state this configuration
+        keeps, for error messages: a refusal names the layer type that
+        caused it."""
+        kinds = []
+        n_conv = 0 if self.layer_types is None \
+            else self.layer_types.count("conv")
+        if self.num_layers - n_conv:
+            kinds.append(f"K/V of {self.num_layers - n_conv} "
+                         "full_attention layer(s) (positional, rewindable)")
+        if n_conv:
+            kinds.append(f"conv_state of {n_conv} conv layer(s) (the last "
+                         f"{self.conv_L_cache - 1} gated inputs per row: "
+                         "fixed size, no snapshot to rewind to or share)")
+        return "; ".join(kinds)
+
+
+#: the token mixers ``GPTConfig.layer_types`` may name
+LAYER_TYPES = ("full_attention", "conv")
 
 
 def kv_row_width(num_kv_heads: int, head_dim: int) -> int:
@@ -235,12 +326,18 @@ class CausalSelfAttention(nn.Module):
         # qkv, kv_store, kv_gather, scores, context (docs/observability.md
         # "Profiler spans")
         with jax.named_scope("qkv"):
-            q = _dense(H * D, (None, "tp"), cfg.dtype, "query")(x) \
-                .reshape(B, T, H, D)
-            k = _dense(Hkv * D, (None, "tp"), cfg.dtype, "key")(x) \
-                .reshape(B, T, Hkv, D)
-            v = _dense(Hkv * D, (None, "tp"), cfg.dtype, "value")(x) \
-                .reshape(B, T, Hkv, D)
+            q = _dense(H * D, (None, "tp"), cfg.dtype, "query",
+                       cfg.use_bias)(x).reshape(B, T, H, D)
+            k = _dense(Hkv * D, (None, "tp"), cfg.dtype, "key",
+                       cfg.use_bias)(x).reshape(B, T, Hkv, D)
+            v = _dense(Hkv * D, (None, "tp"), cfg.dtype, "value",
+                       cfg.use_bias)(x).reshape(B, T, Hkv, D)
+        if cfg.qk_norm:
+            with jax.named_scope("qk_norm"):
+                q = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32,
+                               name="q_norm")(q).astype(cfg.dtype)
+                k = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32,
+                               name="k_norm")(k).astype(cfg.dtype)
 
         per_row = cfg.per_row_positions and self.decode
         ci = self.variable(
@@ -442,7 +539,58 @@ class CausalSelfAttention(nn.Module):
                 causal &= pos[None, :] > pos[:, None] - cfg.sliding_window
             ctx = grouped_attention(q, k, v, causal)
         ctx = ctx.astype(cfg.dtype).reshape(B, T, H * D)
-        return _dense(cfg.hidden_size, ("tp", None), cfg.dtype, "out")(ctx)
+        return _dense(cfg.hidden_size, ("tp", None), cfg.dtype, "out",
+                      cfg.use_bias)(ctx)
+
+
+class ShortConv(nn.Module):
+    """The gated short convolution of a ``"conv"`` layer (LFM2): ``[b, c,
+    v] = split3(W_in u)``; ``z = b * v``; ``s_t = sum_j w[j] * z_{t - (L-1)
+    + j}`` (depthwise, causal, ``L = conv_L_cache`` taps, zeros before the
+    sequence's start); ``W_out (c * s)``.  No biases.
+
+    ``decode=True`` carries the last ``L - 1`` values of ``z`` of every
+    row in the ``conv_state [B, L-1, H]`` cache leaf.  ``lengths [B]`` is
+    the number of VALID tokens of each right-padded row of this call: the
+    state is taken there, not at the end of the padded block (a pad
+    token's ``z`` is not the sequence's).  None = every token is valid."""
+
+    cfg: GPTConfig
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, x, lengths=None):
+        cfg = self.cfg
+        B, T, H = x.shape
+        L = cfg.conv_L_cache
+        with jax.named_scope("in_proj"):
+            b, c, v = jnp.split(
+                _dense(3 * H, (None, "tp"), cfg.dtype, "in_proj",
+                       use_bias=False)(x), 3, axis=-1)
+        w = self.param("conv_kernel", nn.initializers.normal(0.02), (L, H))
+        with jax.named_scope("mix"):
+            z = b * v
+            if self.decode:
+                state = self.variable("cache", "conv_state", jnp.zeros,
+                                      (B, L - 1, H), cfg.dtype)
+                zfull = jnp.concatenate([state.value, z], axis=1)
+            else:
+                zfull = jnp.pad(z, ((0, 0), (L - 1, 0), (0, 0)))
+            wf = w.astype(jnp.float32)
+            s = sum(wf[j] * zfull[:, j:j + T].astype(jnp.float32)
+                    for j in range(L))
+            y = (c.astype(jnp.float32) * s).astype(cfg.dtype)
+        if self.decode:
+            with jax.named_scope("state_store"):
+                if lengths is None:
+                    state.value = zfull[:, T:]
+                else:
+                    at = lengths[:, None] + jnp.arange(L - 1)[None, :]
+                    state.value = jnp.take_along_axis(
+                        zfull, at[:, :, None], axis=1)
+        with jax.named_scope("out_proj"):
+            return _dense(H, ("tp", None), cfg.dtype, "out_proj",
+                          use_bias=False)(y)
 
 
 def _norm(cfg: GPTConfig, name: str):
@@ -452,32 +600,49 @@ def _norm(cfg: GPTConfig, name: str):
 
 
 class DecoderBlock(nn.Module):
+    """One pre-norm block: ``h = x + Op(norm(x))``, ``h + FFN(norm(h))``.
+    ``layer`` picks the operator (``cfg.layer_types``: attention or the
+    short convolution) and the FFN (the MLP, or the experts from
+    ``cfg.num_dense_layers`` on)."""
+
     cfg: GPTConfig
     decode: bool = False
+    layer: int = 0
 
     @nn.compact
-    def __call__(self, x, train: bool = False):
+    def __call__(self, x, train: bool = False, lengths=None):
         # ``train`` is positional-or-keyword (not keyword-only) so the
         # remat wrapper below can mark it static via ``static_argnums``
         # — jax.checkpoint traces kwargs, and a traced ``train`` breaks
         # the ``not train`` dropout toggle (TracerBoolConversionError).
         cfg = self.cfg
         y = _norm(cfg, "ln1")(x).astype(cfg.dtype)
-        y = CausalSelfAttention(cfg, self.decode, name="attn")(y, train=train)
+        if cfg.layer_type(self.layer) == "conv":
+            y = ShortConv(cfg, self.decode, name="conv")(y, lengths)
+        else:
+            y = CausalSelfAttention(cfg, self.decode, name="attn")(
+                y, train=train)
         y = nn.Dropout(cfg.dropout_rate, deterministic=not train)(y)
         x = x + y
         y = _norm(cfg, "ln2")(x).astype(cfg.dtype)
+        if cfg.is_expert_layer(self.layer):
+            from tensorflowonspark_tpu.models.moe import SparseMoE
+
+            y = SparseMoE(cfg, name="moe")(y)
+            y = nn.Dropout(cfg.dropout_rate, deterministic=not train)(y)
+            return x + y
         if cfg.mlp == "swiglu":
             gate = _dense(cfg.intermediate_size, (None, "tp"), cfg.dtype,
-                          "mlp_gate")(y)
+                          "mlp_gate", cfg.use_bias)(y)
             up = _dense(cfg.intermediate_size, (None, "tp"), cfg.dtype,
-                        "mlp_up")(y)
+                        "mlp_up", cfg.use_bias)(y)
             y = nn.silu(gate) * up
         else:
             y = _dense(cfg.intermediate_size, (None, "tp"), cfg.dtype,
-                       "mlp_up")(y)
+                       "mlp_up", cfg.use_bias)(y)
             y = nn.gelu(y)
-        y = _dense(cfg.hidden_size, ("tp", None), cfg.dtype, "mlp_down")(y)
+        y = _dense(cfg.hidden_size, ("tp", None), cfg.dtype, "mlp_down",
+                   cfg.use_bias)(y)
         y = nn.Dropout(cfg.dropout_rate, deterministic=not train)(y)
         return x + y
 
@@ -502,10 +667,12 @@ class GPT(nn.Module):
     decode: bool = False
 
     @nn.compact
-    def hidden(self, input_ids, *, train: bool = False):
+    def hidden(self, input_ids, *, train: bool = False, lengths=None):
         """Trunk only: ``[B, T] -> [B, T, H]`` final hidden states (post
         ``ln_f``, fp32).  Pair with ``ops.tied_softmax_xent(h, table,
-        labels)`` to train without materialising ``[B, T, V]`` logits."""
+        labels)`` to train without materialising ``[B, T, V]`` logits.
+        ``lengths [B]``: valid tokens per right-padded row, for the conv
+        layers' state (:class:`ShortConv`)."""
         cfg = self.cfg
         B, T = input_ids.shape
         tok = nn.Embed(cfg.vocab_size, cfg.hidden_size, name="tok_emb",
@@ -563,12 +730,21 @@ class GPT(nn.Module):
                 # rematerialisation and restore no-remat peak memory
                 block_cls = nn.remat(DecoderBlock, static_argnums=(2,))
             for i in range(cfg.num_layers):
-                x = block_cls(cfg, self.decode, name=f"layer_{i}")(
-                    x, train)
+                block = block_cls(cfg, self.decode, i, name=f"layer_{i}")
+                # a dense model's call is the one it always was
+                x = block(x, train) if lengths is None \
+                    else block(x, train, lengths)
         return _norm(cfg, "ln_f")(x)
 
-    def __call__(self, input_ids, *, train: bool = False):
-        x = self.hidden(input_ids, train=train)
+    def __call__(self, input_ids, *, train: bool = False, lengths=None):
+        """``lengths [B]`` (decode path, right-padded rows): the valid
+        tokens of each row.  Conv layers take their state there, and the
+        logits come back for each row's LAST VALID position only, ``[B, 1,
+        V]``: a prefill wants no other, and ``[B, T, V]`` is its largest
+        temporary."""
+        x = self.hidden(input_ids, train=train, lengths=lengths)
+        if lengths is not None:
+            x = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)
         table = self.get_variable("params", "tok_emb")["embedding"]
         table = getattr(table, "value", table)  # unbox partitioned param
         with jax.named_scope("lm_head"):
@@ -597,7 +773,28 @@ def rewind_cache(cache, position):
     ``pos`` (stacked ``[num_layers]`` leaves under ``scan_layers`` are
     filled).  K/V payloads are untouched — callers rely on by-position
     causal masking plus their next block write to retire entries past the
-    rewound position (see :func:`lookup_generate`)."""
+    rewound position (see :func:`lookup_generate`).
+
+    Refuses a cache with ``conv_state`` leaves: a conv layer's state is
+    the last gated inputs it saw, with no position to mask by — tokens
+    written past ``position`` have already replaced it, and there is no
+    snapshot to go back to."""
+    if any(getattr(path[-1], "key", None) == "conv_state" for path, _ in
+           jax.tree_util.tree_flatten_with_path(cache)[0]):
+        raise ValueError(
+            "rewind_cache: the cache holds conv_state leaves (a "
+            "layer_types 'conv' layer); a short-convolution state cannot "
+            "be rewound without a snapshot — speculative decoding "
+            "(lookup_generate, ContinuousBatcher speculative_k/set_draft) "
+            "is refused for such a configuration")
+    return set_cache_counters(cache, position)
+
+
+def set_cache_counters(cache, position):
+    """The counters half of :func:`rewind_cache`, for callers that have
+    not run the cache past ``position`` in any state that is not masked
+    by position (the batcher's padded prefill takes conv state at each
+    row's true length: ``ShortConv``)."""
     return jax.tree_util.tree_map_with_path(
         lambda path, leaf: jnp.full_like(leaf, position) if any(
             getattr(k, "key", None) in ("index", "pos") for k in path)
@@ -678,6 +875,11 @@ def lookup_generate(cfg: GPTConfig, params, prompt_ids,
     after the prefill).
     """
     B, T0 = prompt_ids.shape
+    if cfg.has_conv:
+        raise ValueError(
+            "lookup_generate rewinds the cache after every verify block, "
+            f"and this configuration keeps {cfg.cache_kinds}: the conv "
+            "state cannot be rewound")
     if max_new_tokens <= 0:
         return (prompt_ids, {"forwards": jnp.zeros((), jnp.int32)}) \
             if return_stats else prompt_ids

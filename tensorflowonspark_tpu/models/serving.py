@@ -73,8 +73,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from tensorflowonspark_tpu import observability as _obs
+from tensorflowonspark_tpu.models import moe as _moe
 from tensorflowonspark_tpu.models.gpt import (GPT, GPTConfig, init_cache,
-                                              nucleus_filter, rewind_cache)
+                                              nucleus_filter,
+                                              set_cache_counters)
 from tensorflowonspark_tpu.models.kv_pages import KVPagePool, hash_page_data
 
 #: compile site -> the program's name, by ROLE and never by shape: the
@@ -128,24 +130,71 @@ class _Slot:
     lease: object = None                        # paged mode: PageLease
 
 
+def _apply(model, params, cache, tokens, lengths=None):
+    """One cached forward: ``(logits, cache, expert stats)``.  The stats
+    are the expert layers' sown counts (``models.moe``: assignments made,
+    the busiest expert's, experts touched) as one flat int32 vector, three
+    per expert layer, or None for a model without experts.  ``lengths``
+    (a padded prefill of a model with conv layers) goes to the model only
+    when given, so a dense model's trace is the one it always was."""
+    kwargs = {} if lengths is None else {"lengths": lengths}
+    logits, vars_ = model.apply(
+        {"params": params, "cache": cache}, tokens,
+        mutable=["cache", _moe.STATS], **kwargs)
+    sown = jax.tree.leaves(vars_.get(_moe.STATS, {}))
+    stats = jnp.concatenate(sown) if sown else None
+    return logits, vars_["cache"], stats
+
+
+def _row_view(cache, row_bt, row_start, conv_rows=None):
+    """The batch's paged cache as a prefill's rows see it: the shared
+    pools as they are, every ``block_table`` the rows' tables ``row_bt
+    [rows, pages]``, every counter the rows' start positions, every
+    ``conv_state`` the rows' carried state (``conv_rows``: one ``[rows,
+    L-1, H]`` array per leaf, in traversal order) or zeros for rows that
+    start fresh (None)."""
+    conv_in = iter(conv_rows or ())
+
+    def rows(path, leaf):
+        k = getattr(path[-1], "key", None)
+        if k == "conv_state":
+            return next(conv_in) if conv_rows is not None else jnp.zeros(
+                row_bt.shape[:1] + leaf.shape[1:], leaf.dtype)
+        if k == "block_table":
+            return jnp.broadcast_to(row_bt, leaf.shape[:-2] + row_bt.shape)
+        if k in ("index", "pos"):
+            return jnp.broadcast_to(
+                row_start, leaf.shape[:-1] + row_start.shape
+            ).astype(leaf.dtype)
+        return leaf     # the shared pool
+
+    return jax.tree_util.tree_map_with_path(rows, cache)
+
+
+def _pack(tokens, stats):
+    """Tokens and expert stats as ONE int32 vector, so that the host's one
+    fetch of the tokens brings the stats (no second synchronisation in a
+    loop turn); a model without experts returns its tokens as they are."""
+    if stats is None:
+        return tokens
+    return jnp.concatenate([tokens.reshape(-1).astype(jnp.int32), stats])
+
+
 def _decode_one_greedy(model, params, cache, tokens):
     """THE greedy decode step — the per-step executables and the
     ``decode_block_steps`` scan bodies both call this, so the
-    block==per-step token-exactness contract cannot drift."""
-    logits, vars_ = model.apply(
-        {"params": params, "cache": cache},
-        tokens[:, None], mutable=["cache"])
-    return jnp.argmax(logits[:, -1], axis=-1), vars_["cache"]
+    block==per-step token-exactness contract cannot drift.  Returns
+    ``(next tokens, expert stats | None, cache)``."""
+    logits, cache, stats = _apply(model, params, cache, tokens[:, None])
+    return jnp.argmax(logits[:, -1], axis=-1), stats, cache
 
 
 def _decode_one_sampled(model, params, cache, tokens, seeds, steps,
                         temps, top_ps):
     """THE sampled decode step (see :func:`_decode_one_greedy`)."""
-    logits, vars_ = model.apply(
-        {"params": params, "cache": cache},
-        tokens[:, None], mutable=["cache"])
+    logits, cache, stats = _apply(model, params, cache, tokens[:, None])
     nxt = _select_tokens(logits[:, -1], seeds, steps, temps, top_ps)
-    return nxt, vars_["cache"]
+    return nxt, stats, cache
 
 
 def _select_tokens(logits, seeds, steps, temps, top_ps):
@@ -281,7 +330,32 @@ class ContinuousBatcher:
                  kv_pool_pages: int | None = None,
                  prefix_cache: bool = True,
                  prefill_only: bool = False,
+                 prefill_rows_max: int | None = None,
+                 decode_ahead: bool = False,
                  aot_cache=None):
+        if cfg.has_conv:
+            # what a second kind of per-sequence state cannot follow yet
+            # refuses here, loudly, naming the state that caused it
+            why = None
+            if speculative_k is not None:
+                why = ("speculative_k rewinds the cache after each verify "
+                       "dispatch")
+            elif kv_page_tokens is not None and prefix_cache:
+                why = ("prefix_cache=True lets a request start from another "
+                       "request's shared pages, which hold no conv state at "
+                       "their end — pass prefix_cache=False")
+            elif prefill_only:
+                why = ("prefill_only exports K/V pages as the whole of a "
+                       "session")
+            if why is not None:
+                raise ValueError(
+                    f"ContinuousBatcher: {why}; this configuration keeps "
+                    f"{cfg.cache_kinds}")
+        if prefill_rows_max is not None and (
+                prefill_rows_max < 1
+                or prefill_rows_max & (prefill_rows_max - 1)):
+            raise ValueError(f"prefill_rows_max must be a positive power "
+                             f"of two, got {prefill_rows_max}")
         if cfg.rolling_kv_cache:
             raise ValueError("ContinuousBatcher requires a full-length "
                              "cache (rolling_kv_cache=False)")
@@ -313,6 +387,13 @@ class ContinuousBatcher:
                 "dispatches keep speculative_k and arm a draft model "
                 "(set_draft / ServingCluster.run(draft_model=)) instead "
                 "of blocking")
+        if decode_ahead and (speculative_k is not None
+                             or decode_block_steps is not None):
+            raise ValueError(
+                "decode_ahead queues the NEXT plain decode step behind the "
+                "running one; speculative_k and decode_block_steps decide "
+                "each dispatch from the last one's tokens — they are "
+                "alternatives")
         if prefill_only:
             if kv_page_tokens is None:
                 raise ValueError("prefill_only needs kv_page_tokens: the "
@@ -374,6 +455,28 @@ class ContinuousBatcher:
         #: O(prompt x max_len) — the chunk loop adds executables only for
         #: (one fixed chunk length + the bucketed final chunk)
         self.prefill_chunk = prefill_chunk
+        #: the most rows ONE prefill dispatch takes (a power of two; None
+        #: = a whole admission group): a burst's group is cut into
+        #: dispatches of at most this many rows, because a prefill's
+        #: temporaries (the scores ``[rows, heads, bucket, max_len]``
+        #: float32 first) grow with its rows and the chip's memory that
+        #: the weights leave free does not
+        self.prefill_rows_max = prefill_rows_max
+        #: DISPATCH-AHEAD: while every slot is seated, greedy, and more than
+        #: one token short of its budget (and no ``eos_id`` can end a row
+        #: early), the next plain decode step's rows are already known, so
+        #: it is dispatched BEFORE the running step's tokens are fetched,
+        #: fed the running step's tokens as they lie on the device.  The
+        #: device then runs step after step with no host turn between
+        #: them, and a late wake-up of the host shorter than a step costs
+        #: nothing.  Token-exact (the same executable, the same inputs);
+        #: admission is never delayed, because with every slot busy and
+        #: no row finishing nothing could have been admitted anyway.
+        self.decode_ahead = bool(decode_ahead)
+        #: the step dispatched ahead and not yet consumed (its packed
+        #: tokens on the device), and how many there were in all
+        self._ahead = None
+        self.decode_ahead_dispatches = 0
         #: PAGED KV mode (``kv_page_tokens`` set, a power of two): the
         #: per-slot dense cache becomes a pool of ``kv_pool_pages``
         #: fixed-size pages behind per-row block tables (``models/gpt``
@@ -454,6 +557,22 @@ class ContinuousBatcher:
         #: ``decode_block_steps`` each block dispatch counts its scanned
         #: steps here) — steps/dispatches is the amortization ratio
         self.decode_steps = 0
+        #: expert-layer accounting (``GPTConfig.num_experts``), summed over
+        #: expert layers and over every decode and prefill dispatch whose
+        #: tokens the host fetched (the stats ride that fetch: ``_pack``;
+        #: a chunk slice of ``prefill_chunk`` has no fetch and is not
+        #: counted): assignments made (rows x experts per token), the
+        #: busiest expert's assignments, and experts that got at least
+        #: one — ``tfos_replica_expert_assignments_total``,
+        #: ``..._expert_peak_assignments_total``,
+        #: ``..._experts_touched_total``
+        self.expert_assignments = 0
+        self.expert_peak_assignments = 0
+        self.experts_touched = 0
+        #: rows whose conv state an admission wrote (configurations with
+        #: ``layer_types`` conv layers; 0 otherwise) —
+        #: ``tfos_replica_state_rows_seated_total``
+        self.state_rows_seated = 0
         #: set to the original error message the first time a device step
         #: raises mid-flight; every executable donates the cache buffer
         #: (``donate_argnums``), so after a failed dispatch the previous
@@ -499,12 +618,24 @@ class ContinuousBatcher:
             (self.cfg, self.max_batch, self.spec_k, self.spec_ngram,
              self.prefill_chunk, self.decode_block_steps))
 
+        n_stats = 3 * self.cfg.num_expert_layers
+
         def step_greedy(params, cache, tokens):
-            return _decode_one_greedy(self.model, params, cache, tokens)
+            if n_stats:
+                # a model with experts takes its tokens in the shape it
+                # returns them (:func:`_pack`), so that a step dispatched
+                # ahead is fed the last step's output as it is; a dense
+                # model's program is the one it always was
+                tokens = tokens[:self.max_batch]
+            nxt, stats, cache = _decode_one_greedy(self.model, params,
+                                                   cache, tokens)
+            return _pack(nxt, stats), cache
 
         def step_sample(params, cache, tokens, seeds, steps, temps, top_ps):
-            return _decode_one_sampled(self.model, params, cache, tokens,
-                                       seeds, steps, temps, top_ps)
+            nxt, stats, cache = _decode_one_sampled(
+                self.model, params, cache, tokens, seeds, steps, temps,
+                top_ps)
+            return _pack(nxt, stats), cache
 
         # two executables so all-greedy traffic (the common batch) never
         # pays the per-row sort/sample computation
@@ -532,6 +663,20 @@ class ContinuousBatcher:
         fully pre-baked warm-up."""
         return None if self._aot is None else self._aot.stats()
 
+    def _fetch(self, packed, shape=None) -> np.ndarray:
+        """The host's fetch of a dispatch's tokens (the caller holds the
+        fetch span): splits off the expert stats that rode with them
+        (:func:`_pack`) into the lifetime counters."""
+        out = np.asarray(packed)
+        n = 3 * self.cfg.num_expert_layers
+        if n:
+            made, peak, touched = out[-n:].reshape(-1, 3).sum(axis=0)
+            self.expert_assignments += int(made)
+            self.expert_peak_assignments += int(peak)
+            self.experts_touched += int(touched)
+            out = out[:-n]
+        return out if shape is None else out.reshape(shape)
+
     def _scatter_rows(self, row_cache, slot_idx: list[int]) -> None:
         """Write a prefilled side cache's rows into the batch slots named
         by ``slot_idx`` — ONE indexed-scatter dispatch regardless of how
@@ -556,6 +701,9 @@ class ContinuousBatcher:
 
             self._prefill_jit[key] = self._jit(key, scatter_fn,
                                                donate_argnums=(0,))
+        if self.cfg.has_conv:
+            self.state_rows_seated += sum(i < self.max_batch
+                                          for i in slot_idx)
         with self._spans(_obs.BATCHER_PREFILL_DISPATCH):
             self.cache = self._prefill_jit[key](
                 self.cache, row_cache, jnp.asarray(slot_idx, jnp.int32))
@@ -657,6 +805,11 @@ class ContinuousBatcher:
                 f"cannot set_role({role!r}) with live requests "
                 f"(load={self.load()})")
         if role == "prefill":
+            if self.cfg.has_conv:
+                raise ValueError(
+                    "prefill role exports K/V pages as the whole of a "
+                    "session; this configuration keeps "
+                    f"{self.cfg.cache_kinds}")
             if self._pages is None:
                 raise ValueError(
                     "prefill role needs paged KV (kv_page_tokens): the "
@@ -687,6 +840,11 @@ class ContinuousBatcher:
         if not isinstance(draft, DraftModel):
             raise TypeError(
                 f"set_draft wants a DraftModel, got {type(draft).__name__}")
+        if self.cfg.has_conv:
+            raise ValueError(
+                "draft_model speculation rewinds the target's cache after "
+                "each verify dispatch; this configuration keeps "
+                f"{self.cfg.cache_kinds}")
         if self.prefill_only:
             raise ValueError(
                 "draft_model conflicts with prefill_only: a prefill pool "
@@ -695,7 +853,8 @@ class ContinuousBatcher:
             raise ValueError(
                 "draft_model needs speculative_k: the draft proposes into "
                 "the k-token verify window (pass speculative_k= to the "
-                "batcher, or serve_draft_k through the serving tier)")
+                "batcher, or serve_draft_k through the serving tier); "
+                f"this configuration keeps {self.cfg.cache_kinds}")
         if draft.cfg.vocab_size != self.cfg.vocab_size:
             raise ValueError(
                 f"draft/target vocab mismatch: draft vocab_size="
@@ -926,9 +1085,14 @@ class ContinuousBatcher:
         pure function of (params, prompt, budget, temperature, top_p,
         seed) the oracle locks.  Returns the local request id."""
         self._check_usable()
+        if self.cfg.has_conv:
+            raise ValueError(
+                "adopt_session seats a session from its K/V pages alone; "
+                f"this configuration keeps {self.cfg.cache_kinds}")
         if self._pages is None:
-            raise ValueError("adopt_session needs paged KV mode "
-                             "(kv_page_tokens)")
+            raise ValueError(
+                "adopt_session needs paged KV mode (kv_page_tokens); this "
+                f"batcher keeps dense rows of {self.cfg.cache_kinds}")
         if self.prefill_only:
             raise ValueError("a prefill-only batcher cannot adopt "
                              "sessions (it never decode-steps)")
@@ -1192,10 +1356,7 @@ class ContinuousBatcher:
         C = self.prefill_chunk
         if ("chunk", C) not in self._prefill_jit:
             def chunk_fn(params, cache, tokens_row):
-                _, vars_ = self.model.apply(
-                    {"params": params, "cache": cache},
-                    tokens_row, mutable=["cache"])
-                return vars_["cache"]
+                return _apply(self.model, params, cache, tokens_row)[1]
             self._prefill_jit[("chunk", C)] = self._jit(
                 ("chunk", C), chunk_fn, donate_argnums=(1,))
         return self._prefill_jit[("chunk", C)]
@@ -1228,7 +1389,7 @@ class ContinuousBatcher:
         self._scatter_rows(row_cache, [slot])
         self._inflight = None
         with self._spans(_obs.BATCHER_PREFILL_FETCH):
-            tok = int(np.asarray(first)[0])
+            tok = int(self._fetch(first)[0])
         self._emit_token(rid, tok)
         s = _Slot(request_id=rid, remaining=budget - 1, tokens=[tok],
                   temperature=temp, top_p=top_p, seed=seed)
@@ -1253,7 +1414,9 @@ class ContinuousBatcher:
         serving traffic must not pay one per shape).  Why padding is
         exact: prefill attention is causal, so pad tokens never
         influence a true last position's logits (selected per row at
-        ``true_len - 1``); each row's cache counters are then REWOUND
+        ``true_len - 1``), and a conv layer takes its state at the row's
+        true length (``ShortConv``'s ``lengths``), where no pad token has
+        entered it; each row's cache counters are then REWOUND
         to its ``true_total``, after which the positional visibility
         mask hides every pad slot (``k_pos > q_pos``) until the decode
         loop overwrites it with a real token's K/V in the same forward
@@ -1270,14 +1433,12 @@ class ContinuousBatcher:
         if key not in self._prefill_jit:
             def final_fn(params, cache, tokens, true_len, true_tot,
                          seeds, temps, top_ps):
-                logits, vars_ = self.model.apply(
-                    {"params": params, "cache": cache},
-                    tokens, mutable=["cache"])
-                last = jnp.take_along_axis(
-                    logits, (true_len - 1)[:, None, None], axis=1)[:, 0]
+                last, cache, stats = self._last_logits(params, cache,
+                                                       tokens, true_len)
                 first = _select_tokens(
                     last, seeds, jnp.zeros_like(true_len), temps, top_ps)
-                return first, rewind_cache(vars_["cache"], true_tot)
+                return _pack(first, stats), \
+                    set_cache_counters(cache, true_tot)
             self._prefill_jit[key] = self._jit(key, final_fn,
                                                donate_argnums=(1,))
         self.prefill_dispatches += 1
@@ -1299,6 +1460,27 @@ class ContinuousBatcher:
                 self.params, cache, padded,
                 jnp.asarray(true_len), jnp.asarray(tot),
                 jnp.asarray(seed_a), jnp.asarray(temp_a), jnp.asarray(top_a))
+
+    def _last_logits(self, params, cache, tokens, true_len):
+        """A padded prefill's forward: ``(logits at each row's last true
+        position [rows, V], cache, expert stats)``.  With conv layers the
+        model is told the true lengths (its state is taken there) and
+        computes the head at that position only."""
+        if self.cfg.has_conv:
+            logits, cache, stats = _apply(self.model, params, cache,
+                                          tokens, lengths=true_len)
+            return logits[:, 0], cache, stats
+        logits, cache, stats = _apply(self.model, params, cache, tokens)
+        return jnp.take_along_axis(
+            logits, (true_len - 1)[:, None, None], axis=1)[:, 0], cache, \
+            stats
+
+    def _prefill_groups(self, buckets: dict) -> list[list]:
+        """The admission groups of one round: same-bucket requests
+        together, cut to at most ``prefill_rows_max`` rows a dispatch."""
+        cap = self.prefill_rows_max
+        return [reqs[i:i + (cap or len(reqs))] for reqs in buckets.values()
+                for i in range(0, len(reqs), cap or len(reqs))]
 
     def _admit(self) -> list[int]:
         """Fill free slots from the pending queue; returns the ids of
@@ -1363,7 +1545,7 @@ class ContinuousBatcher:
                 groups.setdefault(Tp, []).append(req)
             free_iter = iter(free)
             admitted = []   # (slot_index, req_tuple, first_token)
-            for reqs in groups.values():
+            for reqs in self._prefill_groups(groups):
                 rp = _next_pow2(len(reqs))
                 firsts, rows = self._prefill_final(
                     self._fresh_rows_cache(rp),
@@ -1375,7 +1557,7 @@ class ContinuousBatcher:
                 self._scatter_rows(rows,
                                    slots + [self.max_batch] * (rp - len(reqs)))
                 with self._spans(_obs.BATCHER_PREFILL_FETCH):
-                    firsts = np.asarray(firsts)
+                    firsts = self._fetch(firsts)
                 for j, (rid, _, budget, temp, top_p, seed) in enumerate(reqs):
                     admitted.append((slots[j], (rid, budget, temp, top_p,
                                                 seed), int(firsts[j])))
@@ -1466,7 +1648,7 @@ class ContinuousBatcher:
                 groups.setdefault(Tp, []).append((req, lease))
             free_iter = iter(free)
             admitted = []   # (slot, req-fields, first_token, lease)
-            for reqs in groups.values():
+            for reqs in self._prefill_groups(groups):
                 slots = [next(free_iter) for _ in reqs]
                 firsts = self._prefill_paged(
                     [(req, lease, lease.tail_start)
@@ -1491,7 +1673,17 @@ class ContinuousBatcher:
                 break
         return done
 
-    def _prefill_paged(self, entries, slots: list[int]) -> np.ndarray:
+    def _conv_rows(self, rows: int) -> list:
+        """Zeroed conv state for ``rows`` fresh rows: one ``[rows, L-1,
+        H]`` array per ``conv_state`` leaf of the cache, in its traversal
+        order (``[]`` for a model without conv layers)."""
+        return [jnp.zeros((rows,) + leaf.shape[1:], leaf.dtype)
+                for path, leaf in jax.tree_util.tree_flatten_with_path(
+                    self.cache)[0]
+                if getattr(path[-1], "key", None) == "conv_state"]
+
+    def _prefill_paged(self, entries, slots: list[int],
+                       conv_rows: list | None = None) -> np.ndarray:
         """THE paged prefill: one fused dispatch per admission group
         that (1) prefills every row's TAIL tokens (positions after its
         prefix-cache match) straight into the slot's leased pages via a
@@ -1506,7 +1698,11 @@ class ContinuousBatcher:
         ``entries`` = ``[(req_tuple, lease, start)]`` where ``start`` is
         the first prompt position fed here (the lease's tail start, or
         past the already-streamed chunks for a chunked admission's
-        final call).  Pad rows carry all-sentinel block tables (their
+        final call).  ``conv_rows`` is the conv state that chunked
+        admission carried to here (``_conv_rows``' layout); None = the
+        rows start from zero state.  The rows' state after their true
+        last token is scattered into the batch's ``conv_state`` rows with
+        the tables.  Pad rows carry all-sentinel block tables (their
         writes drop) and slot ``max_batch`` (their scatter drops).
         Commits every lease — prefix-index insertion — after the
         dispatch, so only ALREADY-COMPUTED pages are ever matchable."""
@@ -1516,35 +1712,23 @@ class ContinuousBatcher:
         Tp = min(_next_pow2(max(req[1].size - start
                                 for req, _, start in entries)), cfgC)
         rp = _next_pow2(len(entries))
-        key = ("pfinal", Tp, rp)
+        carried = conv_rows is not None
+        key = ("pfinal", Tp, rp, carried) if carried else ("pfinal", Tp, rp)
         if key not in self._prefill_jit:
-            model = self.model
-
             def pfinal_fn(params, cache, tokens, row_bt, row_start,
                           true_len, true_tot, slot_ids, seeds, temps,
-                          top_ps):
-                def rows(path, leaf):
-                    k = getattr(path[-1], "key", None)
-                    if k == "block_table":
-                        return jnp.broadcast_to(
-                            row_bt, leaf.shape[:-2] + row_bt.shape)
-                    if k in ("index", "pos"):
-                        return jnp.broadcast_to(
-                            row_start, leaf.shape[:-1] + row_start.shape
-                        ).astype(leaf.dtype)
-                    return leaf     # the shared pool
-
-                row_cache = jax.tree_util.tree_map_with_path(rows, cache)
-                logits, vars_ = model.apply(
-                    {"params": params, "cache": row_cache}, tokens,
-                    mutable=["cache"])
-                last = jnp.take_along_axis(
-                    logits, (true_len - 1)[:, None, None], axis=1)[:, 0]
+                          top_ps, conv_rows):
+                row_cache = _row_view(cache, row_bt, row_start,
+                                      conv_rows if carried else None)
+                last, new_cache, stats = self._last_logits(
+                    params, row_cache, tokens, true_len)
                 first = _select_tokens(
                     last, seeds, jnp.zeros_like(true_len), temps, top_ps)
 
                 def back(path, b_leaf, r_leaf):
                     k = getattr(path[-1], "key", None)
+                    if k == "conv_state":
+                        return b_leaf.at[slot_ids].set(r_leaf, mode="drop")
                     if k == "block_table":
                         m = jnp.moveaxis(b_leaf, -2, 0)
                         v = jnp.broadcast_to(
@@ -1564,12 +1748,14 @@ class ContinuousBatcher:
                             m.at[slot_ids].set(v, mode="drop"), 0, -1)
                     return r_leaf   # pool leaves: take the prefill writes
 
-                return first, jax.tree_util.tree_map_with_path(
-                    back, cache, vars_["cache"])
+                return _pack(first, stats), \
+                    jax.tree_util.tree_map_with_path(back, cache, new_cache)
 
             self._prefill_jit[key] = self._jit(key, pfinal_fn,
                                                donate_argnums=(1,))
         self.prefill_dispatches += 1
+        if self.cfg.has_conv:
+            self.state_rows_seated += len(entries)
         with self._spans(_obs.BATCHER_PREFILL_DISPATCH):
             row_bt = np.full((rp, npg), P, np.int32)
             row_start = np.zeros((rp,), np.int32)
@@ -1597,43 +1783,39 @@ class ContinuousBatcher:
                 jnp.asarray(row_start), jnp.asarray(true_len),
                 jnp.asarray(true_tot), jnp.asarray(slot_a),
                 jnp.asarray(seed_a), jnp.asarray(temp_a),
-                jnp.asarray(top_a))
+                jnp.asarray(top_a), conv_rows if carried else [])
         for _, lease, _ in entries:
             self._pages.commit(lease)
         with self._spans(_obs.BATCHER_PREFILL_FETCH):
-            return np.asarray(firsts)
+            return self._fetch(firsts)
 
     def _pchunk_jit(self):
         """One fixed-chunk paged prefill executable: streams a chunk of
         the in-flight admission's tail into its leased pages (batch
         block tables/counters untouched — the slot only goes live at
-        the final :meth:`_prefill_paged` call)."""
+        the final :meth:`_prefill_paged` call).  The admission's conv
+        state goes in and comes out beside the cache (``_conv_rows``'
+        layout): the reserved slot's own ``conv_state`` row is no place
+        for it, because the decode steps in between run every row."""
         C = self.prefill_chunk
         key = ("pchunk", C)
         if key not in self._prefill_jit:
-            model = self.model
+            def chunk_fn(params, cache, tokens_row, row_bt, start,
+                         conv_rows):
+                row_cache = _row_view(cache, row_bt, start, conv_rows)
+                new_cache = _apply(self.model, params, row_cache,
+                                   tokens_row)[1]
+                conv_out = []
 
-            def chunk_fn(params, cache, tokens_row, row_bt, start):
-                def rows(path, leaf):
-                    k = getattr(path[-1], "key", None)
-                    if k == "block_table":
-                        return jnp.broadcast_to(
-                            row_bt, leaf.shape[:-2] + row_bt.shape)
-                    if k in ("index", "pos"):
-                        return jnp.broadcast_to(
-                            start, leaf.shape[:-1] + start.shape
-                        ).astype(leaf.dtype)
-                    return leaf
+                def back(p, b, r):
+                    k = getattr(p[-1], "key", None)
+                    if k == "conv_state":
+                        conv_out.append(r)
+                    return b if k in ("index", "pos", "block_table",
+                                      "conv_state") else r
 
-                row_cache = jax.tree_util.tree_map_with_path(rows, cache)
-                _, vars_ = model.apply(
-                    {"params": params, "cache": row_cache}, tokens_row,
-                    mutable=["cache"])
                 return jax.tree_util.tree_map_with_path(
-                    lambda p, b, r: b
-                    if getattr(p[-1], "key", None)
-                    in ("index", "pos", "block_table") else r,
-                    cache, vars_["cache"])
+                    back, cache, new_cache), conv_out
 
             self._prefill_jit[key] = self._jit(key, chunk_fn,
                                                donate_argnums=(1,))
@@ -1658,16 +1840,19 @@ class ContinuousBatcher:
                 // self.cfg.kv_page_tokens
             row_bt = np.full((1, npg), self.cfg.kv_pool_pages, np.int32)
             row_bt[0, :len(lease.page_ids)] = lease.page_ids
+            if "conv" not in inf:
+                inf["conv"] = self._conv_rows(1)
             with self._spans(_obs.BATCHER_PREFILL_DISPATCH):
-                self.cache = self._pchunk_jit()(
+                self.cache, inf["conv"] = self._pchunk_jit()(
                     self.params, self.cache, prompt[None, start:start + C],
-                    row_bt, np.asarray([start], np.int32))
+                    row_bt, np.asarray([start], np.int32), inf["conv"])
             inf["done_chunks"] += 1
             return []
         slot = inf["slot"]
         self._reserved.discard(slot)
         firsts = self._prefill_paged(
-            [(req, lease, lease.tail_start + n_full * C)], [slot])
+            [(req, lease, lease.tail_start + n_full * C)], [slot],
+            conv_rows=inf["conv"] if self.cfg.has_conv else None)
         self._inflight = None
         tok = int(firsts[0])
         self._emit_token(rid, tok)
@@ -1685,16 +1870,20 @@ class ContinuousBatcher:
         setting its cache counters to max_len so its garbage writes hit
         the position guard and DROP instead of landing in pages now
         owned by someone else (the block-table row itself is replaced
-        wholesale at the slot's next admission)."""
+        wholesale at the slot's next admission).  A conv layer's state
+        row is cleared with it."""
         key = ("park",)
         if key not in self._prefill_jit:
             Cmax = self.cfg.max_position_embeddings
 
             def park_fn(cache, slot):
                 def f(path, leaf):
-                    if getattr(path[-1], "key", None) in ("index", "pos"):
+                    k = getattr(path[-1], "key", None)
+                    if k in ("index", "pos"):
                         m = jnp.moveaxis(leaf, -1, 0)
                         return jnp.moveaxis(m.at[slot].set(Cmax), 0, -1)
+                    if k == "conv_state":
+                        return leaf.at[slot].set(0)
                     return leaf
                 return jax.tree_util.tree_map_with_path(f, cache)
 
@@ -1781,9 +1970,8 @@ class ContinuousBatcher:
 
         def verify_fn(params, cache, toks, d, seeds, steps0, temps,
                       top_ps):
-            logits, vars_ = self.model.apply(
-                {"params": params, "cache": cache}, toks,
-                mutable=["cache"])                       # [B, K+1, V]
+            logits, new_cache, stats = _apply(self.model, params, cache,
+                                              toks)      # [B, K+1, V]
             greedy = jnp.argmax(logits, axis=-1)
             ok = (toks[:, 1:] == greedy[:, :-1]) \
                 & (jnp.arange(K)[None, :] < d[:, None])
@@ -1797,8 +1985,8 @@ class ContinuousBatcher:
             cache = jax.tree_util.tree_map_with_path(
                 lambda p, leaf: leaf + (a - K)
                 if getattr(p[-1], "key", None) in ("index", "pos")
-                else leaf, vars_["cache"])
-            return a, bonus, cache
+                else leaf, new_cache)
+            return a, _pack(bonus, stats), cache
 
         self._prefill_jit["verify"] = self._jit("verify", verify_fn,
                                                 donate_argnums=(1,))
@@ -1867,7 +2055,7 @@ class ContinuousBatcher:
                 jnp.asarray([s.top_p if s else 1.0 for s in self.slots],
                             jnp.float32))
         with self._spans(_obs.BATCHER_DECODE_FETCH):
-            a, bonus = np.asarray(a), np.asarray(bonus)
+            a, bonus = np.asarray(a), self._fetch(bonus)
         done = []
         with self._spans(_obs.BATCHER_EMIT):
             self.spec_proposed += int(d.sum())
@@ -1953,30 +2141,36 @@ class ContinuousBatcher:
             return self._prefill_jit[key]
         model = self.model
 
+        def block_out(seq, stats):
+            """``[B, K]`` tokens; with experts, packed with the K steps'
+            stats summed."""
+            return _pack(seq.swapaxes(0, 1),
+                         None if stats is None else jnp.sum(stats, axis=0))
+
         if sampled:
             def block_fn(params, cache, tokens, seeds, steps0, temps,
                          top_ps):
                 def body(carry, i):
                     toks, cache = carry
-                    nxt, cache = _decode_one_sampled(
+                    nxt, stats, cache = _decode_one_sampled(
                         model, params, cache, toks, seeds, steps0 + i,
                         temps, top_ps)
-                    return (nxt, cache), nxt
+                    return (nxt, cache), (nxt, stats)
 
-                (_, cache), seq = jax.lax.scan(
+                (_, cache), (seq, stats) = jax.lax.scan(
                     body, (tokens, cache), jnp.arange(K))
-                return seq.swapaxes(0, 1), cache
+                return block_out(seq, stats), cache
         else:
             def block_fn(params, cache, tokens):
                 def body(carry, _):
                     toks, cache = carry
-                    nxt, cache = _decode_one_greedy(model, params, cache,
-                                                    toks)
-                    return (nxt, cache), nxt
+                    nxt, stats, cache = _decode_one_greedy(
+                        model, params, cache, toks)
+                    return (nxt, cache), (nxt, stats)
 
-                (_, cache), seq = jax.lax.scan(
+                (_, cache), (seq, stats) = jax.lax.scan(
                     body, (tokens, cache), None, length=K)
-                return seq.swapaxes(0, 1), cache
+                return block_out(seq, stats), cache
 
         self._prefill_jit[key] = self._jit(key, block_fn,
                                            donate_argnums=(1,))
@@ -2009,7 +2203,7 @@ class ContinuousBatcher:
                 seq, self.cache = self._block_jit(K, False)(
                     self.params, self.cache, tokens)
         with self._spans(_obs.BATCHER_DECODE_FETCH):
-            seq = np.asarray(seq)
+            seq = self._fetch(seq, (self.max_batch, K))
         with self._spans(_obs.BATCHER_EMIT):
             for i, s in enumerate(self.slots):
                 if s is None:
@@ -2025,14 +2219,28 @@ class ContinuousBatcher:
                         break
         return done
 
+    def _runs_ahead(self) -> bool:
+        """Whether the step AFTER the one about to be fetched is already
+        decided (``decode_ahead``): every slot seated and greedy, none
+        finishing at this step, no ``eos_id``, no chunked admission in
+        flight — so nothing can leave or join before it."""
+        return (self.decode_ahead and self.eos_id is None
+                and self._inflight is None
+                and all(s is not None and s.temperature <= 0
+                        and s.remaining > 1 for s in self.slots))
+
     def _plain_step(self) -> list[int]:
         done: list[int] = []
         self.decode_dispatches += 1
         self.decode_steps += 1
+        nxt, self._ahead = self._ahead, None
         with self._spans(_obs.BATCHER_DECODE_DISPATCH):
-            tokens = jnp.asarray([s.tokens[-1] if s else 0
-                                  for s in self.slots], jnp.int32)
-            if any(s is not None and s.temperature > 0 for s in self.slots):
+            if nxt is not None:
+                pass            # dispatched ahead, during the last turn
+            elif any(s is not None and s.temperature > 0
+                     for s in self.slots):
+                tokens = jnp.asarray([s.tokens[-1] if s else 0
+                                      for s in self.slots], jnp.int32)
                 nxt, self.cache = self._step_sample(
                     self.params, self.cache, tokens,
                     jnp.asarray([s.seed if s else 0 for s in self.slots],
@@ -2044,9 +2252,21 @@ class ContinuousBatcher:
                     jnp.asarray([s.top_p if s else 1.0 for s in self.slots],
                                 jnp.float32))
             else:
-                nxt, self.cache = self._step(self.params, self.cache, tokens)
+                # padded to the packed length for a model with experts
+                # (``step_greedy``)
+                tokens = np.zeros(
+                    self.max_batch + 3 * self.cfg.num_expert_layers,
+                    np.int32)
+                tokens[:self.max_batch] = [s.tokens[-1] if s else 0
+                                           for s in self.slots]
+                nxt, self.cache = self._step(self.params, self.cache,
+                                             jnp.asarray(tokens))
+            if self._runs_ahead():
+                self._ahead, self.cache = self._step(self.params,
+                                                     self.cache, nxt)
+                self.decode_ahead_dispatches += 1
         with self._spans(_obs.BATCHER_DECODE_FETCH):
-            nxt = np.asarray(nxt)
+            nxt = self._fetch(nxt)
         with self._spans(_obs.BATCHER_EMIT):
             for i, s in enumerate(self.slots):
                 if s is None:

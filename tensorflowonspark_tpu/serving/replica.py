@@ -534,7 +534,27 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
         "hit, compile = miss paid with a compile, error = corrupt "
         "entry or failed write, each degraded to a compile).",
         labelnames=("outcome",))
+    # expert layers and conv state (models/moe.py, models/gpt.py
+    # ShortConv): what the batcher read with its tokens, as deltas
+    m_engine = {
+        "expert_assignments": reg.counter(
+            "tfos_replica_expert_assignments_total",
+            "Expert assignments made (rows x experts per token), summed "
+            "over expert layers and over decode and prefill dispatches."),
+        "expert_peak_assignments": reg.counter(
+            "tfos_replica_expert_peak_assignments_total",
+            "The busiest expert's assignments, summed likewise: x "
+            "experts / assignments is the load's unevenness (1 = even)."),
+        "experts_touched": reg.counter(
+            "tfos_replica_experts_touched_total",
+            "Experts that got at least one assignment, summed likewise: "
+            "the expert weights a dispatch had to read."),
+        "state_rows_seated": reg.counter(
+            "tfos_replica_state_rows_seated_total",
+            "Rows whose conv state an admission wrote (configurations "
+            "with conv layers).")}
     last = {"decode_dispatches": 0, "prefill_dispatches": 0,
+            **dict.fromkeys(m_engine, 0),
             "spec_proposed": 0, "spec_accepted": 0,
             "sessions_exported": 0, "sessions_adopted": 0,
             "hit": 0, "miss": 0, "partial": 0,
@@ -544,7 +564,8 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
         """Move the batcher's lifetime counters into the registry as
         deltas (the registry is cumulative per process already)."""
         for attr, inc in (("decode_dispatches", m_disp.inc),
-                          ("prefill_dispatches", m_prefill.inc)):
+                          ("prefill_dispatches", m_prefill.inc),
+                          *((a, m.inc) for a, m in m_engine.items())):
             cur = getattr(batcher, attr, 0)
             if cur > last[attr]:
                 inc(cur - last[attr])

@@ -168,3 +168,71 @@ def test_paged_pool_is_stored_in_place_on_v5e(one_chip, widths, program):
     copies = [ln.strip()[:120] for ln in entry
               if re.search(rf"= \w+\[({tokens}),[\d,]+\]\S* copy\(", ln)]
     assert not copies, copies
+
+
+# -- the layer pattern at LFM2-8B-A1B's widths (ISSUE 28) -------------------
+# One period of the pattern (conv, conv, attention, conv; one dense layer,
+# three expert layers) at the published widths, the cell's 32 rows and its
+# 4096 x 16-token pool: the grouped matmul, the conv state and the 512-lane
+# pool rows as the chip's compiler takes them.
+
+_LFM2_PROGRAMS = {"decode_B32_T1": (32, 1, False),
+                  "prefill_B1_T1024": (1, 1024, True)}
+
+
+@pytest.mark.parametrize("program", sorted(_LFM2_PROGRAMS))
+def test_conv_and_expert_layers_compile_for_v5e(one_chip, program):
+    import re
+
+    from tensorflowonspark_tpu.models import moe
+    from tensorflowonspark_tpu.models.gpt import GPT, GPTConfig, init_cache
+
+    B, T, padded = _LFM2_PROGRAMS[program]
+    cfg = GPTConfig(
+        vocab_size=65536, hidden_size=2048, num_layers=4, num_heads=32,
+        num_kv_heads=8, intermediate_size=7168,
+        max_position_embeddings=2048, pos_encoding="rope", rope_base=1e6,
+        norm="rmsnorm", norm_eps=1e-5, mlp="swiglu", use_bias=False,
+        qk_norm=True,
+        layer_types=("conv", "conv", "full_attention", "conv"),
+        num_dense_layers=1, num_experts=32, num_experts_per_tok=4,
+        moe_intermediate_size=1792, per_row_positions=True,
+        kv_page_tokens=16, kv_pool_pages=4096)
+    model = GPT(cfg, decode=True)
+    params = jax.eval_shape(
+        lambda: jax.tree.map(
+            lambda a: a.astype(jnp.bfloat16),
+            GPT(cfg).init(jax.random.key(0),
+                          jnp.zeros((1, 8), jnp.int32))["params"]))
+    cache = jax.eval_shape(lambda p: init_cache(cfg, p, B), params)
+
+    def step(params, cache, tokens, lengths):
+        logits, vars_ = model.apply(
+            {"params": params, "cache": cache}, tokens,
+            mutable=["cache", moe.STATS],
+            **({"lengths": lengths} if padded else {}))
+        return jnp.argmax(logits[:, -1], -1), vars_
+
+    def on_chip(tree):
+        return jax.tree.map(lambda t: jax.ShapeDtypeStruct(
+            t.shape, t.dtype, sharding=one_chip), tree)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache),
+        jax.ShapeDtypeStruct((B, T), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)).compile()
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):].splitlines()
+    # three grouped matmuls per expert layer, as the chip's own operation
+    assert text.count("ragged-dot") >= 9
+    # the attention layer's two pools: 8 x 64 = 512 lanes, row-major
+    pools = [ln for ln in entry if " parameter(" in ln
+             and re.search(r"= bf16\[65536,512\]", ln)]
+    assert len(pools) == 2
+    assert all(re.search(r"= bf16\[65536,512\]\{1,0[:}]", ln)
+               for ln in pools)
+    # the three conv layers' state rows are parameters of the step
+    assert sum(1 for ln in entry if " parameter(" in ln
+               and re.search(rf"= bf16\[{B},2,2048\]", ln)) == 3
+    # the padded prefill computes the head at one position a row
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9

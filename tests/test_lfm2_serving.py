@@ -1,0 +1,254 @@
+"""The conv layers' per-slot state and the expert layer THROUGH THE BATCHER
+(the serving half of ``tests/test_lfm2.py``; a file of its own so that the
+test runner spreads the two over workers), against the plain float32
+reference ``benchmark/reference/lfm2.py`` at a small size with every feature
+on and seeded random weights.  Comparisons are of LOGITS: the batcher's
+cache is probed with the tokens it would feed next, and what comes back is
+held against the reference's full forward over the whole sequence so far."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import harness
+from benchmark.reference import lfm2 as ref
+from tensorflowonspark_tpu.models import (ContinuousBatcher, greedy_generate,
+                                          moe)
+
+adapter = harness.load_module("models", "lfm2")
+
+CFG = {"hidden_size": 64, "intermediate_size": 160, "num_hidden_layers": 4,
+       "layer_types": ["conv", "conv", "full_attention", "conv"],
+       "num_attention_heads": 4, "num_key_value_heads": 2, "conv_L_cache": 3,
+       "num_dense_layers": 1, "num_experts": 8, "num_experts_per_tok": 2,
+       "moe_intermediate_size": 48, "norm_eps": 1e-5, "rope_theta": 1e6,
+       "vocab_size": 211, "max_position_embeddings": 64, "dtype": "float32",
+       "init_std": 0.3, "expert_bias_std": 0.3}
+#: float32 everywhere: what differs from the reference is the order of
+#: sums (the cache, the grouped matmuls), a few 1e-6 of logits of size ~10
+TOL = 2e-4
+
+
+@pytest.fixture(scope="module")
+def made():
+    with jax.default_matmul_precision("highest"):
+        return adapter.gpt_config(CFG), ref.make_weights(3, CFG)
+
+
+def _ref_last(params, seq):
+    with jax.default_matmul_precision("highest"):
+        return np.asarray(ref.forward(
+            params, jnp.asarray(seq, jnp.int32)[None], CFG)[0, -1])
+
+
+def _prompt(i, n):
+    return np.random.default_rng([7, i]).integers(0, 211, n).astype(np.int32)
+
+
+def _probe(b, params):
+    """Logits of the NEXT position of every active slot: the batcher's
+    cache, fed what the next step would feed it, cache not kept."""
+    toks = jnp.asarray([s.tokens[-1] if s else 0 for s in b.slots],
+                       jnp.int32)
+    with jax.default_matmul_precision("highest"):
+        logits, _ = b.model.apply({"params": params, "cache": b.cache},
+                                  toks[:, None],
+                                  mutable=["cache", moe.STATS])
+    return np.asarray(logits[:, 0])
+
+
+def _check_slots(b, params, prompts):
+    """Every active slot's next-position logits against the reference's
+    full forward over its prompt and the tokens served so far; returns
+    how many slots were checked."""
+    got = _probe(b, params)
+    n = 0
+    for i, s in enumerate(b.slots):
+        if s is None:
+            continue
+        seq = np.concatenate([prompts[s.request_id], s.tokens])
+        np.testing.assert_allclose(got[i], _ref_last(params, seq), atol=TOL,
+                                   err_msg=f"slot {i} after {len(seq)}")
+        n += 1
+    return n
+
+
+MODES = {"paged": dict(kv_page_tokens=4, kv_pool_pages=64,
+                       prefix_cache=False),
+         "dense": {},
+         "paged-chunked": dict(kv_page_tokens=4, kv_pool_pages=64,
+                               prefix_cache=False, prefill_chunk=4),
+         "dense-chunked": dict(prefill_chunk=4),
+         "paged-rows-max": dict(kv_page_tokens=4, kv_pool_pages=64,
+                                prefix_cache=False, prefill_rows_max=1)}
+
+
+@pytest.mark.parametrize("mode", sorted(MODES))
+def test_batcher_logits_match_reference_at_unequal_lengths_and_steps(
+        made, mode):
+    """Prompt lengths that are no bucket sizes (the state is taken at the
+    true length, not at the padded bucket's end), rows admitted at
+    different steps (the state is per row), with and without chunked
+    prefill (the state is carried across chunks)."""
+    cfg, params = made
+    with jax.default_matmul_precision("highest"):
+        b = ContinuousBatcher(cfg, params, max_batch=4, **MODES[mode])
+        prompts = {}
+        schedule = {0: [(0, 11), (1, 5)], 2: [(2, 13)], 5: [(3, 7)]}
+        checked = 0
+        for step in range(9):
+            for i, n in schedule.get(step, []):
+                prompts[b.submit(_prompt(i, n), 12)] = _prompt(i, n)
+            b.step()
+            checked += _check_slots(b, params, prompts)
+    assert checked >= 15
+    assert b.state_rows_seated == 4
+    # three small integers per expert layer per dispatch rode the fetches
+    layers = cfg.num_expert_layers
+    assert b.expert_assignments % (2 * layers) == 0
+    assert 0 < b.experts_touched <= b.expert_assignments
+    assert b.expert_peak_assignments * 8 >= b.expert_assignments
+
+
+def test_chunked_prefill_on_equals_off(made):
+    """The state carried across chunks is the state of the whole prompt:
+    same first tokens, same logits at the next position."""
+    cfg, params = made
+    kw = dict(kv_page_tokens=4, kv_pool_pages=64, prefix_cache=False)
+    b1 = ContinuousBatcher(cfg, params, max_batch=2, **kw)
+    b2 = ContinuousBatcher(cfg, params, max_batch=2, prefill_chunk=4, **kw)
+    with jax.default_matmul_precision("highest"):
+        for b in (b1, b2):
+            b.submit(_prompt(0, 14), 6)
+            while not any(b.slots):
+                b.step()
+        assert [s.tokens for s in b1.slots if s] \
+            == [s.tokens for s in b2.slots if s]
+        p1 = _probe(b1, params)[[i for i, s in enumerate(b1.slots) if s][0]]
+        p2 = _probe(b2, params)[[i for i, s in enumerate(b2.slots) if s][0]]
+    np.testing.assert_allclose(p1, p2, atol=TOL)
+
+
+@pytest.mark.parametrize("mode", ["paged", "dense"])
+def test_a_reused_slot_starts_from_zero_state(made, mode):
+    cfg, params = made
+    with jax.default_matmul_precision("highest"):
+        b = ContinuousBatcher(cfg, params, max_batch=1, **MODES[mode])
+        first = b.submit(_prompt(0, 9), 3)
+        while b.result(first) is None:
+            b.step()
+        assert not any(b.slots)
+        prompts = {b.submit(_prompt(1, 6), 5): _prompt(1, 6)}
+        b.step()
+        assert _check_slots(b, params, prompts) == 1
+        b.step()
+        assert _check_slots(b, params, prompts) == 1
+
+
+def test_greedy_generate_matches_the_batcher(made):
+    """The plain compiled decoder carries the conv state too."""
+    cfg, params = made
+    prompt = _prompt(4, 10)
+    with jax.default_matmul_precision("highest"):
+        solo = np.asarray(greedy_generate(cfg, params, prompt[None], 6))[0]
+        b = ContinuousBatcher(cfg, params, max_batch=2, kv_page_tokens=4,
+                              prefix_cache=False)
+        rid = b.submit(prompt, 6)
+        got = b.run()[rid]
+    assert solo[10:].tolist() == got.tolist()
+
+
+# -- decode_ahead: the next plain step queued behind the running one --------
+
+def _dense():
+    from tensorflowonspark_tpu.models import GPT, GPTConfig
+
+    cfg = GPTConfig(num_layers=2, hidden_size=32, num_heads=2, vocab_size=50,
+                    max_position_embeddings=64)
+    return cfg, GPT(cfg).init(jax.random.key(0),
+                              jnp.zeros((1, 2), jnp.int32))["params"]
+
+
+def _serve(cfg, params, vocab, kwargs, schedule, steps, **submit):
+    """Drive a batcher of 3 slots through ``schedule`` (step -> [(prompt
+    id, prompt length, budget)]); returns it, the finished streams and the
+    tokens each ``step()`` call emitted, in order."""
+    b = ContinuousBatcher(cfg, params, max_batch=3, **kwargs)
+    events, rids = [], {}
+    for step in range(steps):
+        for i, n, budget in schedule.get(step, []):
+            prompt = np.random.default_rng([9, i]).integers(
+                0, vocab, n).astype(np.int32)
+            rids[i] = b.submit(
+                prompt, budget, **submit,
+                on_token=lambda rid, tok, step=step: events.append(
+                    (step, rid, tok)))
+        b.step()
+    return b, {i: b.result(r) for i, r in rids.items()}, events
+
+
+#: three slots filled at once with unequal budgets, a fourth request that
+#: waits for the first to finish, so that steps with every slot seated
+#: (the only ones that may run ahead) alternate with finishes, a free
+#: slot, and an admission
+AHEAD_SCHEDULE = {0: [(0, 11, 9), (1, 5, 14), (2, 7, 20)], 3: [(3, 6, 8)]}
+
+
+@pytest.mark.parametrize("model,mode", [("lfm2", "paged"), ("lfm2", "dense"),
+                                        ("dense-gpt", "paged")])
+def test_decode_ahead_serves_the_same_tokens_at_the_same_steps(
+        made, model, mode):
+    cfg, params = made if model == "lfm2" else _dense()
+    vocab = cfg.vocab_size
+    with jax.default_matmul_precision("highest"):
+        off, want, ev_off = _serve(cfg, params, vocab, MODES[mode],
+                                   AHEAD_SCHEDULE, 30)
+        on, got, ev_on = _serve(cfg, params, vocab,
+                                dict(MODES[mode], decode_ahead=True),
+                                AHEAD_SCHEDULE, 30)
+    assert all(v is not None for v in want.values())
+    assert {i: v.tolist() for i, v in got.items()} \
+        == {i: v.tolist() for i, v in want.items()}
+    assert ev_on == ev_off      # token for token, step for step
+    assert off.decode_ahead_dispatches == 0
+    # every slot was seated over steps 1..8 and 10..13 or so: most of
+    # those steps were queued ahead, and each was consumed
+    assert on.decode_ahead_dispatches >= 6
+    assert on._ahead is None
+    assert on.decode_dispatches == off.decode_dispatches
+    assert (on.expert_assignments, on.experts_touched) \
+        == (off.expert_assignments, off.experts_touched)
+
+
+@pytest.mark.parametrize("why,kwargs,submit", [
+    ("an eos_id can end a row at any step", dict(eos_id=1), {}),
+    ("a sampled row's step needs its host-side sampler state", {},
+     dict(temperature=0.7, seed=3)),
+])
+def test_decode_ahead_stands_down(made, why, kwargs, submit):
+    cfg, params = made
+    b, got, _ = _serve(cfg, params, 211,
+                       dict(MODES["paged"], decode_ahead=True, **kwargs),
+                       AHEAD_SCHEDULE, 30, **submit)
+    assert b.decode_ahead_dispatches == 0, why
+    assert all(v is not None for v in got.values())
+
+
+def test_decode_ahead_waits_for_every_slot_to_be_seated(made):
+    """With a slot free a request may be admitted at the next step, so
+    that step is not dispatched before it is known."""
+    cfg, params = made
+    b, got, _ = _serve(cfg, params, 211,
+                       dict(MODES["paged"], decode_ahead=True),
+                       {0: [(0, 11, 9), (1, 5, 14)]}, 16)
+    assert b.decode_ahead_dispatches == 0
+    assert all(v is not None for v in got.values())
+
+
+@pytest.mark.parametrize("other", [dict(speculative_k=2),
+                                   dict(decode_block_steps=4)])
+def test_decode_ahead_refuses_its_alternatives(other):
+    cfg, _ = _dense()
+    with pytest.raises(ValueError, match="decode_ahead"):
+        ContinuousBatcher(cfg, None, max_batch=2, decode_ahead=True, **other)

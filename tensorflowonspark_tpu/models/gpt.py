@@ -26,7 +26,9 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from tensorflowonspark_tpu.models.bert import _dense
+from tensorflowonspark_tpu.models.bert import _context_mesh, _dense
+from tensorflowonspark_tpu.ops import paged_attention as _paged
+from tensorflowonspark_tpu.ops.flash_attention import _on_tpu
 
 
 @dataclasses.dataclass(frozen=True)
@@ -118,10 +120,13 @@ class GPTConfig:
     # variable mapping logical page -> physical page.  Each step WRITES
     # through the table (positions past a row's allocated pages, or past
     # max_position_embeddings, are dropped — the unallocated sentinel
-    # entry is ``kv_pool_pages``, out of pool range) and READS the full
-    # logical view back with ONE page gather, after which attention is
-    # the identical per-row masked einsum — so paged decode is
-    # token-exact vs the dense cache.  Page accounting (allocation,
+    # entry is ``kv_pool_pages``, out of pool range).  A DECODE step (one
+    # token a row) then attends over the pages where they lie
+    # (``ops.paged_attention``: only the pages a row's length covers, as
+    # stored; :func:`attends_pages_in_place` says when); every other
+    # step READS the full logical view back with one page gather, after
+    # which attention is the identical per-row masked einsum — the same
+    # mathematics either way.  Page accounting (allocation,
     # prefix sharing, refcounts) is host-side: ``models.kv_pages``.
     # Decode-path only; needs per_row_positions; incompatible with
     # rolling_kv_cache and kv_cache_int8.
@@ -288,6 +293,26 @@ def kv_row_width(num_kv_heads: int, head_dim: int) -> int:
     return -(-num_kv_heads * head_dim // 128) * 128
 
 
+def attends_pages_in_place(cfg: GPTConfig, tokens_per_row: int = 1) -> bool:
+    """Whether a cached step of ``tokens_per_row`` tokens attends through
+    the paged-attention kernel (``ops.paged_attention``) and not through
+    the whole-view gather.  Decided from what the step can see, at trace
+    time: the cache is paged, the step is a decode step (one token a
+    row), no sliding window bands the mask, a page is whole tiles of the
+    pool's dtype (so a page is copied out as it is stored), no mesh of
+    several devices is in scope (a ``pallas_call`` is not partitioned:
+    under a ``tp`` gang GSPMD shards the pool's row by heads), and the
+    backend is the TPU the kernel is written for (elsewhere it would run
+    under the Pallas interpreter, which costs a CPU test suite minutes
+    and gains nothing)."""
+    if cfg.kv_page_tokens is None or tokens_per_row != 1 \
+            or cfg.sliding_window is not None or not _on_tpu() \
+            or not _paged.pages_are_tiles(cfg.dtype, cfg.kv_page_tokens):
+        return False
+    mesh = _context_mesh()
+    return mesh is None or mesh.size == 1
+
+
 def _rope(x, positions, base: float):
     """Rotary embedding: rotate feature pairs of ``x [B, T, H, D]`` by
     position-dependent angles (``positions [T]``, or ``[B, T]`` when rows
@@ -384,6 +409,7 @@ class CausalSelfAttention(nn.Module):
             C = min(L, cfg.sliding_window) if rolling else L
             idx = ci.value
             paged = cfg.kv_page_tokens is not None
+            in_place = attends_pages_in_place(cfg, T)
             if paged:
                 # Paged pool: per-layer K/V is [P*pt, W] — one token's
                 # heads side by side in a row of whole 128-lane tiles
@@ -394,12 +420,16 @@ class CausalSelfAttention(nn.Module):
                 # the table and DROP out-of-range ones (unallocated page,
                 # or position >= max_len — e.g. a parked/finished row
                 # whose counter sits at C, or a speculative verify
-                # overshooting its budget); reads gather the row's full
-                # logical view [B, C, Hkv, D] back in ONE page gather
-                # (the sentinel clamps to garbage the positional mask
-                # hides), after which the shared per-row mask + grouped
-                # attention below apply unchanged — only the store/gather
-                # substrate differs from dense.
+                # overshooting its budget).  A decode step (T == 1)
+                # then attends over the pages in place
+                # (attends_pages_in_place: the kernel walks the table,
+                # stops at the row's length idx + 1 and reads the rows
+                # as stored; a parked row reads nothing).  Any other
+                # step gathers the row's full logical view
+                # [B, C, Hkv, D] back in one page gather (the sentinel
+                # clamps to garbage the positional mask hides), after
+                # which the shared per-row mask + grouped attention
+                # below apply unchanged.
                 pt = cfg.kv_page_tokens
                 P = cfg.kv_pool_pages
                 npg = C // pt
@@ -422,6 +452,8 @@ class CausalSelfAttention(nn.Module):
                                 B, Tw, Hkv * D),
                             ((0, 0), (0, 0), (0, W - Hkv * D)))
                         ref.value = ref.value.at[phys].set(row, mode="drop")
+                    if in_place:
+                        return None
                     with jax.named_scope("kv_gather"):
                         pool = ref.value.reshape(P, pt, W)
                         return pool[cbt.value][..., :Hkv * D].reshape(
@@ -490,28 +522,38 @@ class CausalSelfAttention(nn.Module):
                 k_all = store(ck, k.astype(cfg.dtype))
                 v_all = store(cv, v.astype(cfg.dtype))
             ci.value = idx + T
-            if per_row:
-                q_pos = idx[:, None] + jnp.arange(T)[None, :]        # [B, T]
-                k_pos = jnp.arange(L)
-                visible = k_pos[None, None, :] <= q_pos[:, :, None]  # [B,T,L]
-                if cfg.sliding_window is not None:
-                    visible &= k_pos[None, None, :] \
-                        > q_pos[:, :, None] - cfg.sliding_window
-            elif rolling:
-                # slot s holds position p(s) = the latest pos == s (mod C);
-                # visible iff written, causal, and inside the window
-                q_pos = (idx + jnp.arange(T))[:, None]               # [T, 1]
-                p_end = idx + T - 1
-                p_slot = p_end - ((p_end - jnp.arange(C)[None, :]) % C)
-                visible = (p_slot >= 0) & (p_slot <= q_pos) \
-                    & (p_slot > q_pos - cfg.sliding_window)
+            if in_place:
+                with jax.named_scope("paged_decode"):
+                    ctx = _paged.paged_decode_attention(
+                        q[:, 0], ck.value, cv.value, cbt.value,
+                        jnp.where(idx < C, idx + 1, 0), num_kv_heads=Hkv,
+                        page_tokens=pt)[:, None]
             else:
-                q_pos = (idx + jnp.arange(T))[:, None]               # [T, 1]
-                k_pos = jnp.arange(L)
-                visible = k_pos[None, :] <= q_pos                    # [T, L]
-                if cfg.sliding_window is not None:
-                    visible &= k_pos[None, :] > q_pos - cfg.sliding_window
-            ctx = grouped_attention(q, k_all, v_all, visible)
+                if per_row:
+                    q_pos = idx[:, None] + jnp.arange(T)[None, :]    # [B, T]
+                    k_pos = jnp.arange(L)
+                    # [B, T, L]
+                    visible = k_pos[None, None, :] <= q_pos[:, :, None]
+                    if cfg.sliding_window is not None:
+                        visible &= k_pos[None, None, :] \
+                            > q_pos[:, :, None] - cfg.sliding_window
+                elif rolling:
+                    # slot s holds position p(s) = the latest pos == s
+                    # (mod C); visible iff written, causal, and inside
+                    # the window
+                    q_pos = (idx + jnp.arange(T))[:, None]           # [T, 1]
+                    p_end = idx + T - 1
+                    p_slot = p_end - ((p_end - jnp.arange(C)[None, :]) % C)
+                    visible = (p_slot >= 0) & (p_slot <= q_pos) \
+                        & (p_slot > q_pos - cfg.sliding_window)
+                else:
+                    q_pos = (idx + jnp.arange(T))[:, None]           # [T, 1]
+                    k_pos = jnp.arange(L)
+                    visible = k_pos[None, :] <= q_pos                # [T, L]
+                    if cfg.sliding_window is not None:
+                        visible &= k_pos[None, :] \
+                            > q_pos - cfg.sliding_window
+                ctx = grouped_attention(q, k_all, v_all, visible)
         elif cfg.attention_fn is not None:
             if G > 1:  # kernels take equal head counts; broadcast K/V once
                 k = jnp.repeat(k, G, axis=2)

@@ -74,8 +74,9 @@ import numpy as np
 
 from tensorflowonspark_tpu import observability as _obs
 from tensorflowonspark_tpu.models import moe as _moe
-from tensorflowonspark_tpu.models.gpt import (GPT, GPTConfig, init_cache,
-                                              nucleus_filter,
+from tensorflowonspark_tpu.models.gpt import (GPT, GPTConfig,
+                                              attends_pages_in_place,
+                                              init_cache, nucleus_filter,
                                               set_cache_counters)
 from tensorflowonspark_tpu.models.kv_pages import KVPagePool, hash_page_data
 
@@ -128,6 +129,7 @@ class _Slot:
     top_p: float = 1.0
     seed: int = 0
     lease: object = None                        # paged mode: PageLease
+    prompt_len: int = 0     # paged mode: positions seated by the admission
 
 
 def _apply(model, params, cache, tokens, lengths=None):
@@ -573,6 +575,19 @@ class ContinuousBatcher:
         #: ``layer_types`` conv layers; 0 otherwise) —
         #: ``tfos_replica_state_rows_seated_total``
         self.state_rows_seated = 0
+        #: paged mode, per decode dispatch and summed over the seated rows:
+        #: the pages a row's length covers (``kv_pages_read``) and, only
+        #: when the step attends over the pages in place
+        #: (``models.gpt.attends_pages_in_place``), the pages of its whole
+        #: view (``kv_pages_viewed``).  read / viewed is the share of the
+        #: view the kernel touches; viewed == 0 says the gather path ran —
+        #: ``tfos_replica_kv_pages_read_total``, ``..._viewed_total``
+        self.kv_pages_read = 0
+        self.kv_pages_viewed = 0
+        #: whether this batcher's decode step attends in place: the model's
+        #: own rule, read once, in the scope the batcher is built and
+        #: stepped in
+        self._attends_in_place = attends_pages_in_place(self.cfg)
         #: set to the original error message the first time a device step
         #: raises mid-flight; every executable donates the cache buffer
         #: (``donate_argnums``), so after a failed dispatch the previous
@@ -1206,7 +1221,8 @@ class ContinuousBatcher:
                       tokens=list(sess["tokens"]),
                       temperature=float(sess.get("temperature", 0.0)),
                       top_p=float(sess.get("top_p", 1.0)),
-                      seed=int(sess.get("seed", 0)), lease=lease)
+                      seed=int(sess.get("seed", 0)), lease=lease,
+                      prompt_len=prompt.size)
             self.slots[free[0]] = s
 
     # -- prefix-cache cloning (warm-standby promotion; docs/robustness.md)
@@ -1647,23 +1663,24 @@ class ContinuousBatcher:
                          self.cfg.max_position_embeddings)
                 groups.setdefault(Tp, []).append((req, lease))
             free_iter = iter(free)
-            admitted = []   # (slot, req-fields, first_token, lease)
+            # (slot, req-fields, first_token, lease, prompt length)
+            admitted = []
             for reqs in self._prefill_groups(groups):
                 slots = [next(free_iter) for _ in reqs]
                 firsts = self._prefill_paged(
                     [(req, lease, lease.tail_start)
                      for req, lease in reqs], slots)
                 for j, (req, lease) in enumerate(reqs):
-                    rid, _, budget, temp, top_p, seed = req
+                    rid, prompt, budget, temp, top_p, seed = req
                     admitted.append((slots[j], (rid, budget, temp, top_p,
                                                 seed), int(firsts[j]),
-                                     lease))
-            for slot, (rid, budget, temp, top_p, seed), tok, lease \
+                                     lease, prompt.size))
+            for slot, (rid, budget, temp, top_p, seed), tok, lease, n \
                     in admitted:
                 self._emit_token(rid, tok)
                 s = _Slot(request_id=rid, remaining=budget - 1,
                           tokens=[tok], temperature=temp, top_p=top_p,
-                          seed=seed, lease=lease)
+                          seed=seed, lease=lease, prompt_len=n)
                 if s.remaining <= 0 or tok == self.eos_id:
                     self._finish(slot, s)   # slot stays free; loop refills
                     done.append(rid)
@@ -1857,7 +1874,8 @@ class ContinuousBatcher:
         tok = int(firsts[0])
         self._emit_token(rid, tok)
         s = _Slot(request_id=rid, remaining=budget - 1, tokens=[tok],
-                  temperature=temp, top_p=top_p, seed=seed, lease=lease)
+                  temperature=temp, top_p=top_p, seed=seed, lease=lease,
+                  prompt_len=prompt.size)
         if s.remaining <= 0 or tok == self.eos_id:
             self._finish(slot, s)
             return [rid]
@@ -1992,6 +2010,21 @@ class ContinuousBatcher:
                                                 donate_argnums=(1,))
         return self._prefill_jit["verify"]
 
+    def _count_kv_pages(self, steps: int = 1, tokens_per_row: int = 1):
+        """Account one decode dispatch of ``steps`` steps of
+        ``tokens_per_row`` tokens in ``kv_pages_read`` /
+        ``kv_pages_viewed``: host arithmetic over the seated slots'
+        lengths, no device work."""
+        if self._pages is None:
+            return
+        pt, C = self.cfg.kv_page_tokens, self.cfg.max_position_embeddings
+        lens = [s.prompt_len + len(s.tokens) + tokens_per_row - 1
+                for s in self.slots if s is not None]
+        self.kv_pages_read += sum(-(-min(n + k, C) // pt)
+                                  for n in lens for k in range(steps))
+        if tokens_per_row == 1 and self._attends_in_place:
+            self.kv_pages_viewed += steps * len(lens) * (C // pt)
+
     def _spec_step(self) -> list[int]:
         """One speculative decode step for every active slot: propose
         (draft model when armed, else host-side prompt lookup), then one
@@ -2044,6 +2077,7 @@ class ContinuousBatcher:
         self.decode_dispatches += 1
         self.decode_steps += 1
         with self._spans(_obs.BATCHER_DECODE_DISPATCH):
+            self._count_kv_pages(tokens_per_row=K + 1)
             a, bonus, self.cache = self._verify_jit()(
                 self.params, self.cache, jnp.asarray(toks), jnp.asarray(d),
                 jnp.asarray([s.seed if s else 0 for s in self.slots],
@@ -2186,6 +2220,7 @@ class ContinuousBatcher:
         self.decode_dispatches += 1
         self.decode_steps += K
         with self._spans(_obs.BATCHER_DECODE_DISPATCH):
+            self._count_kv_pages(steps=K)
             tokens = jnp.asarray([s.tokens[-1] if s else 0
                                   for s in self.slots], jnp.int32)
             if any(s is not None and s.temperature > 0 for s in self.slots):
@@ -2235,6 +2270,7 @@ class ContinuousBatcher:
         self.decode_steps += 1
         nxt, self._ahead = self._ahead, None
         with self._spans(_obs.BATCHER_DECODE_DISPATCH):
+            self._count_kv_pages()
             if nxt is not None:
                 pass            # dispatched ahead, during the last turn
             elif any(s is not None and s.temperature > 0

@@ -535,7 +535,8 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
         "entry or failed write, each degraded to a compile).",
         labelnames=("outcome",))
     # expert layers and conv state (models/moe.py, models/gpt.py
-    # ShortConv): what the batcher read with its tokens, as deltas
+    # ShortConv): what the batcher read with its tokens, as deltas; the
+    # paged decode step's page accounting likewise
     m_engine = {
         "expert_assignments": reg.counter(
             "tfos_replica_expert_assignments_total",
@@ -552,7 +553,17 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
         "state_rows_seated": reg.counter(
             "tfos_replica_state_rows_seated_total",
             "Rows whose conv state an admission wrote (configurations "
-            "with conv layers).")}
+            "with conv layers)."),
+        "kv_pages_read": reg.counter(
+            "tfos_replica_kv_pages_read_total",
+            "KV pages the seated rows' lengths cover, summed over decode "
+            "dispatches (paged mode)."),
+        "kv_pages_viewed": reg.counter(
+            "tfos_replica_kv_pages_viewed_total",
+            "KV pages of the seated rows' whole views, summed over the "
+            "decode dispatches that attended over the pages in place "
+            "(ops.paged_attention): read / viewed is the share the kernel "
+            "touches, 0 says the whole-view gather ran.")}
     last = {"decode_dispatches": 0, "prefill_dispatches": 0,
             **dict.fromkeys(m_engine, 0),
             "spec_proposed": 0, "spec_accepted": 0,

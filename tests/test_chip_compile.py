@@ -42,12 +42,23 @@ def one_chip(topo):
     from jax.experimental.compilation_cache.compilation_cache import \
         reset_cache
 
+    from tensorflowonspark_tpu.models import gpt
+    from tensorflowonspark_tpu.ops import paged_attention
+
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     reset_cache()
+    # the test process's default backend is the CPU, where the model's
+    # paged decode step takes the gather path and the kernel alone its
+    # interpreter: everything this module compiles is for the chip
+    seen = [(m, m._on_tpu) for m in (gpt, paged_attention)]
+    for m, _ in seen:
+        m._on_tpu = lambda: True
     try:
         yield SingleDeviceSharding(topo.devices[0])
     finally:
+        for m, fn in seen:
+            m._on_tpu = fn
         jax.config.update("jax_enable_compilation_cache", prev)
         reset_cache()
 
@@ -115,25 +126,39 @@ _POOL_WIDTHS = {"gpt2xl_25x64": dict(num_heads=25, hidden_size=1600),
 _POOL_PROGRAMS = {"decode_B16_T1": (16, 1), "prefill_B1_T256": (1, 256)}
 
 
-def _compile_paged_step(one_chip, B, T, **widths):
+def _compile_paged_step(one_chip, B, T, pool_pages=1024, steps=1, **widths):
     """The paged step through the repo's ``GPT`` (2 layers, the cell's
     1024 x 16-token pool, cache donated), compiled for the described
-    chip from shapes alone."""
+    chip from shapes alone; ``steps`` > 1 scans it, each step fed the
+    last one's tokens, as the batcher's ``decode_block_steps`` does."""
     from tensorflowonspark_tpu.models.gpt import GPT, GPTConfig, init_cache
 
     cfg = GPTConfig(num_layers=2, intermediate_size=4 * widths["hidden_size"],
                     per_row_positions=True, kv_page_tokens=16,
-                    kv_pool_pages=1024, **widths)
+                    kv_pool_pages=pool_pages, **widths)
     model = GPT(cfg, decode=True)
     params = jax.eval_shape(
         lambda: GPT(cfg).init(jax.random.key(0),
                               jnp.zeros((1, 8), jnp.int32))["params"])
     cache = jax.eval_shape(lambda p: init_cache(cfg, p, B), params)
 
-    def step(params, cache, tokens):
+    def one_step(params, cache, tokens):
         logits, vars_ = model.apply({"params": params, "cache": cache},
                                     tokens, mutable=["cache"])
         return jnp.argmax(logits[:, -1], -1), vars_["cache"]
+
+    def step(params, cache, tokens):
+        if steps == 1:
+            return one_step(params, cache, tokens)
+
+        def body(carry, _):
+            nxt, cache = one_step(params, carry[1], carry[0])
+            nxt = nxt[:, None].astype(jnp.int32)
+            return (nxt, cache), nxt
+
+        (_, cache), seq = jax.lax.scan(body, (tokens, cache), None,
+                                       length=steps)
+        return seq, cache
 
     def on_chip(tree):
         return jax.tree.map(lambda t: jax.ShapeDtypeStruct(
@@ -168,6 +193,69 @@ def test_paged_pool_is_stored_in_place_on_v5e(one_chip, widths, program):
     copies = [ln.strip()[:120] for ln in entry
               if re.search(rf"= \w+\[({tokens}),[\d,]+\]\S* copy\(", ln)]
     assert not copies, copies
+
+
+# -- the decode step attends over the pages in place (ISSUE 29) ------------
+# Both serve cells' decode programs at their real widths, rows and pools
+# (2 layers): the Mosaic kernel is in the program and fits its fast memory
+# (a refusal raises here), and no instruction has the shape of a row's
+# whole view any more.  A prefill is the program it was.
+
+_DECODE_CELLS = {
+    "gpt2xl_B16": dict(B=16, pool_pages=1024, num_heads=25,
+                       hidden_size=1600),
+    "lfm2_attention_B32": dict(
+        B=32, pool_pages=4096, num_heads=32, num_kv_heads=8,
+        hidden_size=2048, max_position_embeddings=2048, pos_encoding="rope",
+        rope_base=1e6, norm="rmsnorm", use_bias=False, qk_norm=True)}
+
+
+@pytest.mark.parametrize("cell", sorted(_DECODE_CELLS))
+def test_decode_step_attends_over_the_pages_in_place_on_v5e(one_chip, cell):
+    import re
+
+    from tensorflowonspark_tpu.models.gpt import kv_row_width
+
+    widths = dict(_DECODE_CELLS[cell])
+    B = widths.pop("B")
+    cfg, text = _compile_paged_step(one_chip, B, 1, **widths)
+    Hkv = cfg.num_kv_heads or cfg.num_heads
+    assert text.count("tpu_custom_call") >= cfg.num_layers
+    assert "tfos_paged_decode_attention" in text
+    C, pt = cfg.max_position_embeddings, cfg.kv_page_tokens
+    W = kv_row_width(Hkv, cfg.head_dim)
+    views = (f"{B},{C},{Hkv},{cfg.head_dim}", f"{B},{C // pt},{pt},{W}",
+             f"{C},{B},{W}", f"{B},{C},{W}")
+    whole = [ln.strip()[:120] for ln in text.splitlines()
+             if re.search(r"= \(?\w+\[(" + "|".join(views) + r")\]", ln)]
+    assert not whole, whole
+
+
+@pytest.mark.parametrize("how,kw,calls", [
+    ("block_of_4_steps", {"steps": 4}, 2),
+    ("scan_layers", {"scan_layers": True}, 1)])
+def test_kernel_compiles_inside_a_scan_on_v5e(one_chip, how, kw, calls):
+    """``jit_tfos_decode_block`` scans the step, ``scan_layers`` the
+    layers: the kernel is inside the scanned body either way."""
+    _, text = _compile_paged_step(one_chip, 16, 1, **kw,
+                                  **_POOL_WIDTHS["gpt2xl_25x64"])
+    assert text.count("tpu_custom_call") == calls
+    assert "16,1024,25,64" not in text
+
+
+def test_prefill_is_the_gather_path_program_on_v5e(one_chip, monkeypatch):
+    """The same text whether the decode step's rule exists or not (both
+    compiles from one source line: the text carries the call's frames)."""
+    from tensorflowonspark_tpu.models import gpt
+
+    texts = []
+    for rule in (None, lambda *a: False):
+        if rule is not None:
+            monkeypatch.setattr(gpt, "attends_pages_in_place", rule)
+        texts.append(_compile_paged_step(
+            one_chip, 1, 256, **_POOL_WIDTHS["gpt2xl_25x64"])[1])
+    assert "tpu_custom_call" not in texts[0]
+    assert texts[0] == texts[1]
 
 
 # -- the layer pattern at LFM2-8B-A1B's widths (ISSUE 28) -------------------

@@ -35,6 +35,7 @@ import contextlib
 import itertools
 import logging
 import multiprocessing as mp
+import multiprocessing.connection
 import os
 import secrets
 import tempfile
@@ -100,6 +101,12 @@ class LocalProcessBackend:
     def __init__(self, worker_env: dict | None = None):
         self.worker_env = worker_env or {}
         self.procs: list[mp.Process] = []
+        # mp.Process is not thread-safe: the health monitor's thread polls
+        # exit codes while shutdown() joins, and of two waitpid()s on one
+        # child the loser gets ECHILD, which Process reads as "not started
+        # yet" — a worker that is gone then counts as alive until the
+        # winner has stored the code.  Every reap takes this lock.
+        self._reap_lock = threading.Lock()
 
     def start(self, num_workers: int, fn, tf_args, cluster_meta: dict, queues) -> None:
         self.procs = []  # restartable: a relaunch must not index old procs
@@ -130,31 +137,40 @@ class LocalProcessBackend:
                     f"{len(self.procs)})")
             self._spawn(i, fn, tf_args, cluster_meta, queues)
 
+    def _exitcode(self, p: mp.Process) -> int | None:
+        """``p``'s exit code, None while it runs — the one place a worker
+        is reaped.  A child's sentinel closes an instant before the child
+        can be waited for, so once it has closed the reap waits for it."""
+        with self._reap_lock:
+            if p.exitcode is None and mp.connection.wait([p.sentinel], 0):
+                p.join(5)
+            return p.exitcode
+
     def alive(self) -> list[bool]:
-        return [p.is_alive() for p in self.procs]
+        return [c is None for c in self.exitcodes().values()]
 
     def failed(self) -> list[int]:
-        return [i for i, p in enumerate(self.procs)
-                if (not p.is_alive()) and p.exitcode not in (0, None)]
+        return [i for i, c in self.exitcodes().items() if c not in (0, None)]
 
     def exitcodes(self) -> dict[int, int | None]:
         """Exit codes by executor id (None while alive) — the monitor's
         crash-vs-preemption classifier reads the signal number from here."""
-        return {i: p.exitcode for i, p in enumerate(self.procs)}
+        return {i: self._exitcode(p) for i, p in enumerate(self.procs)}
 
     def join(self, timeout: float | None = None) -> bool:
         deadline = None if timeout is None else time.monotonic() + timeout
         for p in self.procs:
             remaining = None if deadline is None else max(0.0, deadline - time.monotonic())
-            p.join(remaining)
-        return all(not p.is_alive() for p in self.procs)
+            # the sentinel, not p.join: waiting on it reaps nothing, so the
+            # lock is never held across a wait
+            mp.connection.wait([p.sentinel], remaining)
+        return not any(self.alive())
 
     def terminate(self) -> None:
-        for p in self.procs:
-            if p.is_alive():
+        for p, alive in zip(self.procs, self.alive()):
+            if alive:
                 p.terminate()
-        for p in self.procs:
-            p.join(5)
+        self.join(5)
 
 
 class TPUCluster:

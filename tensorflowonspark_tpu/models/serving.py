@@ -14,11 +14,11 @@ static-shape KV cache:
 - every slot decodes at its own cache offset
   (``GPTConfig.per_row_positions``: the per-layer ``index`` and
   learned-position ``pos`` counters are ``[B]`` vectors);
-- new requests are PREFILLED on a fresh side cache — same-bucket
-  arrivals admitted together share ONE batched prefill dispatch — then
-  their cache rows and counters are scattered into free slots in one
-  indexed scatter (running slots never recompile, never stall, and
-  never see the new prompts);
+- new requests are PREFILLED straight into their leased pages —
+  same-bucket arrivals admitted together share ONE batched prefill
+  dispatch, which also seats their block tables and counters in free
+  slots (running slots never recompile, never stall, and never see
+  the new prompts);
 - a finished slot is released immediately and can be re-admitted on the
   very next step.
 
@@ -29,28 +29,26 @@ power-of-two block size actually taken — O(log K), each reused for the
 lifetime), one prefill
 executable per (power-of-two prompt BUCKET, power-of-two admission
 GROUP size) pair — prompts are right-padded internally and the pad
-positions provably never leak (see ``_prefill_final``), so
+positions provably never leak (see ``_prefill``), so
 arbitrary-length traffic costs O(log max_len x log max_batch)
 compiles, not one per length; with ``prefill_chunk`` long prompts add
 one fixed-chunk executable and stream through the cache solo,
 TIME-SLICED one chunk per step so running slots keep decoding while a
 long admission is in flight, with O(chunk x max_len) transient
-attention memory — and one scatter executable per group size.  A
-BURST of arrivals therefore costs
-O(distinct buckets) device dispatches, not O(requests): the admission
-regime continuous batching exists for.  The decode loop itself is
-plain Python — admission decisions are host-side control flow,
-exactly what should NOT be traced.
+attention memory.  A BURST of arrivals therefore costs O(distinct
+buckets) device dispatches, not O(requests): the admission regime
+continuous batching exists for.  The decode loop itself is plain
+Python — admission decisions are host-side control flow, exactly what
+should NOT be traced.
 
-With ``kv_page_tokens`` the cache substrate goes PAGED (vLLM-shaped):
-per-layer K/V pools behind per-row block tables (``models/gpt.py``),
-host-side page accounting with a refcounted shared-prefix index
-(``models/kv_pages.py``), admission tied to free PAGES instead of free
-slots, and prefix-hit requests prefilling only their tails — the
-fused ``_prefill_paged`` executable prefills, selects first tokens,
-and scatters block tables + counters in one dispatch.  Same O(log)
-executable-count discipline, same output contract (docs/serving.md
-"KV paging & prefix cache").
+The cache substrate is PAGED (vLLM-shaped), and it is the only one:
+per-layer K/V pools of ``kv_page_tokens``-token pages behind per-row
+block tables (``models/gpt.py``), host-side page accounting with a
+refcounted shared-prefix index (``models/kv_pages.py``), admission
+tied to free PAGES as well as free slots, and prefix-hit requests
+prefilling only their tails — the fused ``_prefill`` executable
+prefills, selects first tokens, and scatters block tables + counters
+in one dispatch (docs/serving.md "KV paging & prefix cache").
 
 Output contract (locked by ``tests/test_serving.py``): a request's
 tokens are a pure function of its own (params, prompt, budget,
@@ -76,8 +74,7 @@ from tensorflowonspark_tpu import observability as _obs
 from tensorflowonspark_tpu.models import moe as _moe
 from tensorflowonspark_tpu.models.gpt import (GPT, GPTConfig,
                                               attends_pages_in_place,
-                                              init_cache, nucleus_filter,
-                                              set_cache_counters)
+                                              init_cache, nucleus_filter)
 from tensorflowonspark_tpu.models.kv_pages import KVPagePool, hash_page_data
 
 #: compile site -> the program's name, by ROLE and never by shape: the
@@ -88,9 +85,7 @@ from tensorflowonspark_tpu.models.kv_pages import KVPagePool, hash_page_data
 PROGRAM_NAMES = {
     "step": "tfos_decode", "step_sample": "tfos_decode_sampled",
     "block": "tfos_decode_block", "verify": "tfos_verify",
-    "final": "tfos_prefill", "pfinal": "tfos_prefill",
-    "chunk": "tfos_prefill_chunk", "pchunk": "tfos_prefill_chunk",
-    "zeros": "tfos_kv_zeros", "scatter": "tfos_kv_scatter",
+    "final": "tfos_prefill", "chunk": "tfos_prefill_chunk",
     "padopt": "tfos_kv_seat", "park": "tfos_kv_park",
     "pexport": "tfos_kv_export", "draft_propose": "tfos_draft"}
 
@@ -128,8 +123,8 @@ class _Slot:
     temperature: float = 0.0                    # 0 = greedy
     top_p: float = 1.0
     seed: int = 0
-    lease: object = None                        # paged mode: PageLease
-    prompt_len: int = 0     # paged mode: positions seated by the admission
+    lease: object = None                        # its PageLease
+    prompt_len: int = 0     # positions seated by the admission
 
 
 def _apply(model, params, cache, tokens, lengths=None):
@@ -330,7 +325,7 @@ class ContinuousBatcher:
                  decode_block_steps: int | None = None,
                  kv_page_tokens: int | None = None,
                  kv_pool_pages: int | None = None,
-                 prefix_cache: bool = True,
+                 prefix_cache: bool | None = None,
                  prefill_only: bool = False,
                  prefill_rows_max: int | None = None,
                  decode_ahead: bool = False,
@@ -342,7 +337,7 @@ class ContinuousBatcher:
             if speculative_k is not None:
                 why = ("speculative_k rewinds the cache after each verify "
                        "dispatch")
-            elif kv_page_tokens is not None and prefix_cache:
+            elif prefix_cache:
                 why = ("prefix_cache=True lets a request start from another "
                        "request's shared pages, which hold no conv state at "
                        "their end — pass prefix_cache=False")
@@ -396,16 +391,11 @@ class ContinuousBatcher:
                 "running one; speculative_k and decode_block_steps decide "
                 "each dispatch from the last one's tokens — they are "
                 "alternatives")
-        if prefill_only:
-            if kv_page_tokens is None:
-                raise ValueError("prefill_only needs kv_page_tokens: the "
-                                 "KV-page handoff a prefill pool emits is "
-                                 "page-granular (docs/serving.md "
-                                 "\"Disaggregated prefill/decode\")")
-            if speculative_k is not None or decode_block_steps is not None:
-                raise ValueError("prefill_only is a prefill-pool posture; "
-                                 "speculative_k/decode_block_steps are "
-                                 "decode-time knobs")
+        if prefill_only and (speculative_k is not None
+                             or decode_block_steps is not None):
+            raise ValueError("prefill_only is a prefill-pool posture; "
+                             "speculative_k/decode_block_steps are "
+                             "decode-time knobs")
         #: multi-step decode: when no admission work is pending, run up
         #: to this many decode steps inside ONE ``lax.scan`` dispatch
         #: (power-of-two block sizes -> O(log block) compiles).  The
@@ -479,39 +469,41 @@ class ContinuousBatcher:
         #: tokens on the device), and how many there were in all
         self._ahead = None
         self.decode_ahead_dispatches = 0
-        #: PAGED KV mode (``kv_page_tokens`` set, a power of two): the
-        #: per-slot dense cache becomes a pool of ``kv_pool_pages``
-        #: fixed-size pages behind per-row block tables (``models/gpt``
-        #: device side, ``models/kv_pages`` host-side accounting), with
-        #: admission tied to FREE PAGES instead of free slots and —
-        #: unless ``prefix_cache=False`` — a refcounted shared-prefix
-        #: index so a request whose prompt starts like a cached one
-        #: skips straight to prefilling the tail.  Token-exact vs the
-        #: dense cache on hit and miss paths alike (the locked greedy
-        #: oracle covers both).
-        if kv_page_tokens is not None:
-            pt = int(kv_page_tokens)
-            per_req = -(-cfg.max_position_embeddings // pt)
-            # default pool = dense-equivalent capacity (every slot can
-            # hold a max-length request); smaller pools are legal — the
-            # memory lever — and ``submit`` rejects any single request
-            # the whole pool cannot hold, so admission stays live
-            pool_pages = (int(kv_pool_pages) if kv_pool_pages is not None
-                          else int(max_batch) * per_req)
-            # dataclass validation (pow2, divisibility, int8/rolling
-            # conflicts) happens in GPTConfig.__post_init__
-            self.cfg = dataclasses.replace(
-                cfg, per_row_positions=True, kv_page_tokens=pt,
-                kv_pool_pages=pool_pages)
-            self._pages = KVPagePool(pool_pages, pt,
-                                     prefix_cache=bool(prefix_cache))
+        #: THE K/V STORE: a pool of ``kv_pool_pages`` pages of
+        #: ``kv_page_tokens`` tokens (a power of two) behind per-row block
+        #: tables (``models/gpt`` device side, ``models/kv_pages``
+        #: host-side accounting), with admission tied to FREE PAGES as
+        #: well as free slots and — unless ``prefix_cache=False`` — a
+        #: refcounted shared-prefix index so a request whose prompt starts
+        #: like a cached one skips straight to prefilling the tail.
+        #: Token-exact vs the generators' dense cache on hit and miss
+        #: paths alike (the locked greedy oracle covers both).
+        if kv_page_tokens is None:
+            # the page every cell serves from, halved until it divides
+            # the window so that a toy configuration still builds
+            pt = 16
+            while cfg.max_position_embeddings % pt:
+                pt //= 2
         else:
-            if kv_pool_pages is not None:
-                raise ValueError("kv_pool_pages needs kv_page_tokens")
-            self._pages = None
-            self.cfg = dataclasses.replace(cfg, per_row_positions=True)
-        # prefill runs single-row, where per-row == scalar semantics; one
-        # cfg keeps the two paths' traces structurally identical
+            pt = int(kv_page_tokens)
+        per_req = -(-cfg.max_position_embeddings // pt)
+        # default pool: every slot can hold a max-length request; smaller
+        # pools are legal — the memory lever — and ``submit`` rejects any
+        # single request the whole pool cannot hold, so admission stays
+        # live
+        pool_pages = (int(kv_pool_pages) if kv_pool_pages is not None
+                      else int(max_batch) * per_req)
+        # dataclass validation (pow2, divisibility, int8/rolling
+        # conflicts) happens in GPTConfig.__post_init__
+        self.cfg = dataclasses.replace(
+            cfg, per_row_positions=True, kv_page_tokens=pt,
+            kv_pool_pages=pool_pages)
+        # pages shared from another request's prompt hold no conv state
+        # at their end, so a configuration with conv layers has no index
+        self._pages = KVPagePool(
+            pool_pages, pt,
+            prefix_cache=(not cfg.has_conv if prefix_cache is None
+                          else bool(prefix_cache)))
         self.params = params
         #: the compiled executables are keyed on this tree's structure +
         #: leaf shapes/dtypes; load_params validates every later tree
@@ -575,7 +567,7 @@ class ContinuousBatcher:
         #: ``layer_types`` conv layers; 0 otherwise) —
         #: ``tfos_replica_state_rows_seated_total``
         self.state_rows_seated = 0
-        #: paged mode, per decode dispatch and summed over the seated rows:
+        #: per decode dispatch and summed over the seated rows:
         #: the pages a row's length covers (``kv_pages_read``) and, only
         #: when the step attends over the pages in place
         #: (``models.gpt.attends_pages_in_place``), the pages of its whole
@@ -612,11 +604,9 @@ class ContinuousBatcher:
         #: prompt per live request (speculative drafting needs the full
         #: history); dropped at finish so memory tracks the in-flight set
         self._prompts: dict[int, np.ndarray] = {}
-        # compiled-prefill registry:
-        #   ("final", pow2_bucket, pow2_rows) -> batched prefill jit,
-        #   ("chunk", chunk_len) -> chunk jit,
-        #   ("zeros", rows) -> fresh side-cache allocator,
-        #   ("scatter", rows) -> indexed row scatter jit
+        # compiled-program registry, keyed by site (PROGRAM_NAMES) and
+        # shape: ("final", pow2_bucket, pow2_rows) -> batched prefill jit,
+        # ("chunk", chunk_len) -> chunk jit, ...
         self._prefill_jit: dict = {}
         #: optional :class:`~tensorflowonspark_tpu.serving.aot.
         #: AOTExecutableCache`: every compile site below routes through
@@ -692,37 +682,6 @@ class ContinuousBatcher:
             out = out[:-n]
         return out if shape is None else out.reshape(shape)
 
-    def _scatter_rows(self, row_cache, slot_idx: list[int]) -> None:
-        """Write a prefilled side cache's rows into the batch slots named
-        by ``slot_idx`` — ONE indexed-scatter dispatch regardless of how
-        many rows were admitted.  Pad rows (group padded to a power of
-        two) carry slot index ``max_batch``: out of bounds, dropped by
-        ``mode="drop"``, so their garbage prefill never lands."""
-        rp = len(slot_idx)
-        key = ("scatter", rp)
-        if key not in self._prefill_jit:
-            scan = self.cfg.scan_layers
-
-            def scatter_fn(cache, rows, slots):
-                def put(path, m, s):
-                    is_counter = getattr(path[-1], "key", None) in ("index",
-                                                                    "pos")
-                    axis = (m.ndim - 1) if is_counter else (1 if scan else 0)
-                    mm = jnp.moveaxis(m, axis, 0)
-                    ss = jnp.moveaxis(s.astype(m.dtype), axis, 0)
-                    return jnp.moveaxis(mm.at[slots].set(ss, mode="drop"),
-                                        0, axis)
-                return jax.tree_util.tree_map_with_path(put, cache, rows)
-
-            self._prefill_jit[key] = self._jit(key, scatter_fn,
-                                               donate_argnums=(0,))
-        if self.cfg.has_conv:
-            self.state_rows_seated += sum(i < self.max_batch
-                                          for i in slot_idx)
-        with self._spans(_obs.BATCHER_PREFILL_DISPATCH):
-            self.cache = self._prefill_jit[key](
-                self.cache, row_cache, jnp.asarray(slot_idx, jnp.int32))
-
     def _check_usable(self) -> None:
         if self._poisoned is not None:
             raise RuntimeError(
@@ -767,9 +726,7 @@ class ContinuousBatcher:
         shapes/dtypes differ from the compiled ones raises
         ``ValueError`` (the multi-model hot-swap path turns this into a
         typed ``model_swap_failed`` instead of a poisoned dispatch).
-        Dense-row KV state from before the swap is dead
-        (every admission prefills its own rows from scratch), and the
-        paged pool's PREFIX INDEX is rebuilt empty — cached pages hold
+        The pool's PREFIX INDEX is rebuilt empty — cached pages hold
         KV computed under the OLD weights, and a post-swap prefix hit
         against them would silently decode wrong tokens when the new
         tree differs (e.g. a later-checkpoint restore)."""
@@ -791,14 +748,13 @@ class ContinuousBatcher:
                 f"{leaves[bad[0]]}, want {want_leaves[bad[0]]}) — an "
                 "incompatible model version cannot reuse these "
                 "executables")
-        if self._pages is not None:
-            # idle by the unload_params contract: every page is free or
-            # parked in the (now-stale) prefix cache — a fresh pool of
-            # the same geometry drops the index without touching the
-            # device-side tables (idle rows are parked at the sentinel)
-            self._pages = KVPagePool(
-                self._pages.total_pages, self._pages.page_tokens,
-                prefix_cache=self._pages.prefix_cache)
+        # idle by the unload_params contract: every page is free or
+        # parked in the (now-stale) prefix cache — a fresh pool of the
+        # same geometry drops the index without touching the device-side
+        # tables (idle rows are parked at the sentinel)
+        self._pages = KVPagePool(
+            self._pages.total_pages, self._pages.page_tokens,
+            prefix_cache=self._pages.prefix_cache)
         self.params = params
 
     def set_role(self, role: str | None) -> None:
@@ -807,8 +763,8 @@ class ContinuousBatcher:
         prefill/decode tier (a standby's engine is built role-less so
         ONE pool can back both specializations).  ``"prefill"`` flips
         :attr:`prefill_only` on, under the same constraints the
-        constructor enforces (paged KV, no decode-time amortization
-        knobs); ``"decode"``/``None`` flips it off (adoption readiness is
+        constructor enforces (no decode-time amortization knobs);
+        ``"decode"``/``None`` flips it off (adoption readiness is
         checked by ``adopt_session`` itself).  Only legal while no
         request is live: a seated request's posture must never change
         under it."""
@@ -825,11 +781,6 @@ class ContinuousBatcher:
                     "prefill role exports K/V pages as the whole of a "
                     "session; this configuration keeps "
                     f"{self.cfg.cache_kinds}")
-            if self._pages is None:
-                raise ValueError(
-                    "prefill role needs paged KV (kv_page_tokens): the "
-                    "KV-page handoff a prefill pool emits is "
-                    "page-granular")
             if self.spec_k is not None or self.decode_block_steps is not None:
                 raise ValueError(
                     "prefill role conflicts with speculative_k/"
@@ -913,31 +864,25 @@ class ContinuousBatcher:
         submit"; this answers "how deep is the queue", which is what
         least-loaded routing across replicas needs.
 
-        ``free_pages``/``total_pages`` surface KV memory pressure in
-        paged mode (``kv_page_tokens``): free counts allocatable pages
-        RIGHT NOW (free + evictable cached prefix pages) — the signal
-        ``serve_replica`` forwards so the scheduler's least-outstanding
-        routing can tie-break away from memory-starved replicas.  Both
-        are 0 for a dense-cache batcher (no pressure signal: every
-        replica ties equal)."""
+        ``free_pages``/``total_pages`` surface KV memory pressure: free
+        counts allocatable pages RIGHT NOW (free + evictable cached
+        prefix pages) — the signal ``serve_replica`` forwards so the
+        scheduler's least-outstanding routing can tie-break away from
+        memory-starved replicas."""
         active = sum(s is not None for s in self.slots)
         pending = len(self._pending) + len(self._pending_adopt) \
             + (1 if self._inflight is not None else 0)
-        pages = self._pages
         return {"active": active, "pending": pending,
                 "reserved": len(self._reserved), "total": active + pending,
-                "free_pages": 0 if pages is None else pages.free_pages(),
-                "total_pages": 0 if pages is None else pages.total_pages}
+                "free_pages": self._pages.free_pages(),
+                "total_pages": self._pages.total_pages}
 
     def prefix_stats(self) -> dict:
-        """Prefix-cache admission outcomes (zeros for a dense batcher):
+        """Prefix-cache admission outcomes:
         ``hit`` = every shareable prompt page was already cached,
         ``partial`` = some were, ``miss`` = none; plus ``evictions`` and
         the page-capacity gauges — the source for the replica-side
         ``tfos_replica_prefix_cache_requests_total`` metrics."""
-        if self._pages is None:
-            return {"hit": 0, "miss": 0, "partial": 0, "evictions": 0,
-                    "free_pages": 0, "cached_pages": 0, "total_pages": 0}
         return self._pages.stats()
 
     # -- KV-page session handoff (docs/serving.md "Disaggregated
@@ -1104,10 +1049,6 @@ class ContinuousBatcher:
             raise ValueError(
                 "adopt_session seats a session from its K/V pages alone; "
                 f"this configuration keeps {self.cfg.cache_kinds}")
-        if self._pages is None:
-            raise ValueError(
-                "adopt_session needs paged KV mode (kv_page_tokens); this "
-                f"batcher keeps dense rows of {self.cfg.cache_kinds}")
         if self.prefill_only:
             raise ValueError("a prefill-only batcher cannot adopt "
                              "sessions (it never decode-steps)")
@@ -1214,7 +1155,7 @@ class ContinuousBatcher:
             self._seat_pages_device(free[0], lease.page_ids, import_ids,
                                     kv_sel, prompt.size)
             # commit AFTER the import dispatch: only written pages are
-            # ever matchable (the _prefill_paged contract)
+            # ever matchable (the _prefill contract)
             self._pages.commit(lease)
             self.sessions_adopted += 1
             s = _Slot(request_id=rid, remaining=int(sess["remaining"]),
@@ -1232,13 +1173,9 @@ class ContinuousBatcher:
         indexed page, donor insertion order, content-hashed) for a peer
         to import — the page-transfer plane's bulk edition, ridden by
         the standby promotion clone so a healed replica keeps its
-        peer's prefix hits.  None when dense or empty.  Must run on the
+        peer's prefix hits.  None when empty.  Must run on the
         batcher's driving thread (the gather reads the live cache)."""
-        if self._pages is None:
-            return None
-        entries = self._pages.export_index()
-        if max_pages is not None:
-            entries = entries[:max_pages]
+        entries = self._pages.export_index()[:max_pages]
         if not entries:
             return None
         pids = [pid for _, pid in entries]
@@ -1256,7 +1193,7 @@ class ContinuousBatcher:
         verified first (corrupt transfers raise, they never reach the
         device); capacity truncation keeps chains reachable (donor
         order).  Returns the number of pages imported."""
-        if self._pages is None or not export:
+        if not export:
             return 0
         if int(export.get("page_tokens", -1)) != self._pages.page_tokens \
                 or export.get("struct") != self._kv_struct():
@@ -1333,8 +1270,7 @@ class ContinuousBatcher:
                 f"({max_new_tokens}) = {total} exceeds "
                 f"max_position_embeddings "
                 f"({self.cfg.max_position_embeddings})")
-        if self._pages is not None \
-                and self._pages.pages_needed(total) > self._pages.total_pages:
+        if self._pages.pages_needed(total) > self._pages.total_pages:
             # liveness guard: a request the WHOLE pool cannot hold would
             # wait at the head of the queue forever (prefix sharing
             # could shrink its need, but cached pages are evictable and
@@ -1355,127 +1291,6 @@ class ContinuousBatcher:
             # prompt (page chain keys + the decode pool's replay input)
             self._prompts[rid] = prompt
         return rid
-
-    def _fresh_rows_cache(self, rows: int):
-        """Zeroed ``rows``-row side cache (compiled allocation, cached
-        trace per row count)."""
-        key = ("zeros", rows)
-        if key not in self._prefill_jit:
-            template = jax.eval_shape(
-                lambda: init_cache(self.cfg, self.params, rows))
-            self._prefill_jit[key] = self._jit(
-                key, lambda: jax.tree.map(
-                    lambda t: jnp.zeros(t.shape, t.dtype), template))
-        return self._prefill_jit[key]()
-
-    def _chunk_jit(self):
-        C = self.prefill_chunk
-        if ("chunk", C) not in self._prefill_jit:
-            def chunk_fn(params, cache, tokens_row):
-                return _apply(self.model, params, cache, tokens_row)[1]
-            self._prefill_jit[("chunk", C)] = self._jit(
-                ("chunk", C), chunk_fn, donate_argnums=(1,))
-        return self._prefill_jit[("chunk", C)]
-
-    def _advance_inflight(self) -> list[int]:
-        """Advance the in-flight chunked admission by ONE chunk (the
-        time slice), or finish it: run the bucketed final call on the
-        remainder and scatter into the reserved slot.  Long-context
-        admission therefore costs one extra dispatch per decode step
-        instead of stalling every running slot for the whole chunk
-        loop — O(chunk x max_len) transient attention memory per slice,
-        same as before."""
-        inf = self._inflight
-        C = self.prefill_chunk
-        rid, prompt, budget, temp, top_p, seed = inf["req"]
-        n_full = (prompt.size - 1) // C   # >= 1 token left for the final
-        i = inf["done_chunks"]
-        if i < n_full:
-            with self._spans(_obs.BATCHER_PREFILL_DISPATCH):
-                inf["cache"] = self._chunk_jit()(
-                    self.params, inf["cache"],
-                    prompt[None, i * C:(i + 1) * C])
-            inf["done_chunks"] += 1
-            return []
-        first, row_cache = self._prefill_final(
-            inf["cache"], [prompt[n_full * C:]], [prompt.size],
-            [temp], [top_p], [seed])
-        slot = inf["slot"]
-        self._reserved.discard(slot)
-        self._scatter_rows(row_cache, [slot])
-        self._inflight = None
-        with self._spans(_obs.BATCHER_PREFILL_FETCH):
-            tok = int(self._fetch(first)[0])
-        self._emit_token(rid, tok)
-        s = _Slot(request_id=rid, remaining=budget - 1, tokens=[tok],
-                  temperature=temp, top_p=top_p, seed=seed)
-        if s.remaining <= 0 or tok == self.eos_id:
-            self._finish(slot, s)
-            return [rid]
-        self.slots[slot] = s
-        return []
-
-    def _prefill_final(self, cache, rests: list, true_totals: list,
-                       temps: list, top_ps: list, seeds: list):
-        """THE bucketed prefill call — a whole-prompt admission GROUP
-        (same power-of-two bucket, fresh ``len(rests)``-row side cache)
-        and the last chunk of a chunked prefill (1-row cache) both end
-        here.  Returns ``(first_tokens, row_caches)``; entries past
-        ``len(rests)`` are padding.
-
-        Prompts are right-padded to the bucket length and the group to
-        the cache's power-of-two row count, so the compile count is
-        O(log max_len x log max_batch) instead of O(distinct lengths x
-        group sizes) (a TPU compile is tens of seconds; arbitrary
-        serving traffic must not pay one per shape).  Why padding is
-        exact: prefill attention is causal, so pad tokens never
-        influence a true last position's logits (selected per row at
-        ``true_len - 1``), and a conv layer takes its state at the row's
-        true length (``ShortConv``'s ``lengths``), where no pad token has
-        entered it; each row's cache counters are then REWOUND
-        to its ``true_total``, after which the positional visibility
-        mask hides every pad slot (``k_pos > q_pos``) until the decode
-        loop overwrites it with a real token's K/V in the same forward
-        that first makes it visible; and pad ROWS never reach the
-        batch — their out-of-bounds slot index drops them at scatter.
-        One executable serves greedy and sampled requests
-        (``_select_tokens`` reduces to argmax at temperature 0)."""
-        R = len(rests)
-        rp = jax.tree.leaves(cache)[0].shape[
-            1 if self.cfg.scan_layers else 0]    # cache row count (pow2)
-        Tp = min(_next_pow2(max(r.size for r in rests)),
-                 self.cfg.max_position_embeddings)
-        key = ("final", Tp, rp)
-        if key not in self._prefill_jit:
-            def final_fn(params, cache, tokens, true_len, true_tot,
-                         seeds, temps, top_ps):
-                last, cache, stats = self._last_logits(params, cache,
-                                                       tokens, true_len)
-                first = _select_tokens(
-                    last, seeds, jnp.zeros_like(true_len), temps, top_ps)
-                return _pack(first, stats), \
-                    set_cache_counters(cache, true_tot)
-            self._prefill_jit[key] = self._jit(key, final_fn,
-                                               donate_argnums=(1,))
-        self.prefill_dispatches += 1
-        with self._spans(_obs.BATCHER_PREFILL_DISPATCH):
-            padded = np.zeros((rp, Tp), np.int32)
-            true_len = np.ones((rp,), np.int32)
-            for j, r in enumerate(rests):
-                padded[j, :r.size] = r
-                true_len[j] = r.size
-            tot = np.ones((rp,), np.int32)
-            tot[:R] = true_totals
-            seed_a = np.zeros((rp,), np.int32)
-            seed_a[:R] = seeds
-            temp_a = np.zeros((rp,), np.float32)
-            temp_a[:R] = temps
-            top_a = np.ones((rp,), np.float32)
-            top_a[:R] = top_ps
-            return self._prefill_jit[key](
-                self.params, cache, padded,
-                jnp.asarray(true_len), jnp.asarray(tot),
-                jnp.asarray(seed_a), jnp.asarray(temp_a), jnp.asarray(top_a))
 
     def _last_logits(self, params, cache, tokens, true_len):
         """A padded prefill's forward: ``(logits at each row's last true
@@ -1503,108 +1318,29 @@ class ContinuousBatcher:
         requests that finished AT admission (1-token budget or immediate
         eos) so ``step()`` can report them.
 
+        Each taken request first LEASES pages — a prefix-index match
+        plus freshly allocated tail pages — and a request the pool
+        cannot serve right now blocks the queue (strict-FIFO page
+        backpressure: pages free as running requests finish, so the
+        head admits eventually; ``submit`` already rejected requests
+        larger than the whole pool, so this cannot deadlock).
+
         Burst admission: requests taken this round are grouped by
-        power-of-two prompt bucket and each group shares ONE batched
-        prefill dispatch plus one scatter — O(distinct buckets) device
-        dispatches for the round, not O(requests).  Prompts beyond
+        power-of-two bucket of their TAIL length — after its prefix
+        match a 10k-token prompt with a cached system prompt shares the
+        short-tail executable, which is the TTFT win — and each group
+        shares ONE batched prefill dispatch: O(distinct buckets) device
+        dispatches for the round, not O(requests).  Tails beyond
         ``prefill_chunk`` stream through the at-most-one in-flight
         chunked admission, one chunk per step (``_advance_inflight``),
         with their slot reserved until the final chunk lands.  The loop
         repeats while finished-at-admission requests keep freeing
         slots."""
-        if self._pages is not None:
-            return self._admit_paged()
-        done = []
-        if self._inflight is not None:
-            done.extend(self._advance_inflight())
-        while self._pending:
-            free = [i for i, s in enumerate(self.slots)
-                    if s is None and i not in self._reserved]
-            if not free:
-                break
-            C = self.prefill_chunk
-            taken_idx = []
-            whole = []
-            for j, req in enumerate(self._pending):
-                if len(free) - len(whole) == 0:  # every free slot claimed
-                    break
-                if C is not None and req[1].size > C:
-                    if self._inflight is not None:
-                        # one chunked admission at a time; SKIP (don't
-                        # stall the queue): short requests behind a
-                        # second long prompt still admit into free slots
-                        # while the first streams — relative order
-                        # within each class is preserved
-                        continue
-                    slot = free.pop()        # reserve from the tail
-                    self._reserved.add(slot)
-                    self._inflight = {
-                        "req": req, "slot": slot,
-                        "cache": self._fresh_rows_cache(1),
-                        "done_chunks": 0}
-                    taken_idx.append(j)
-                    # first slice; a chunked prompt always has >= 1 full
-                    # chunk before the final call, so it cannot finish
-                    # (or produce a token) on this slice
-                    self._advance_inflight()
-                else:
-                    taken_idx.append(j)
-                    whole.append(req)
-            if not taken_idx:
-                break
-            for j in reversed(taken_idx):
-                del self._pending[j]
-            groups: dict[int, list] = {}
-            for req in whole:
-                Tp = min(_next_pow2(req[1].size),
-                         self.cfg.max_position_embeddings)
-                groups.setdefault(Tp, []).append(req)
-            free_iter = iter(free)
-            admitted = []   # (slot_index, req_tuple, first_token)
-            for reqs in self._prefill_groups(groups):
-                rp = _next_pow2(len(reqs))
-                firsts, rows = self._prefill_final(
-                    self._fresh_rows_cache(rp),
-                    [r[1] for r in reqs], [r[1].size for r in reqs],
-                    [r[3] for r in reqs], [r[4] for r in reqs],
-                    [r[5] for r in reqs])
-                slots = [next(free_iter) for _ in reqs]
-                # pad rows target slot max_batch: out of bounds, dropped
-                self._scatter_rows(rows,
-                                   slots + [self.max_batch] * (rp - len(reqs)))
-                with self._spans(_obs.BATCHER_PREFILL_FETCH):
-                    firsts = self._fetch(firsts)
-                for j, (rid, _, budget, temp, top_p, seed) in enumerate(reqs):
-                    admitted.append((slots[j], (rid, budget, temp, top_p,
-                                                seed), int(firsts[j])))
-            for slot, (rid, budget, temp, top_p, seed), tok in admitted:
-                self._emit_token(rid, tok)
-                s = _Slot(request_id=rid, remaining=budget - 1, tokens=[tok],
-                          temperature=temp, top_p=top_p, seed=seed)
-                if s.remaining <= 0 or tok == self.eos_id:
-                    self._finish(slot, s)   # slot stays free; loop refills
-                    done.append(rid)
-                else:
-                    self.slots[slot] = s
-        return done
-
-    # -- paged admission (kv_page_tokens; docs/serving.md) -----------------
-    def _admit_paged(self) -> list[int]:
-        """Paged-mode admission (see :meth:`_admit` for the slot/burst
-        mechanics): each taken request first LEASES pages — a prefix-
-        index match plus freshly allocated tail pages — and a request
-        the pool cannot serve right now blocks the queue (strict-FIFO
-        page backpressure: pages free as running requests finish, so
-        the head admits eventually; ``submit`` already rejected
-        requests larger than the whole pool, so this cannot deadlock).
-        Burst grouping keys on the pow2 TAIL-length bucket — after its
-        prefix match a 10k-token prompt with a cached system prompt
-        shares the short-tail executable, which is the TTFT win."""
         done: list[int] = []
         self._admit_adopts()   # handed-off sessions seat before new
         # prompts: their prefill compute is already spent elsewhere
         if self._inflight is not None:
-            done.extend(self._advance_inflight_paged())
+            done.extend(self._advance_inflight())
         C = self.prefill_chunk
         while self._pending:
             free = [i for i, s in enumerate(self.slots)
@@ -1649,7 +1385,7 @@ class ContinuousBatcher:
                     taken_idx.append(j)
                     # first slice; >= 1 full chunk precedes the final
                     # call, so this cannot finish or emit a token
-                    self._advance_inflight_paged()
+                    self._advance_inflight()
                 else:
                     taken_idx.append(j)
                     whole.append((req, lease))
@@ -1667,7 +1403,7 @@ class ContinuousBatcher:
             admitted = []
             for reqs in self._prefill_groups(groups):
                 slots = [next(free_iter) for _ in reqs]
-                firsts = self._prefill_paged(
+                firsts = self._prefill(
                     [(req, lease, lease.tail_start)
                      for req, lease in reqs], slots)
                 for j, (req, lease) in enumerate(reqs):
@@ -1699,10 +1435,10 @@ class ContinuousBatcher:
                     self.cache)[0]
                 if getattr(path[-1], "key", None) == "conv_state"]
 
-    def _prefill_paged(self, entries, slots: list[int],
-                       conv_rows: list | None = None) -> np.ndarray:
-        """THE paged prefill: one fused dispatch per admission group
-        that (1) prefills every row's TAIL tokens (positions after its
+    def _prefill(self, entries, slots: list[int],
+                 conv_rows: list | None = None) -> np.ndarray:
+        """THE prefill: one fused dispatch per admission group that
+        (1) prefills every row's TAIL tokens (positions after its
         prefix-cache match) straight into the slot's leased pages via a
         per-row block-table view over the shared pool — shared prefix
         pages are only READ, the read-only/copy-on-write contract —
@@ -1710,7 +1446,19 @@ class ContinuousBatcher:
         position, and (3) scatters the rows' block tables and rewound-
         to-true-total counters into the batch cache: admission lands in
         ONE executable per (pow2 tail bucket, pow2 group size), no side
-        cache, no separate scatter dispatch.
+        cache, no separate scatter dispatch.  One executable serves
+        greedy and sampled requests (``_select_tokens`` reduces to argmax
+        at temperature 0).
+
+        Why padding is exact: prefill attention is causal, so pad tokens
+        never influence a true last position's logits (selected per row
+        at ``true_len - 1``), and a conv layer takes its state at the
+        row's true length (``ShortConv``'s ``lengths``), where no pad
+        token has entered it; each row's cache counters are then REWOUND
+        to its true total, after which the positional visibility mask
+        hides every pad position (``k_pos > q_pos``) until the decode
+        loop overwrites it with a real token's K/V in the same forward
+        that first makes it visible.
 
         ``entries`` = ``[(req_tuple, lease, start)]`` where ``start`` is
         the first prompt position fed here (the lease's tail start, or
@@ -1730,11 +1478,11 @@ class ContinuousBatcher:
                                 for req, _, start in entries)), cfgC)
         rp = _next_pow2(len(entries))
         carried = conv_rows is not None
-        key = ("pfinal", Tp, rp, carried) if carried else ("pfinal", Tp, rp)
+        key = ("final", Tp, rp, carried) if carried else ("final", Tp, rp)
         if key not in self._prefill_jit:
-            def pfinal_fn(params, cache, tokens, row_bt, row_start,
-                          true_len, true_tot, slot_ids, seeds, temps,
-                          top_ps, conv_rows):
+            def final_fn(params, cache, tokens, row_bt, row_start,
+                         true_len, true_tot, slot_ids, seeds, temps,
+                         top_ps, conv_rows):
                 row_cache = _row_view(cache, row_bt, row_start,
                                       conv_rows if carried else None)
                 last, new_cache, stats = self._last_logits(
@@ -1768,7 +1516,7 @@ class ContinuousBatcher:
                 return _pack(first, stats), \
                     jax.tree_util.tree_map_with_path(back, cache, new_cache)
 
-            self._prefill_jit[key] = self._jit(key, pfinal_fn,
+            self._prefill_jit[key] = self._jit(key, final_fn,
                                                donate_argnums=(1,))
         self.prefill_dispatches += 1
         if self.cfg.has_conv:
@@ -1806,16 +1554,16 @@ class ContinuousBatcher:
         with self._spans(_obs.BATCHER_PREFILL_FETCH):
             return self._fetch(firsts)
 
-    def _pchunk_jit(self):
-        """One fixed-chunk paged prefill executable: streams a chunk of
+    def _chunk_jit(self):
+        """One fixed-chunk prefill executable: streams a chunk of
         the in-flight admission's tail into its leased pages (batch
         block tables/counters untouched — the slot only goes live at
-        the final :meth:`_prefill_paged` call).  The admission's conv
+        the final :meth:`_prefill` call).  The admission's conv
         state goes in and comes out beside the cache (``_conv_rows``'
         layout): the reserved slot's own ``conv_state`` row is no place
         for it, because the decode steps in between run every row."""
         C = self.prefill_chunk
-        key = ("pchunk", C)
+        key = ("chunk", C)
         if key not in self._prefill_jit:
             def chunk_fn(params, cache, tokens_row, row_bt, start,
                          conv_rows):
@@ -1838,12 +1586,14 @@ class ContinuousBatcher:
                                                donate_argnums=(1,))
         return self._prefill_jit[key]
 
-    def _advance_inflight_paged(self) -> list[int]:
-        """Paged edition of :meth:`_advance_inflight`: chunk slices
-        stream the prompt tail straight into the slot's leased pages
-        (no side cache to scatter later), the bucketed final call goes
-        through :meth:`_prefill_paged`.  Same time-slicing contract —
-        one chunk per ``step()``, running slots never stall."""
+    def _advance_inflight(self) -> list[int]:
+        """Advance the in-flight chunked admission by ONE chunk (the
+        time slice) streamed straight into the slot's leased pages, or
+        finish it with the bucketed :meth:`_prefill` call on the
+        remainder.  Long-context admission therefore costs one extra
+        dispatch per decode step instead of stalling every running slot
+        for the whole chunk loop — O(chunk x max_len) transient
+        attention memory per slice."""
         inf = self._inflight
         C = self.prefill_chunk
         req = inf["req"]
@@ -1860,14 +1610,14 @@ class ContinuousBatcher:
             if "conv" not in inf:
                 inf["conv"] = self._conv_rows(1)
             with self._spans(_obs.BATCHER_PREFILL_DISPATCH):
-                self.cache, inf["conv"] = self._pchunk_jit()(
+                self.cache, inf["conv"] = self._chunk_jit()(
                     self.params, self.cache, prompt[None, start:start + C],
                     row_bt, np.asarray([start], np.int32), inf["conv"])
             inf["done_chunks"] += 1
             return []
         slot = inf["slot"]
         self._reserved.discard(slot)
-        firsts = self._prefill_paged(
+        firsts = self._prefill(
             [(req, lease, lease.tail_start + n_full * C)], [slot],
             conv_rows=inf["conv"] if self.cfg.has_conv else None)
         self._inflight = None
@@ -1883,7 +1633,7 @@ class ContinuousBatcher:
         return []
 
     def _park_slot(self, i: int) -> None:
-        """Paged mode: a finished slot's pages return to the pool, but
+        """A finished slot's pages return to the pool, but
         the batch executables keep stepping every row — park the row by
         setting its cache counters to max_len so its garbage writes hit
         the position guard and DROP instead of landing in pages now
@@ -1915,11 +1665,10 @@ class ContinuousBatcher:
         self._prompts.pop(s.request_id, None)
         self._on_token.pop(s.request_id, None)
         self.slots[i] = None
-        if self._pages is not None:
-            if s.lease is not None:
-                self._pages.release(s.lease)
-                s.lease = None
-            self._park_slot(i)
+        if s.lease is not None:
+            self._pages.release(s.lease)
+            s.lease = None
+        self._park_slot(i)
 
     # -- decode ------------------------------------------------------------
     def step(self) -> list[int]:
@@ -2015,8 +1764,6 @@ class ContinuousBatcher:
         ``tokens_per_row`` tokens in ``kv_pages_read`` /
         ``kv_pages_viewed``: host arithmetic over the seated slots'
         lengths, no device work."""
-        if self._pages is None:
-            return
         pt, C = self.cfg.kv_page_tokens, self.cfg.max_position_embeddings
         lens = [s.prompt_len + len(s.tokens) + tokens_per_row - 1
                 for s in self.slots if s is not None]
@@ -2213,8 +1960,8 @@ class ContinuousBatcher:
     def _block_step(self, K: int) -> list[int]:
         """ONE dispatch, K committed decode steps.  A row that emits
         ``eos_id`` mid-block keeps scanning (its later tokens are
-        discarded here and its stale K/V is overwritten wholesale by the
-        next admission's scatter) — wasted compute is bounded by K-1
+        discarded here and their K/V lies in its own pages, which return
+        to the pool when it finishes) — wasted compute is bounded by K-1
         row-steps, the price of the K× dispatch amortization."""
         done: list[int] = []
         self.decode_dispatches += 1

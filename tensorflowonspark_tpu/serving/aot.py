@@ -5,8 +5,8 @@ cache``) already dedupes *compiles* across processes, but every process
 still pays trace + lower + (cache-hit) load through the full ``jax.jit``
 machinery on its first call of every serve-step shape — and a cache MISS
 is a full compile inside the heal window.  This module takes the
-remaining step: each serve-step executable (``decode``, the ``pfinal``/
-``pchunk`` prefill buckets, ``verify``, scatter/park/adopt helpers) is
+remaining step: each serve-step executable (``decode``, the ``final``/
+``chunk`` prefill buckets, ``verify``, park/seat/export helpers) is
 ``lower().compile()``-d once, serialized via
 ``jax.experimental.serialize_executable``, and written to a content-
 addressed file under the cache directory.  Every later process —

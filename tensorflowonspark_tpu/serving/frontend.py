@@ -450,8 +450,7 @@ class ServingCluster:
         the prompt KV once, never decode-step) and D decode gangs (only
         ever step), with each session handed off as a verified KV-page
         transfer on the queue/shm plane.  ``num_replicas`` must equal
-        ``P + D``; ``batcher_kwargs`` must set ``kv_page_tokens`` (the
-        handoff is page-granular); optional ``"prefill_kwargs"`` /
+        ``P + D``; optional ``"prefill_kwargs"`` /
         ``"decode_kwargs"`` entries overlay per-pool batcher knobs
         (e.g. ``prefill_chunk`` for the prefill pool's streaming
         admission).  With ``autoscale={"prefill": {...}, "decode":
@@ -591,11 +590,6 @@ class ServingCluster:
                     f"disagg pools sum to "
                     f"{disagg['prefill'] + disagg['decode']} gangs but "
                     f"num_replicas={num_replicas} — pass their sum")
-            if (batcher_kwargs or {}).get("kv_page_tokens") is None:
-                raise ValueError(
-                    "disagg needs paged KV: set batcher_kwargs="
-                    "{'kv_page_tokens': ...} — the prefill→decode "
-                    "handoff is a KV-page transfer")
             if warm_standbys:
                 # a standby's engine is built from the BASE kwargs and
                 # must be able to set_role() into EITHER pool at

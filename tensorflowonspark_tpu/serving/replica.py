@@ -289,7 +289,7 @@ def serve_prefix_donation(batcher, item, ctx) -> None:
         logger.exception("replica %d: prefix-cache export for donation "
                          "failed", ctx.executor_id)
     if not pages:
-        logger.info("replica %d: nothing to donate (empty/dense prefix "
+        logger.info("replica %d: nothing to donate (empty prefix "
                     "cache)", ctx.executor_id)
         return
     m_donations = _donation_counter()
@@ -519,7 +519,7 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
     g_pages = reg.gauge(
         "tfos_replica_kv_pages_free_count",
         "Allocatable KV pages (free + evictable cached) in the paged "
-        "pool; 0 for a dense-cache batcher.")
+        "pool.")
     m_prefix = reg.counter(
         "tfos_replica_prefix_cache_requests_total",
         "Prefix-cache admission outcomes (hit/miss/partial).",
@@ -557,7 +557,7 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
         "kv_pages_read": reg.counter(
             "tfos_replica_kv_pages_read_total",
             "KV pages the seated rows' lengths cover, summed over decode "
-            "dispatches (paged mode)."),
+            "dispatches."),
         "kv_pages_viewed": reg.counter(
             "tfos_replica_kv_pages_viewed_total",
             "KV pages of the seated rows' whole views, summed over the "

@@ -308,8 +308,8 @@ class _Replica:
             self.model, self.version = str(model[0]), str(model[1])
         self.outstanding: dict[int, ServeRequest] = {}
         self.reported_load = 0   # last ContinuousBatcher.load()["total"]
-        #: last self-reported allocatable KV pages (paged-KV replicas;
-        #: 0 for dense ones) — the memory-pressure routing tie-break
+        #: last self-reported allocatable KV pages — the memory-pressure
+        #: routing tie-break
         self.reported_free_pages = 0
         #: last self-reported cumulative speculation counters
         #: ({"proposed": n, "accepted": n}) from a speculating replica's

@@ -276,6 +276,41 @@ def test_raise_worker_errors_aggregates_all_crashes(tmp_path):
         _raise_worker_errors(str(tmp_path), 3)
 
 
+def test_local_backend_reaps_a_worker_once_across_threads():
+    """The health monitor's thread reads exit codes while ``shutdown``
+    joins.  ``multiprocessing.Process`` is not thread-safe: of two
+    ``waitpid`` calls on one child the loser gets ECHILD and reports the
+    child alive, so a join on workers that had all exited came back False
+    ("workers still alive after 60s", under load once in a few runs).
+    The backend serialises the reap."""
+    import multiprocessing as mp
+    import threading
+
+    from tensorflowonspark_tpu.cluster import LocalProcessBackend
+
+    for _ in range(2):
+        backend = LocalProcessBackend()
+        ctx = mp.get_context("spawn")
+        backend.procs = [ctx.Process(target=funcs.fn_noop, args=({}, None))
+                         for _ in range(6)]
+        for p in backend.procs:
+            p.start()
+        stop = threading.Event()
+
+        def poll():
+            while not stop.is_set():
+                backend.exitcodes()
+
+        poller = threading.Thread(target=poll)
+        poller.start()
+        try:
+            assert backend.join(60), backend.exitcodes()
+        finally:
+            stop.set()
+            poller.join()
+        assert backend.failed() == [] and not any(backend.alive())
+
+
 class FlakyBackend:
     """LocalProcessBackend whose first start() raises — the relaunch-during-
     re-provisioning shape (an agent fleet not yet back after preemption)."""

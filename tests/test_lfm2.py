@@ -137,7 +137,7 @@ def _refused(made, what):
     cfg, params = made
     paged = dict(kv_page_tokens=4, prefix_cache=False)
     if what == "prefix-cache":
-        ContinuousBatcher(cfg, params, max_batch=2, kv_page_tokens=4)
+        ContinuousBatcher(cfg, params, max_batch=2, prefix_cache=True)
     elif what == "speculative_k":
         ContinuousBatcher(cfg, params, max_batch=2, speculative_k=2)
     elif what == "prefill_only":
@@ -190,9 +190,20 @@ def test_errors_state_the_cache_kinds(made):
                                    jnp.zeros((1, 2), jnp.int32))["params"]
     b = ContinuousBatcher(dense, dense_params, max_batch=1)
     with pytest.raises(ValueError, match="K/V of 2 full_attention"):
-        b.adopt_session({"v": 1})
-    with pytest.raises(ValueError, match="K/V of 2 full_attention"):
         b.set_draft(DraftModel(dense, dense_params, window=4))
+
+
+def test_conv_layers_turn_the_prefix_index_off_by_default(made):
+    """What the code can work out is no option: with conv layers the
+    shared-prefix index is off unless asked for, and asking refuses,
+    naming the state that shared pages cannot carry."""
+    cfg, params = made
+    b = ContinuousBatcher(cfg, params, max_batch=2)
+    assert b._pages.prefix_cache is False
+    assert b.export_prefix_cache() is None
+    with pytest.raises(ValueError) as e:
+        ContinuousBatcher(cfg, params, max_batch=2, prefix_cache=True)
+    assert cfg.cache_kinds in str(e.value)
 
 
 def test_config_validation():

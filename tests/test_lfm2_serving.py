@@ -76,10 +76,10 @@ def _check_slots(b, params, prompts):
 
 MODES = {"paged": dict(kv_page_tokens=4, kv_pool_pages=64,
                        prefix_cache=False),
-         "dense": {},
+         "default": {},
          "paged-chunked": dict(kv_page_tokens=4, kv_pool_pages=64,
                                prefix_cache=False, prefill_chunk=4),
-         "dense-chunked": dict(prefill_chunk=4),
+         "default-chunked": dict(prefill_chunk=4),
          "paged-rows-max": dict(kv_page_tokens=4, kv_pool_pages=64,
                                 prefix_cache=False, prefill_rows_max=1)}
 
@@ -130,7 +130,7 @@ def test_chunked_prefill_on_equals_off(made):
     np.testing.assert_allclose(p1, p2, atol=TOL)
 
 
-@pytest.mark.parametrize("mode", ["paged", "dense"])
+@pytest.mark.parametrize("mode", ["paged", "default"])
 def test_a_reused_slot_starts_from_zero_state(made, mode):
     cfg, params = made
     with jax.default_matmul_precision("highest"):
@@ -195,7 +195,7 @@ def _serve(cfg, params, vocab, kwargs, schedule, steps, **submit):
 AHEAD_SCHEDULE = {0: [(0, 11, 9), (1, 5, 14), (2, 7, 20)], 3: [(3, 6, 8)]}
 
 
-@pytest.mark.parametrize("model,mode", [("lfm2", "paged"), ("lfm2", "dense"),
+@pytest.mark.parametrize("model,mode", [("lfm2", "paged"), ("lfm2", "default"),
                                         ("dense-gpt", "paged")])
 def test_decode_ahead_serves_the_same_tokens_at_the_same_steps(
         made, model, mode):

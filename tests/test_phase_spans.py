@@ -354,16 +354,14 @@ def lowered_names():
     try:
         cfg, params = _make()
         long, short = _prompt(1, 11), _prompt(2, 5)
-        # dense: zeros, final, scatter, chunk, step, step_sample
-        b = ContinuousBatcher(cfg, params, max_batch=2, prefill_chunk=4)
-        b.submit(long, 3)
-        b.submit(short, 3, temperature=0.8, seed=3)
-        b.run()
-        # scanned blocks
+        # scanned blocks and the plain step that ends them (9 = 4 + 4 + 1),
+        # greedy then sampled: block, step, step_sample
         b = ContinuousBatcher(cfg, params, max_batch=2, decode_block_steps=4)
-        b.submit(short, 9)
+        b.submit(short, 10)
         b.run()
-        # paged: pfinal, pchunk, park; speculative verify and draft
+        b.submit(short, 10, temperature=0.8, seed=3)
+        b.run()
+        # final, chunk, park; speculative verify and draft
         b = ContinuousBatcher(cfg, params, max_batch=2, kv_page_tokens=8,
                               prefill_chunk=4, speculative_k=2)
         draft = DraftModel(cfg, params, window=8)

@@ -17,8 +17,9 @@ from tensorflowonspark_tpu.models import (GPT, GPTConfig, ContinuousBatcher,
 
 
 def _make(pos_encoding="rope", **kw):
+    kw.setdefault("max_position_embeddings", 48)
     cfg = GPTConfig(vocab_size=61, hidden_size=32, num_layers=2, num_heads=4,
-                    intermediate_size=64, max_position_embeddings=48,
+                    intermediate_size=64,
                     dtype=jnp.float32, pos_encoding=pos_encoding, **kw)
     params = GPT(cfg).init(jax.random.key(0),
                            jnp.ones((1, 4), jnp.int32))["params"]
@@ -404,29 +405,32 @@ def test_failed_step_poisons_the_batcher():
 
 def test_burst_admission_shares_one_prefill_dispatch():
     """A burst of same-bucket arrivals is admitted with ONE batched
-    prefill call and one scatter — and every request stays greedy-exact
-    vs its solo oracle (batching must not change numerics)."""
+    prefill call, which seats the rows too — and every request stays
+    greedy-exact vs its solo oracle (batching must not change
+    numerics)."""
     cfg, params = _make()
     rng = np.random.default_rng(11)
     b = ContinuousBatcher(cfg, params, max_batch=8)
     calls = []
-    orig = b._prefill_final
-    b._prefill_final = lambda *a: calls.append(1) or orig(*a)
+    orig = b._prefill
+    b._prefill = lambda *a: calls.append(1) or orig(*a)
     reqs = [(rng.integers(0, cfg.vocab_size, (5,)).astype(np.int32), n)
             for n in (4, 6, 3, 5, 7, 4, 6, 5)]
     rids = [b.submit(p, n) for p, n in reqs]
     results = b.run()
     assert len(calls) == 1, f"expected one batched prefill, got {len(calls)}"
-    assert set(b._prefill_jit) >= {("final", 8, 8), ("scatter", 8)}
+    assert ("final", 8, 8) in b._prefill_jit
+    assert b.prefill_dispatches == 1
     for rid, (p, n) in zip(rids, reqs):
         np.testing.assert_array_equal(results[rid],
                                       _oracle(cfg, params, p, n))
 
 
 def test_group_padding_rows_never_land():
-    """A group of 3 pads to 4 prefill rows; the pad row's garbage cache
-    is dropped at scatter (out-of-bounds slot) and running slots are
-    untouched: all requests remain greedy-exact."""
+    """A group of 3 pads to 4 prefill rows; the pad row's writes drop
+    (an all-sentinel block table) and so does its seat (out-of-bounds
+    slot), and running slots are untouched: all requests remain
+    greedy-exact."""
     cfg, params = _make()
     rng = np.random.default_rng(12)
     b = ContinuousBatcher(cfg, params, max_batch=4)
@@ -865,24 +869,27 @@ def test_on_token_fires_before_finish_and_with_eos():
 def test_load_counts_every_live_request_once():
     cfg, params = _make()
     b = ContinuousBatcher(cfg, params, max_batch=2, prefill_chunk=4)
-    # dense mode: no page pool, so the memory-pressure gauges read 0
+    # the default pool: max_batch requests of max_position_embeddings
+    # tokens in 16-token pages, all of it allocatable
     assert b.load() == {"active": 0, "pending": 0, "reserved": 0,
-                        "total": 0, "free_pages": 0, "total_pages": 0}
+                        "total": 0, "free_pages": 6, "total_pages": 6}
     rng = np.random.default_rng(31)
     b.submit(rng.integers(0, cfg.vocab_size, (3,)).astype(np.int32), 6)
     b.submit(rng.integers(0, cfg.vocab_size, (18,)).astype(np.int32), 5)
     b.submit(rng.integers(0, cfg.vocab_size, (4,)).astype(np.int32), 6)
     assert b.load() == {"active": 0, "pending": 3, "reserved": 0,
-                        "total": 3, "free_pages": 0, "total_pages": 0}
+                        "total": 3, "free_pages": 6, "total_pages": 6}
     b.step()
     # short prompt active; the long one is the in-flight chunked
     # admission (pending, with its slot reserved); the third queued
     load = b.load()
     assert load["total"] == 3, load
     assert load["active"] >= 1 and load["reserved"] == 1, load
+    assert load["free_pages"] < 6, load     # live requests hold pages
     b.run()
+    # released pages are allocatable again, cached or not
     assert b.load() == {"active": 0, "pending": 0, "reserved": 0,
-                        "total": 0, "free_pages": 0, "total_pages": 0}
+                        "total": 0, "free_pages": 6, "total_pages": 6}
 
 
 # -- paged KV + shared prefix cache (kv_page_tokens) ----------------------
@@ -998,7 +1005,7 @@ def test_paged_mixed_greedy_sampled_hit_and_miss_paths():
     """Hit-vs-miss exactness under mixed traffic: greedy requests stay
     oracle-exact and a sampled request is the same pure function of
     (seed, temp, top_p) whether its prefix hits the cache, misses it,
-    or the batcher is dense."""
+    or the pool keeps no index (and pages of another size)."""
     cfg, params = _make()
     rng = np.random.default_rng(44)
     pre = rng.integers(0, cfg.vocab_size, (16,)).astype(np.int32)
@@ -1007,9 +1014,8 @@ def test_paged_mixed_greedy_sampled_hit_and_miss_paths():
     greedy_p = np.concatenate([pre, rng.integers(0, cfg.vocab_size,
                                                  (3,)).astype(np.int32)])
 
-    def run(paged, warm):
-        b = ContinuousBatcher(cfg, params, max_batch=2,
-                              **({"kv_page_tokens": 8} if paged else {}))
+    def run(kw, warm):
+        b = ContinuousBatcher(cfg, params, max_batch=2, **kw)
         if warm:    # populate the prefix index so the next admits HIT
             b.submit(np.concatenate(
                 [pre, np.asarray([1], np.int32)]), 2)
@@ -1022,15 +1028,14 @@ def test_paged_mixed_greedy_sampled_hit_and_miss_paths():
             assert st["hit"] >= 2, st
         return res[rs], res[rg]
 
-    s_hit, g_hit = run(True, True)
-    s_miss, g_miss = run(True, False)
-    s_dense, g_dense = run(False, False)
-    np.testing.assert_array_equal(s_hit, s_dense)
-    np.testing.assert_array_equal(s_miss, s_dense)
-    np.testing.assert_array_equal(g_hit, g_dense)
-    np.testing.assert_array_equal(g_miss, g_dense)
-    np.testing.assert_array_equal(g_dense,
-                                  _oracle(cfg, params, greedy_p, 8))
+    s_hit, g_hit = run({"kv_page_tokens": 8}, True)
+    s_miss, g_miss = run({"kv_page_tokens": 8}, False)
+    s_plain, g_plain = run({"prefix_cache": False}, False)
+    np.testing.assert_array_equal(s_hit, s_plain)
+    np.testing.assert_array_equal(s_miss, s_plain)
+    want = _oracle(cfg, params, greedy_p, 8)
+    for got in (g_hit, g_miss, g_plain):
+        np.testing.assert_array_equal(got, want)
 
 
 @pytest.mark.parametrize("kw", [{"prefill_chunk": 6},
@@ -1098,14 +1103,52 @@ def test_paged_validation():
         ContinuousBatcher(cfg, params, max_batch=2, kv_page_tokens=6)
     with pytest.raises(ValueError, match="divide"):
         ContinuousBatcher(cfg, params, max_batch=2, kv_page_tokens=32)
-    with pytest.raises(ValueError, match="kv_page_tokens"):
-        ContinuousBatcher(cfg, params, max_batch=2, kv_pool_pages=8)
     with pytest.raises(ValueError, match="kv_pool_pages"):
         ContinuousBatcher(cfg, params, max_batch=2, kv_page_tokens=8,
                           kv_pool_pages=0)
     cfg8, params8 = _make(kv_cache_int8=True)
     with pytest.raises(ValueError, match="kv_cache_int8"):
         ContinuousBatcher(cfg8, params8, max_batch=2, kv_page_tokens=8)
+
+
+@pytest.mark.parametrize("max_pos,page", [(48, 16), (24, 8), (20, 4)])
+def test_default_pool_holds_a_whole_window_per_slot(max_pos, page):
+    """Built with no paging argument the batcher serves from 16-token
+    pages, halved until they divide the window, and from a pool that
+    holds ``max_batch`` whole windows: ``max_batch`` requests of full
+    length seat at once (no page backpressure where slots are free), and
+    each is greedy-exact."""
+    cfg, params = _make(max_position_embeddings=max_pos)
+    b = ContinuousBatcher(cfg, params, max_batch=3)
+    assert b.cfg.kv_page_tokens == page
+    assert b.cfg.kv_pool_pages == 3 * max_pos // page
+    rng = np.random.default_rng(max_pos)
+    reqs = [(rng.integers(0, cfg.vocab_size, (max_pos - n,)).astype(np.int32),
+             n) for n in (5, 6, 7)]
+    rids = [b.submit(p, n) for p, n in reqs]
+    b.step()
+    assert all(b.slots), "a full-length request waited for pages"
+    assert b.load()["free_pages"] == 0
+    results = b.run()
+    for rid, (p, n) in zip(rids, reqs):
+        np.testing.assert_array_equal(results[rid],
+                                      _oracle(cfg, params, p, n))
+
+
+def test_default_pool_reports_itself():
+    """``load()`` and ``prefix_stats()`` of a batcher built with no
+    paging argument read a real pool with the shared-prefix index on."""
+    cfg, params = _make()
+    b = ContinuousBatcher(cfg, params, max_batch=2)
+    assert b.load()["free_pages"] == b.load()["total_pages"] == 6
+    assert b.prefix_stats()["total_pages"] == 6
+    prompt = np.arange(1, 37, dtype=np.int32)          # two whole pages
+    for _ in range(2):
+        b.submit(prompt, 3)
+        b.run()
+    st = b.prefix_stats()
+    assert st["miss"] == 1 and st["hit"] == 1 and st["cached_pages"] == 2, st
+    assert b.load()["free_pages"] == 6       # cached pages are evictable
 
 
 def test_block_decode_validation():
@@ -1245,8 +1288,6 @@ def test_adopt_rejects_corrupt_and_mismatched_sessions_loudly():
 
 def test_prefill_only_validation_and_direct_finish():
     cfg, params = _make()
-    with pytest.raises(ValueError, match="kv_page_tokens"):
-        ContinuousBatcher(cfg, params, max_batch=2, prefill_only=True)
     with pytest.raises(ValueError, match="decode-time"):
         ContinuousBatcher(cfg, params, max_batch=2, kv_page_tokens=8,
                           prefill_only=True, speculative_k=2)
@@ -1298,9 +1339,6 @@ def test_set_role_validation():
     with pytest.raises(ValueError, match="unknown role"):
         b.set_role("both")
     # prefill posture keeps the constructor's constraints
-    unpaged = ContinuousBatcher(cfg, params, max_batch=2)
-    with pytest.raises(ValueError, match="paged KV"):
-        unpaged.set_role("prefill")
     spec = ContinuousBatcher(cfg, params, max_batch=2, kv_page_tokens=8,
                              speculative_k=2)
     with pytest.raises(ValueError, match="decode-time"):
@@ -1362,10 +1400,11 @@ def test_export_import_prefix_cache_roundtrip_exact():
     fresh = ContinuousBatcher(cfg, params, max_batch=2, kv_page_tokens=8)
     with pytest.raises(ValueError, match="content hash mismatch"):
         fresh.import_prefix_cache(bad)
-    # dense batchers have nothing to export/import
-    dense = ContinuousBatcher(cfg, params, max_batch=2)
-    assert dense.export_prefix_cache() is None
-    assert dense.import_prefix_cache(export) == 0
+    # a pool without the index has nothing to export
+    plain = ContinuousBatcher(cfg, params, max_batch=2, prefix_cache=False)
+    rid = plain.submit(np.arange(1, 25, dtype=np.int32), 2)
+    plain.run()
+    assert plain.export_prefix_cache() is None
 
 
 # -- the pool's token row (models.gpt.kv_row_width) -----------------------

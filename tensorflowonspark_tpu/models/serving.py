@@ -313,7 +313,11 @@ class ContinuousBatcher:
     counts queued-but-unadmitted requests against the free slots),
     ``step()`` once per decode step (returns every request id finished
     since the last call, including ones that completed at admission),
-    submit more as slots free up.
+    submit more as slots free up.  Between two ``step()`` calls the
+    device may already hold the next step (:meth:`settle`).
+
+    ``decode_ahead`` is accepted and unread: the batcher runs ahead by
+    rule, wherever the next step's rows are decided (:meth:`_runs_ahead`).
     """
 
     def __init__(self, cfg: GPTConfig, params, max_batch: int,
@@ -328,7 +332,7 @@ class ContinuousBatcher:
                  prefix_cache: bool | None = None,
                  prefill_only: bool = False,
                  prefill_rows_max: int | None = None,
-                 decode_ahead: bool = False,
+                 decode_ahead: bool | None = None,
                  aot_cache=None):
         if cfg.has_conv:
             # what a second kind of per-sequence state cannot follow yet
@@ -384,13 +388,6 @@ class ContinuousBatcher:
                 "dispatches keep speculative_k and arm a draft model "
                 "(set_draft / ServingCluster.run(draft_model=)) instead "
                 "of blocking")
-        if decode_ahead and (speculative_k is not None
-                             or decode_block_steps is not None):
-            raise ValueError(
-                "decode_ahead queues the NEXT plain decode step behind the "
-                "running one; speculative_k and decode_block_steps decide "
-                "each dispatch from the last one's tokens — they are "
-                "alternatives")
         if prefill_only and (speculative_k is not None
                              or decode_block_steps is not None):
             raise ValueError("prefill_only is a prefill-pool posture; "
@@ -454,19 +451,21 @@ class ContinuousBatcher:
         #: float32 first) grow with its rows and the chip's memory that
         #: the weights leave free does not
         self.prefill_rows_max = prefill_rows_max
-        #: DISPATCH-AHEAD: while every slot is seated, greedy, and more than
-        #: one token short of its budget (and no ``eos_id`` can end a row
-        #: early), the next plain decode step's rows are already known, so
-        #: it is dispatched BEFORE the running step's tokens are fetched,
-        #: fed the running step's tokens as they lie on the device.  The
-        #: device then runs step after step with no host turn between
-        #: them, and a late wake-up of the host shorter than a step costs
-        #: nothing.  Token-exact (the same executable, the same inputs);
-        #: admission is never delayed, because with every slot busy and
-        #: no row finishing nothing could have been admitted anyway.
-        self.decode_ahead = bool(decode_ahead)
-        #: the step dispatched ahead and not yet consumed (its packed
-        #: tokens on the device), and how many there were in all
+        #: RUN-AHEAD, by rule (:meth:`_runs_ahead`): while every slot is
+        #: seated, greedy, and more than one token short of its budget (and
+        #: no ``eos_id`` can end a row early), the next plain decode step's
+        #: rows are already known, so it is dispatched BEFORE the running
+        #: step's tokens are fetched, fed the running step's tokens as they
+        #: lie on the device.  The device then runs step after step with no
+        #: host turn between them, and a late wake-up of the host shorter
+        #: than a step costs nothing.  Token-exact (the same executable, the
+        #: same inputs); admission is never delayed, because with every slot
+        #: busy and no row finishing nothing could have been admitted anyway.
+        #: ``_ahead`` is the step dispatched ahead and not yet consumed (its
+        #: packed tokens on the device): while it is set ``self.cache`` is
+        #: one step ahead of the slots (:meth:`settle` makes them agree);
+        #: ``decode_ahead_dispatches`` counts them, at most one per decode
+        #: dispatch — ``tfos_replica_decode_ahead_dispatches_total``
         self._ahead = None
         self.decode_ahead_dispatches = 0
         #: THE K/V STORE: a pool of ``kv_pool_pages`` pages of
@@ -1681,9 +1680,14 @@ class ContinuousBatcher:
         already donated the cache buffer, so the instance cannot be
         resumed — and every later call raises ``RuntimeError`` naming
         the original failure."""
+        return self._guarded(self._step_inner)
+
+    def _guarded(self, dispatching, **kwargs) -> list[int]:
+        """Run ``dispatching`` (a method that dispatches device work),
+        marking the batcher unusable if it raises."""
         self._check_usable()
         try:
-            return self._step_inner()
+            return dispatching(**kwargs)
         except Exception as e:
             self._poisoned = f"{type(e).__name__}: {e}"
             raise
@@ -2003,15 +2007,17 @@ class ContinuousBatcher:
 
     def _runs_ahead(self) -> bool:
         """Whether the step AFTER the one about to be fetched is already
-        decided (``decode_ahead``): every slot seated and greedy, none
-        finishing at this step, no ``eos_id``, no chunked admission in
-        flight — so nothing can leave or join before it."""
-        return (self.decode_ahead and self.eos_id is None
-                and self._inflight is None
+        decided: a batcher that decides each dispatch from the last one's
+        tokens (``speculative_k``, ``decode_block_steps``) never says so;
+        otherwise every slot seated and greedy, none finishing at this
+        step, no ``eos_id``, no chunked admission in flight — so nothing
+        can leave or join before it."""
+        return (self.spec_k is None and self.decode_block_steps is None
+                and self.eos_id is None and self._inflight is None
                 and all(s is not None and s.temperature <= 0
                         and s.remaining > 1 for s in self.slots))
 
-    def _plain_step(self) -> list[int]:
+    def _plain_step(self, run_ahead: bool = True) -> list[int]:
         done: list[int] = []
         self.decode_dispatches += 1
         self.decode_steps += 1
@@ -2044,7 +2050,7 @@ class ContinuousBatcher:
                                            for s in self.slots]
                 nxt, self.cache = self._step(self.params, self.cache,
                                              jnp.asarray(tokens))
-            if self._runs_ahead():
+            if run_ahead and self._runs_ahead():
                 self._ahead, self.cache = self._step(self.params,
                                                      self.cache, nxt)
                 self.decode_ahead_dispatches += 1
@@ -2062,6 +2068,30 @@ class ContinuousBatcher:
                     done.append(s.request_id)
                     self._finish(i, s)
         return done
+
+    def settle(self) -> list[int]:
+        """Consume the step queued ahead, if there is one: fetch it, emit
+        its tokens, finish what finishes — and dispatch no other — so that
+        the slots and ``self.cache`` agree again.  Returns the ids it
+        finished, as ``step()`` would have at its next call; a no-op
+        (``[]``) when nothing is queued.
+
+        For a caller that reads ``self.cache`` against the slots between
+        two ``step()`` calls (a logit probe); nothing inside the package
+        has to.  While a step is queued every slot is seated and no
+        chunked admission is in flight, so ``_admit`` seats and adopts
+        nothing and no slot is finished or parked before ``_plain_step``
+        consumes it; ``unload_params`` (hence ``load_params``) and
+        ``set_role`` want an idle batcher, and the serve loop swaps models
+        and exits only when idle; ``run()`` ends with no slot seated, hence
+        none queued; the page traffic between turns
+        (``export_prefix_cache``, ``import_prefix_cache``) touches only
+        indexed prompt pages and free pages, where no decode step writes.
+        A queued step keeps the parameters it was dispatched with, as any
+        step in flight does."""
+        if self._ahead is None:
+            return []
+        return self._guarded(self._plain_step, run_ahead=False)
 
     def result(self, request_id: int, *, pop: bool = False) \
             -> np.ndarray | None:

@@ -536,8 +536,14 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
         labelnames=("outcome",))
     # expert layers and conv state (models/moe.py, models/gpt.py
     # ShortConv): what the batcher read with its tokens, as deltas; the
-    # paged decode step's page accounting likewise
+    # paged decode step's page accounting and its steps run ahead likewise
     m_engine = {
+        "decode_ahead_dispatches": reg.counter(
+            "tfos_replica_decode_ahead_dispatches_total",
+            "Plain decode steps dispatched before the running step's "
+            "tokens were fetched (ContinuousBatcher._runs_ahead): over "
+            "tfos_replica_decode_dispatches_total, the share of decode "
+            "steps whose host turn hid behind the device."),
         "expert_assignments": reg.counter(
             "tfos_replica_expert_assignments_total",
             "Expert assignments made (rows x experts per token), summed "

@@ -49,6 +49,7 @@ def _prompt(i, n):
 def _probe(b, params):
     """Logits of the NEXT position of every active slot: the batcher's
     cache, fed what the next step would feed it, cache not kept."""
+    b.settle()      # a step queued ahead has its tokens emitted first
     toks = jnp.asarray([s.tokens[-1] if s else 0 for s in b.slots],
                        jnp.int32)
     with jax.default_matmul_precision("highest"):
@@ -139,7 +140,8 @@ def test_a_reused_slot_starts_from_zero_state(made, mode):
         while b.result(first) is None:
             b.step()
         assert not any(b.slots)
-        prompts = {b.submit(_prompt(1, 6), 5): _prompt(1, 6)}
+        # a budget that outlasts both probes: each settles a queued step
+        prompts = {b.submit(_prompt(1, 6), 8): _prompt(1, 6)}
         b.step()
         assert _check_slots(b, params, prompts) == 1
         b.step()
@@ -159,32 +161,49 @@ def test_greedy_generate_matches_the_batcher(made):
     assert solo[10:].tolist() == got.tolist()
 
 
-# -- decode_ahead: the next plain step queued behind the running one --------
+# -- run-ahead: the next plain step queued behind the running one ----------
 
-def _dense():
+def _dense(**kw):
     from tensorflowonspark_tpu.models import GPT, GPTConfig
 
     cfg = GPTConfig(num_layers=2, hidden_size=32, num_heads=2, vocab_size=50,
-                    max_position_embeddings=64)
+                    max_position_embeddings=64, **kw)
     return cfg, GPT(cfg).init(jax.random.key(0),
                               jnp.zeros((1, 2), jnp.int32))["params"]
 
 
-def _serve(cfg, params, vocab, kwargs, schedule, steps, **submit):
+def _toy(made, model):
+    return made if model == "lfm2" else _dense()
+
+
+#: an ``eos_id`` no row ever emits: the batcher it is given to serves the
+#: same tokens and stands down at every step (a row MAY end at any step),
+#: which makes it the step-by-step twin of a default-built batcher
+NEVER = dict(eos_id=-1)
+
+
+def _prompt_of(i, n, vocab):
+    return np.random.default_rng([9, i]).integers(0, vocab, n).astype(
+        np.int32)
+
+
+def _serve(cfg, params, vocab, kwargs, schedule, steps, between=None,
+           **submit):
     """Drive a batcher of 3 slots through ``schedule`` (step -> [(prompt
-    id, prompt length, budget)]); returns it, the finished streams and the
-    tokens each ``step()`` call emitted, in order."""
+    id, prompt length, budget)]), calling ``between(b, step)`` after each
+    ``step()``; returns it, the finished streams and the tokens each turn
+    emitted, in order."""
     b = ContinuousBatcher(cfg, params, max_batch=3, **kwargs)
     events, rids = [], {}
     for step in range(steps):
         for i, n, budget in schedule.get(step, []):
-            prompt = np.random.default_rng([9, i]).integers(
-                0, vocab, n).astype(np.int32)
             rids[i] = b.submit(
-                prompt, budget, **submit,
+                _prompt_of(i, n, vocab), budget, **submit,
                 on_token=lambda rid, tok, step=step: events.append(
                     (step, rid, tok)))
         b.step()
+        if between is not None:
+            between(b, step)
     return b, {i: b.result(r) for i, r in rids.items()}, events
 
 
@@ -199,13 +218,14 @@ AHEAD_SCHEDULE = {0: [(0, 11, 9), (1, 5, 14), (2, 7, 20)], 3: [(3, 6, 8)]}
                                         ("dense-gpt", "paged")])
 def test_decode_ahead_serves_the_same_tokens_at_the_same_steps(
         made, model, mode):
-    cfg, params = made if model == "lfm2" else _dense()
+    """A default-built batcher runs ahead; its twin never does."""
+    cfg, params = _toy(made, model)
     vocab = cfg.vocab_size
     with jax.default_matmul_precision("highest"):
-        off, want, ev_off = _serve(cfg, params, vocab, MODES[mode],
+        off, want, ev_off = _serve(cfg, params, vocab,
+                                   dict(MODES[mode], **NEVER),
                                    AHEAD_SCHEDULE, 30)
-        on, got, ev_on = _serve(cfg, params, vocab,
-                                dict(MODES[mode], decode_ahead=True),
+        on, got, ev_on = _serve(cfg, params, vocab, MODES[mode],
                                 AHEAD_SCHEDULE, 30)
     assert all(v is not None for v in want.values())
     assert {i: v.tolist() for i, v in got.items()} \
@@ -221,17 +241,34 @@ def test_decode_ahead_serves_the_same_tokens_at_the_same_steps(
         == (off.expert_assignments, off.experts_touched)
 
 
-@pytest.mark.parametrize("why,kwargs,submit", [
-    ("an eos_id can end a row at any step", dict(eos_id=1), {}),
+@pytest.mark.parametrize("why,kwargs,schedule,submit", [
+    ("an eos_id can end a row at any step", dict(eos_id=1),
+     AHEAD_SCHEDULE, {}),
     ("a sampled row's step needs its host-side sampler state", {},
-     dict(temperature=0.7, seed=3)),
+     AHEAD_SCHEDULE, dict(temperature=0.7, seed=3)),
+    ("a chunked admission in flight takes its slot at an unknown step",
+     dict(prefill_chunk=4), {0: [(0, 11, 20), (1, 5, 20)],
+                             2: [(2, 30, 4)]}, {}),
+    ("a row at its last token leaves at this step", {},
+     {0: [(0, 11, 2), (1, 5, 2), (2, 7, 2)]}, {}),
 ])
-def test_decode_ahead_stands_down(made, why, kwargs, submit):
+def test_decode_ahead_stands_down(made, why, kwargs, schedule, submit):
     cfg, params = made
-    b, got, _ = _serve(cfg, params, 211,
-                       dict(MODES["paged"], decode_ahead=True, **kwargs),
-                       AHEAD_SCHEDULE, 30, **submit)
-    assert b.decode_ahead_dispatches == 0, why
+    seen = []
+
+    def watch(b, step):
+        # the chunked case: no step is queued while the admission streams
+        seen.append((b._inflight is not None, b._ahead is not None))
+
+    b, got, _ = _serve(cfg, params, 211, dict(MODES["paged"], **kwargs),
+                       schedule, 30, between=watch, **submit)
+    assert not any(inflight and ahead for inflight, ahead in seen), why
+    if "prefill_chunk" in kwargs:
+        assert any(inflight for inflight, _ in seen)
+        ran = [ahead for inflight, ahead in seen if not inflight]
+        assert b.decode_ahead_dispatches == sum(ran) > 0
+    else:
+        assert b.decode_ahead_dispatches == 0, why
     assert all(v is not None for v in got.values())
 
 
@@ -239,8 +276,7 @@ def test_decode_ahead_waits_for_every_slot_to_be_seated(made):
     """With a slot free a request may be admitted at the next step, so
     that step is not dispatched before it is known."""
     cfg, params = made
-    b, got, _ = _serve(cfg, params, 211,
-                       dict(MODES["paged"], decode_ahead=True),
+    b, got, _ = _serve(cfg, params, 211, MODES["paged"],
                        {0: [(0, 11, 9), (1, 5, 14)]}, 16)
     assert b.decode_ahead_dispatches == 0
     assert all(v is not None for v in got.values())
@@ -248,7 +284,117 @@ def test_decode_ahead_waits_for_every_slot_to_be_seated(made):
 
 @pytest.mark.parametrize("other", [dict(speculative_k=2),
                                    dict(decode_block_steps=4)])
-def test_decode_ahead_refuses_its_alternatives(other):
-    cfg, _ = _dense()
-    with pytest.raises(ValueError, match="decode_ahead"):
-        ContinuousBatcher(cfg, None, max_batch=2, decode_ahead=True, **other)
+def test_decode_ahead_stands_down_for_its_alternatives(other):
+    """Speculation and blocks decide each dispatch from the last one's
+    tokens: such a batcher builds (keyword and all), serves the plain
+    generator's tokens, and queues nothing ahead, not even on the plain
+    steps it falls back to."""
+    cfg, params = _dense(dtype=jnp.float32)     # no bfloat16 near-ties
+    with jax.default_matmul_precision("highest"):
+        b, got, _ = _serve(cfg, params, 50, dict(decode_ahead=True, **other),
+                           AHEAD_SCHEDULE, 30)
+        for i, n, budget in sum(AHEAD_SCHEDULE.values(), []):
+            prompt = _prompt_of(i, n, 50)
+            solo = np.asarray(greedy_generate(cfg, params, prompt[None],
+                                              budget))[0]
+            assert got[i].tolist() == solo[n:].tolist(), (other, i)
+    assert b.decode_ahead_dispatches == 0 and b._ahead is None
+
+
+# -- settle(), and who may meet a queued step -------------------------------
+
+def _queued(cfg, params, kwargs):
+    """A batcher stopped between two turns with a step queued ahead."""
+    b = ContinuousBatcher(cfg, params, max_batch=3, **kwargs)
+    prompts = {}
+    for i, n, budget in AHEAD_SCHEDULE[0]:
+        prompts[b.submit(_prompt_of(i, n, cfg.vocab_size), budget)] \
+            = _prompt_of(i, n, cfg.vocab_size)
+    for _ in range(3):
+        b.step()
+    assert b._ahead is not None
+    return b, prompts
+
+
+def test_settle_makes_cache_and_slots_agree(made):
+    """After it the probe's logits match the reference at every seated
+    slot; it emits the queued step's tokens, dispatches nothing, and a
+    second call does nothing."""
+    cfg, params = made
+    with jax.default_matmul_precision("highest"):
+        b, prompts = _queued(cfg, params, MODES["paged"])
+        before = [len(s.tokens) for s in b.slots]
+        counters = (b.decode_dispatches, b.decode_ahead_dispatches)
+        assert b.settle() == []
+        assert b._ahead is None
+        assert [len(s.tokens) for s in b.slots] == [n + 1 for n in before]
+        assert (b.decode_dispatches, b.decode_ahead_dispatches) \
+            == (counters[0] + 1, counters[1])
+        assert _check_slots(b, params, prompts) == 3
+        assert b.settle() == []
+        assert [len(s.tokens) for s in b.slots] == [n + 1 for n in before]
+        assert b.decode_dispatches == counters[0] + 1
+        # and the stream goes on where a twin that never settled is
+        twin, _ = _queued(cfg, params, MODES["paged"])
+        assert {r: v.tolist() for r, v in b.run().items()} \
+            == {r: v.tolist() for r, v in twin.run().items()}
+
+
+def test_settle_returns_what_the_queued_step_finished():
+    cfg, params = _dense()
+    b = ContinuousBatcher(cfg, params, max_batch=2)
+    short = b.submit(_prompt_of(0, 6, 50), 2)
+    b.submit(_prompt_of(1, 9, 50), 9)
+    # both seated with their first tokens; short's second is its last, so
+    # the step that makes it had none queued behind it
+    assert b.step() == [short]
+    assert b._ahead is None and b.settle() == []
+    b = ContinuousBatcher(cfg, params, max_batch=2)
+    short = b.submit(_prompt_of(0, 6, 50), 3)
+    b.submit(_prompt_of(1, 9, 50), 9)
+    b.step()            # the queued step is short's last
+    assert b._ahead is not None
+    assert b.settle() == [short]
+    assert b.result(short) is not None and len(b.result(short)) == 3
+    assert b.slots[0] is None or b.slots[1] is None
+
+
+@pytest.mark.parametrize("reader", ["load_params", "park", "run",
+                                    "export_prefix_cache"])
+def test_a_queued_step_survives_its_readers(reader):
+    """Each path that reads or replaces the cache, the parameters or a
+    slot between two turns, where it can meet a queued step."""
+    cfg, params = _dense()
+    # load_params rebuilds the page index (it is for an idle batcher, which
+    # has no step queued; unload_params refuses a busy one): called on a
+    # busy one all the same, only a pool without an index survives it
+    kwargs = dict(kv_page_tokens=4, prefix_cache=reader != "load_params")
+    with jax.default_matmul_precision("highest"):
+        b, _ = _queued(cfg, params, kwargs)
+        twin, _ = _queued(cfg, params, kwargs)
+        queued = b._ahead
+        if reader == "load_params":
+            # the step in flight keeps the parameters it was dispatched
+            # with and is not lost: consumed by the next turn as it lies
+            b.load_params(jax.tree.map(np.asarray, params))
+            assert b._ahead is queued
+        elif reader == "park":
+            # a slot is only ever parked by the turn that consumed the
+            # queued step: none is queued behind a row that ends
+            parked = []
+            park = b._park_slot
+            b._park_slot = lambda i: (parked.append(b._ahead), park(i))[1]
+            b.run()
+            assert len(parked) == 3 and all(a is None for a in parked)
+        elif reader == "export_prefix_cache":
+            # indexed prompt pages are written by no decode step: the
+            # snapshot is the same with the step queued or settled
+            got = b.export_prefix_cache()
+            twin.settle()
+            want = twin.export_prefix_cache()
+            assert got["page_hashes"] == want["page_hashes"] != []
+            assert b._ahead is queued
+        out = b.run()
+        assert b._ahead is None and not any(b.slots)
+        assert {r: v.tolist() for r, v in out.items()} \
+            == {r: v.tolist() for r, v in twin.run().items()}

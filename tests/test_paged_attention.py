@@ -219,9 +219,12 @@ def _serve(cfg, params, **kw):
     return prompts, [out[r] for r in rids], b
 
 
-MODES = {"greedy": {}, "sampled": {"sampled": True},
+#: "greedy" is the step-by-step path (an ``eos_id`` no row emits makes the
+#: batcher stand down at every step), "decode_ahead" what a default-built
+#: batcher does
+MODES = {"greedy": {"eos_id": -1}, "sampled": {"sampled": True},
          "decode_block_steps": {"decode_block_steps": 4},
-         "decode_ahead": {"decode_ahead": True}}
+         "decode_ahead": {}}
 
 
 @pytest.mark.parametrize("mode", sorted(MODES))
@@ -233,8 +236,7 @@ def test_batcher_streams_agree_with_the_gather_path(served, as_on_tpu,
     assert 0 < b.kv_pages_read < b.kv_pages_viewed
     if mode == "decode_block_steps":
         assert b.decode_steps > b.decode_dispatches
-    if mode == "decode_ahead":
-        assert b.decode_ahead_dispatches > 0
+    assert (b.decode_ahead_dispatches > 0) == (mode == "decode_ahead")
     with monkeypatch.context() as m:
         _gather_only(m)
         _, want, g = _serve(cfg, params, **dict(MODES[mode]))

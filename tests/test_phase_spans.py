@@ -208,11 +208,18 @@ def served(tmp_path_factory):
     def clocks():
         return {n: c.value() for n, c in phases.seconds.items()}
 
+    def dispatches():
+        return {"ahead": reg.counter(
+                    "tfos_replica_decode_ahead_dispatches_total").value(),
+                "decode": reg.counter(
+                    "tfos_replica_decode_dispatches_total").value()}
+
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0     # as benchmark/child.start_trace
     jax.profiler.start_trace(trace_dir, profiler_options=options)
     try:
         before, slow_before = clocks(), slow.value(phase="decode_fetch")
+        counted = dispatches()
         t0 = time.perf_counter()
         feeder.start()
         replica.run_serve_loop(args, ctx, batcher)
@@ -236,6 +243,11 @@ def served(tmp_path_factory):
             if events:
                 host[(plane.name, line.name)] = events
     return {"ctx": ctx, "workdir": workdir, "wall": wall, "host": host,
+            # the loop publishes the batcher's lifetime counts, the
+            # warm-up's included, as this run's deltas
+            "dispatches": {k: v - counted[k]
+                           for k, v in dispatches().items()},
+            "batcher": batcher,
             "split": {n: after[n] - before[n] for n in after},
             "slow_trips": slow.value(phase="decode_fetch") - slow_before}
 
@@ -282,6 +294,16 @@ def test_phase_seconds_sum_to_the_loops_wall_time(served):
     assert all(v > 0 for v in served["split"].values()), served["split"]
     # the stretched turn's sleep stands in for the device and reads so
     assert served["split"][obs.BATCHER_DECODE_FETCH] >= SLOW_DELAY
+
+
+def test_ahead_dispatches_are_published_beside_decode_dispatches(served):
+    """``tfos_replica_decode_ahead_dispatches_total`` rises with the
+    batcher's attribute (two slots, six 18-token streams: most turns find
+    both seated) and is a share of the decode dispatches."""
+    d, b = served["dispatches"], served["batcher"]
+    assert d["ahead"] == b.decode_ahead_dispatches > 0
+    assert d["decode"] == b.decode_dispatches
+    assert len(served["ctx"].steps) / 2 < d["ahead"] <= d["decode"]
 
 
 def test_stretched_turn_trips_the_slow_step_rule_once(served):

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 
 from tensorflowonspark_tpu.models.bert import _context_mesh, _dense
 from tensorflowonspark_tpu.ops import paged_attention as _paged
+from tensorflowonspark_tpu.ops import power_retention as _retention
 from tensorflowonspark_tpu.ops.flash_attention import _on_tpu
 
 
@@ -138,9 +139,16 @@ class GPTConfig:
     # RMSNorm over each head's values of q and of k (learned scale per
     # head position), BEFORE the rotation.
     qk_norm: bool = False
-    # Per-layer token mixer: ``"full_attention"`` or ``"conv"``, one entry
-    # per layer (None = attention everywhere).  A conv layer is a gated
-    # SHORT CONVOLUTION (LFM2): ``[b, c, v] = split3(W_in u)``, ``z = b *
+    # Per-layer token mixer: ``"full_attention"``, ``"conv"`` or
+    # ``"retention"``, one entry per layer (None = attention everywhere).
+    # A retention layer is POWER RETENTION (:class:`PowerRetention`,
+    # ``ops.power_retention``): attention weighted by the square of the
+    # query-key product under a learned per-token decay, carried on the
+    # decode path as a fixed-size float32 state per row (``ret_state [B,
+    # Hkv, D, F]`` and its normaliser ``ret_norm [B, Hkv, F]``, ``F`` =
+    # 8704 for heads of 128): no K/V, no pages.  A configuration with no
+    # ``full_attention`` layer owns no K/V pool at all.  A conv layer is a
+    # gated SHORT CONVOLUTION (LFM2): ``[b, c, v] = split3(W_in u)``, ``z = b *
     # v``, a depthwise causal convolution of ``conv_L_cache`` taps over
     # ``z``, ``W_out (c * conv)``.  On the decode path its per-sequence
     # state is the last ``conv_L_cache - 1`` values of ``z``: a
@@ -148,6 +156,14 @@ class GPTConfig:
     # row, no pages, no position counter (``cache_kinds``).
     layer_types: tuple | None = None
     conv_L_cache: int = 3
+    # tokens per chunk of a retention layer's chunked form (a block of
+    # tokens on either path), and the normaliser's epsilon
+    retention_chunk: int = 128
+    retention_eps: float = 1e-6
+    # False = a separate output head ``lm_head [hidden, vocab]``, stored
+    # as its product reads it (the tied head re-lays the embedding table
+    # for the product on every step)
+    tie_word_embeddings: bool = True
     # Sparse experts (``models.moe.SparseMoE``): with ``num_experts`` set,
     # layers ``>= num_dense_layers`` replace the SwiGLU MLP by
     # ``num_experts`` SwiGLU experts of width ``moe_intermediate_size``,
@@ -234,12 +250,16 @@ class GPTConfig:
                     f"moe_intermediate_size={self.moe_intermediate_size}, "
                     f"{self.num_experts_per_tok} of {self.num_experts} "
                     f"experts, {self.num_dense_layers} dense layers")
-        if self.scan_layers and (self.has_conv or self.num_experts
+        if self.retention_chunk < 1:
+            raise ValueError(f"retention_chunk must be >= 1, got "
+                             f"{self.retention_chunk}")
+        if self.scan_layers and (self.has_state or self.num_experts
                                  is not None):
             raise ValueError(
                 "scan_layers stacks ONE uniform block; layer_types with "
-                "conv layers and num_experts (dense layers before expert "
-                "layers) make the blocks differ — leave scan_layers off")
+                "conv or retention layers and num_experts (dense layers "
+                "before expert layers) make the blocks differ — leave "
+                "scan_layers off")
 
     @property
     def head_dim(self) -> int:
@@ -252,6 +272,23 @@ class GPTConfig:
     @property
     def has_conv(self) -> bool:
         return self.layer_types is not None and "conv" in self.layer_types
+
+    def _count(self, layer_type: str) -> int:
+        return 0 if self.layer_types is None \
+            else self.layer_types.count(layer_type)
+
+    @property
+    def num_attention_layers(self) -> int:
+        """Layers that own K/V (``full_attention``)."""
+        return self.num_layers - self._count("conv") \
+            - self._count("retention")
+
+    @property
+    def has_state(self) -> bool:
+        """Whether some layer keeps per-row RECURRENT state on the decode
+        path (:data:`STATE_LEAVES`): fixed size per row, no position to
+        mask by, so nothing to rewind to, share or hand off."""
+        return self.num_attention_layers < self.num_layers
 
     def is_expert_layer(self, layer: int) -> bool:
         return self.num_experts is not None \
@@ -268,20 +305,47 @@ class GPTConfig:
         keeps, for error messages: a refusal names the layer type that
         caused it."""
         kinds = []
-        n_conv = 0 if self.layer_types is None \
-            else self.layer_types.count("conv")
-        if self.num_layers - n_conv:
-            kinds.append(f"K/V of {self.num_layers - n_conv} "
+        n_conv, n_ret = self._count("conv"), self._count("retention")
+        if self.num_attention_layers:
+            kinds.append(f"K/V of {self.num_attention_layers} "
                          "full_attention layer(s) (positional, rewindable)")
         if n_conv:
             kinds.append(f"conv_state of {n_conv} conv layer(s) (the last "
                          f"{self.conv_L_cache - 1} gated inputs per row: "
                          "fixed size, no snapshot to rewind to or share)")
+        if n_ret:
+            kinds.append(f"ret_state of {n_ret} retention layer(s) (the "
+                         "decayed sum of every token so far per row: fixed "
+                         "size, no snapshot to rewind to or share)")
         return "; ".join(kinds)
 
 
 #: the token mixers ``GPTConfig.layer_types`` may name
-LAYER_TYPES = ("full_attention", "conv")
+LAYER_TYPES = ("full_attention", "conv", "retention")
+
+#: cache leaves that are per-row recurrent state: the batch on their
+#: leading axis, a fixed size per row, written whole by a step.  What
+#: moves a sequence (seat, park, chunked admission) moves these rows;
+#: what needs a snapshot of them is refused (``GPTConfig.has_state``).
+STATE_LEAVES = ("conv_state", "ret_state", "ret_norm")
+
+
+def is_state_leaf(path) -> bool:
+    """Whether a cache leaf's tree path names one of :data:`STATE_LEAVES`."""
+    return getattr(path[-1], "key", None) in STATE_LEAVES
+
+
+def state_step_bytes(cfg: GPTConfig, rows: int) -> int:
+    """Bytes of per-row state one decode step of ``rows`` rows reads and
+    writes, as THIS process runs it: a conv state is read and written
+    once; a retention state as often as
+    ``ops.power_retention.state_passes`` says."""
+    conv = 2 * rows * (cfg.conv_L_cache - 1) * cfg.hidden_size \
+        * jnp.dtype(cfg.dtype).itemsize
+    ret = _retention.state_passes() * _retention.state_bytes(
+        rows, cfg.num_kv_heads or cfg.num_heads, cfg.head_dim) \
+        if cfg._count("retention") else 0
+    return cfg._count("conv") * conv + cfg._count("retention") * ret
 
 
 def kv_row_width(num_kv_heads: int, head_dim: int) -> int:
@@ -635,6 +699,87 @@ class ShortConv(nn.Module):
                           use_bias=False)(y)
 
 
+class PowerRetention(nn.Module):
+    """The token mixer of a ``"retention"`` layer: power retention with
+    power 2 (``ops.power_retention`` has the equations).  ``q``, ``k``,
+    ``v`` as attention makes them (grouped heads, no biases, RMSNorm over
+    each head's values of q and of k, rotation by absolute position), and
+    one log-decay per key/value head and token, ``g = log sigmoid(W_g
+    u)``; q, k, g, the state and the normaliser stay float32.
+
+    ``decode=True`` carries ``ret_state [B, Hkv, D, F]`` and ``ret_norm
+    [B, Hkv, F]`` (float32) and a position counter ``index``.  One token a
+    row is the recurrent step (scope ``step``: the kernel on the TPU); a
+    block of tokens is the chunked form (scope ``chunk``), which with
+    ``lengths [B]`` leaves the state as it was after each right-padded
+    row's last valid token.  ``decode=False`` is the chunked form from an
+    empty state."""
+
+    cfg: GPTConfig
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, x, lengths=None):
+        cfg = self.cfg
+        B, T, _ = x.shape
+        H, D = cfg.num_heads, cfg.head_dim
+        Hkv = cfg.num_kv_heads or H
+        with jax.named_scope("qkvg"):
+            q = _dense(H * D, (None, "tp"), cfg.dtype, "query",
+                       False)(x).reshape(B, T, H, D)
+            k = _dense(Hkv * D, (None, "tp"), cfg.dtype, "key",
+                       False)(x).reshape(B, T, Hkv, D)
+            v = _dense(Hkv * D, (None, "tp"), cfg.dtype, "value",
+                       False)(x).reshape(B, T, Hkv, D)
+            g = jax.nn.log_sigmoid(
+                _dense(Hkv, (None, None), cfg.dtype, "gate",
+                       False)(x).astype(jnp.float32))
+        with jax.named_scope("qk_norm"):
+            q = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32,
+                           name="q_norm")(q)
+            k = nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32,
+                           name="k_norm")(k)
+        per_row = cfg.per_row_positions and self.decode
+        ci = self.variable(
+            "cache", "index",
+            lambda: jnp.zeros((B,) if per_row else (), jnp.int32)) \
+            if self.decode else None
+        if cfg.pos_encoding == "rope":
+            if per_row:
+                positions = ci.value[:, None] + jnp.arange(T)[None, :]
+            else:
+                positions = (ci.value if ci is not None else 0) \
+                    + jnp.arange(T)
+            q = _rope(q, positions, cfg.rope_base)
+            k = _rope(k, positions, cfg.rope_base)
+        if self.decode:
+            state = self.variable("cache", "ret_state", jnp.zeros,
+                                  (B, Hkv, D, _retention.feature_dim(D)),
+                                  jnp.float32)
+            norm = self.variable("cache", "ret_norm", jnp.zeros,
+                                 (B, Hkv, _retention.feature_dim(D)),
+                                 jnp.float32)
+            ci.value = ci.value + T
+            s0, z0 = state.value, norm.value
+        else:
+            s0, z0 = _retention.init_state(B, Hkv, D)
+        if self.decode and T == 1:
+            with jax.named_scope("step"):
+                num, den, s1, z1 = _retention.retention_step(
+                    s0, z0, q[:, 0], k[:, 0], v[:, 0], g[:, 0])
+                y = (num / (den[..., None] + cfg.retention_eps))[:, None]
+        else:
+            with jax.named_scope("chunk"):
+                y, s1, z1 = _retention.retention_chunked(
+                    s0, z0, q, k, v, g, cfg.retention_eps,
+                    cfg.retention_chunk, lengths)
+        if self.decode:
+            state.value, norm.value = s1, z1
+        with jax.named_scope("out"):
+            return _dense(cfg.hidden_size, ("tp", None), cfg.dtype, "out",
+                          False)(y.astype(cfg.dtype).reshape(B, T, H * D))
+
+
 def _norm(cfg: GPTConfig, name: str):
     if cfg.norm == "rmsnorm":
         return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name)
@@ -643,9 +788,9 @@ def _norm(cfg: GPTConfig, name: str):
 
 class DecoderBlock(nn.Module):
     """One pre-norm block: ``h = x + Op(norm(x))``, ``h + FFN(norm(h))``.
-    ``layer`` picks the operator (``cfg.layer_types``: attention or the
-    short convolution) and the FFN (the MLP, or the experts from
-    ``cfg.num_dense_layers`` on)."""
+    ``layer`` picks the operator (``cfg.layer_types``: attention, the
+    short convolution or power retention) and the FFN (the MLP, or the
+    experts from ``cfg.num_dense_layers`` on)."""
 
     cfg: GPTConfig
     decode: bool = False
@@ -661,6 +806,8 @@ class DecoderBlock(nn.Module):
         y = _norm(cfg, "ln1")(x).astype(cfg.dtype)
         if cfg.layer_type(self.layer) == "conv":
             y = ShortConv(cfg, self.decode, name="conv")(y, lengths)
+        elif cfg.layer_type(self.layer) == "retention":
+            y = PowerRetention(cfg, self.decode, name="ret")(y, lengths)
         else:
             y = CausalSelfAttention(cfg, self.decode, name="attn")(
                 y, train=train)
@@ -698,7 +845,8 @@ class _ScanBlock(DecoderBlock):
 
 
 class GPT(nn.Module):
-    """Causal LM: ``input_ids [B, T] -> logits [B, T, V]`` (tied head).
+    """Causal LM: ``input_ids [B, T] -> logits [B, T, V]`` (the head is
+    the embedding table, or ``lm_head`` with ``tie_word_embeddings=False``).
 
     ``decode=True`` builds the incremental path: each call consumes the
     next token(s), reads/writes the ``cache`` collection, and positions
@@ -776,6 +924,11 @@ class GPT(nn.Module):
                 # a dense model's call is the one it always was
                 x = block(x, train) if lengths is None \
                     else block(x, train, lengths)
+        if not cfg.tie_word_embeddings:
+            # declared here, in the one compact method; __call__ reads it
+            self.param("lm_head", nn.with_partitioning(
+                nn.initializers.normal(0.02), (None, "tp")),
+                (cfg.hidden_size, cfg.vocab_size))
         return _norm(cfg, "ln_f")(x)
 
     def __call__(self, input_ids, *, train: bool = False, lengths=None):
@@ -787,6 +940,14 @@ class GPT(nn.Module):
         x = self.hidden(input_ids, train=train, lengths=lengths)
         if lengths is not None:
             x = jnp.take_along_axis(x, (lengths - 1)[:, None, None], axis=1)
+        cfg = self.cfg
+        if not cfg.tie_word_embeddings:
+            head = self.get_variable("params", "lm_head")
+            head = getattr(head, "value", head)
+            with jax.named_scope("lm_head"):
+                return jnp.einsum("bth,hv->btv", x.astype(cfg.dtype),
+                                  head.astype(cfg.dtype),
+                                  preferred_element_type=jnp.float32)
         table = self.get_variable("params", "tok_emb")["embedding"]
         table = getattr(table, "value", table)  # unbox partitioned param
         with jax.named_scope("lm_head"):
@@ -800,13 +961,22 @@ def init_cache(cfg: GPTConfig, params, batch: int):
     at the unallocated sentinel (``kv_pool_pages``) — zeroing them would
     alias every row onto physical page 0."""
     model = GPT(cfg, decode=True)
-    _, vars_ = model.apply(
-        {"params": params}, jnp.zeros((batch, 1), jnp.int32),
-        mutable=["cache"])
+
+    def trace(params):
+        return model.apply({"params": params},
+                           jnp.zeros((batch, 1), jnp.int32),
+                           mutable=["cache"])[1]["cache"]
+
+    # recurrent state can be the largest thing on the chip after the
+    # weights (a retention layer's is 36 MB a row): its shapes are taken
+    # without running the step, which would hold a second copy of it
+    cache = jax.eval_shape(trace, params) if cfg.has_state \
+        else trace(params)
     return jax.tree_util.tree_map_with_path(
-        lambda path, leaf: jnp.full_like(leaf, cfg.kv_pool_pages)
+        lambda path, leaf: jnp.full(leaf.shape, cfg.kv_pool_pages,
+                                    leaf.dtype)
         if any(getattr(k, "key", None) == "block_table" for k in path)
-        else jnp.zeros_like(leaf), vars_["cache"])
+        else jnp.zeros(leaf.shape, leaf.dtype), cache)
 
 
 def rewind_cache(cache, position):
@@ -817,16 +987,19 @@ def rewind_cache(cache, position):
     causal masking plus their next block write to retire entries past the
     rewound position (see :func:`lookup_generate`).
 
-    Refuses a cache with ``conv_state`` leaves: a conv layer's state is
-    the last gated inputs it saw, with no position to mask by — tokens
-    written past ``position`` have already replaced it, and there is no
-    snapshot to go back to."""
-    if any(getattr(path[-1], "key", None) == "conv_state" for path, _ in
-           jax.tree_util.tree_flatten_with_path(cache)[0]):
+    Refuses a cache with recurrent-state leaves (:data:`STATE_LEAVES`): a
+    conv layer's state is the last gated inputs it saw and a retention
+    layer's the decayed sum of every token so far, with no position to
+    mask by — tokens written past ``position`` have already entered it,
+    and there is no snapshot to go back to."""
+    held = sorted({path[-1].key for path, _ in
+                   jax.tree_util.tree_flatten_with_path(cache)[0]
+                   if is_state_leaf(path)})
+    if held:
         raise ValueError(
-            "rewind_cache: the cache holds conv_state leaves (a "
-            "layer_types 'conv' layer); a short-convolution state cannot "
-            "be rewound without a snapshot — speculative decoding "
+            f"rewind_cache: the cache holds {', '.join(held)} leaves (a "
+            "layer_types 'conv' or 'retention' layer); a recurrent state "
+            "cannot be rewound without a snapshot — speculative decoding "
             "(lookup_generate, ContinuousBatcher speculative_k/set_draft) "
             "is refused for such a configuration")
     return set_cache_counters(cache, position)
@@ -917,10 +1090,10 @@ def lookup_generate(cfg: GPTConfig, params, prompt_ids,
     after the prefill).
     """
     B, T0 = prompt_ids.shape
-    if cfg.has_conv:
+    if cfg.has_state:
         raise ValueError(
             "lookup_generate rewinds the cache after every verify block, "
-            f"and this configuration keeps {cfg.cache_kinds}: the conv "
+            f"and this configuration keeps {cfg.cache_kinds}: a recurrent "
             "state cannot be rewound")
     if max_new_tokens <= 0:
         return (prompt_ids, {"forwards": jnp.zeros((), jnp.int32)}) \

@@ -145,6 +145,12 @@ class KVPagePool:
         self.partials = 0
         self.evictions = 0
 
+    def fresh(self) -> "KVPagePool":
+        """An empty pool of the same geometry (a parameter swap drops the
+        prefix index: its pages hold K/V of the old weights)."""
+        return KVPagePool(self.total_pages, self.page_tokens,
+                          prefix_cache=self.prefix_cache)
+
     # -- capacity ----------------------------------------------------------
     def free_pages(self) -> int:
         """Allocatable pages RIGHT NOW: free + evictable cached — the
@@ -372,3 +378,42 @@ class KVPagePool:
             else:
                 self._free.append(pid)
         lease._insert = []
+
+
+class NoPages:
+    """The page accountant of a model with no K/V to page (no
+    ``full_attention`` layer: its per-sequence state is fixed-size rows of
+    the batch).  Every lease is empty and always granted, so admission is
+    bounded by free slots alone; nothing is indexed, shared or evicted."""
+
+    total_pages = 0
+    page_tokens = 1
+    prefix_cache = False
+
+    def fresh(self) -> "NoPages":
+        return self
+
+    def free_pages(self) -> int:
+        return 0
+
+    def pages_needed(self, total_tokens: int) -> int:
+        return 0
+
+    def stats(self) -> dict:
+        return {"hit": 0, "miss": 0, "partial": 0, "evictions": 0,
+                "free_pages": 0, "cached_pages": 0, "total_pages": 0}
+
+    def match_tokens(self, prompt) -> int:
+        return 0
+
+    def admit(self, prompt, total_tokens: int) -> PageLease:
+        return PageLease([], 0, self.page_tokens, "miss", [])
+
+    def export_index(self) -> list:
+        return []
+
+    def commit(self, lease: PageLease) -> None:
+        pass
+
+    def release(self, lease: PageLease) -> None:
+        pass

@@ -48,7 +48,11 @@ refcounted shared-prefix index (``models/kv_pages.py``), admission
 tied to free PAGES as well as free slots, and prefix-hit requests
 prefilling only their tails — the fused ``_prefill`` executable
 prefills, selects first tokens, and scatters block tables + counters
-in one dispatch (docs/serving.md "KV paging & prefix cache").
+in one dispatch (docs/serving.md "KV paging & prefix cache").  Beside
+the pages a slot may own fixed-size rows of RECURRENT state (conv and
+retention layers, ``models.gpt.STATE_LEAVES``), moved by the same
+admission, parking and chunked-prefill code; a model with no attention
+layer has only those, and its batcher builds no pool at all.
 
 Output contract (locked by ``tests/test_serving.py``): a request's
 tokens are a pure function of its own (params, prompt, budget,
@@ -74,8 +78,11 @@ from tensorflowonspark_tpu import observability as _obs
 from tensorflowonspark_tpu.models import moe as _moe
 from tensorflowonspark_tpu.models.gpt import (GPT, GPTConfig,
                                               attends_pages_in_place,
-                                              init_cache, nucleus_filter)
-from tensorflowonspark_tpu.models.kv_pages import KVPagePool, hash_page_data
+                                              init_cache, is_state_leaf,
+                                              nucleus_filter,
+                                              state_step_bytes)
+from tensorflowonspark_tpu.models.kv_pages import (KVPagePool, NoPages,
+                                                   hash_page_data)
 
 #: compile site -> the program's name, by ROLE and never by shape: the
 #: profiler reads ``jit_<name>``, and a reduction that selects a program
@@ -132,8 +139,8 @@ def _apply(model, params, cache, tokens, lengths=None):
     are the expert layers' sown counts (``models.moe``: assignments made,
     the busiest expert's, experts touched) as one flat int32 vector, three
     per expert layer, or None for a model without experts.  ``lengths``
-    (a padded prefill of a model with conv layers) goes to the model only
-    when given, so a dense model's trace is the one it always was."""
+    (a padded prefill of a model with recurrent state) goes to the model
+    only when given, so a dense model's trace is the one it always was."""
     kwargs = {} if lengths is None else {"lengths": lengths}
     logits, vars_ = model.apply(
         {"params": params, "cache": cache}, tokens,
@@ -143,19 +150,19 @@ def _apply(model, params, cache, tokens, lengths=None):
     return logits, vars_["cache"], stats
 
 
-def _row_view(cache, row_bt, row_start, conv_rows=None):
-    """The batch's paged cache as a prefill's rows see it: the shared
-    pools as they are, every ``block_table`` the rows' tables ``row_bt
-    [rows, pages]``, every counter the rows' start positions, every
-    ``conv_state`` the rows' carried state (``conv_rows``: one ``[rows,
-    L-1, H]`` array per leaf, in traversal order) or zeros for rows that
-    start fresh (None)."""
-    conv_in = iter(conv_rows or ())
+def _row_view(cache, row_bt, row_start, state_rows=None):
+    """The batch's cache as a prefill's rows see it: the shared pools as
+    they are, every ``block_table`` the rows' tables ``row_bt [rows,
+    pages]``, every counter the rows' start positions, every leaf of
+    recurrent state (``models.gpt.STATE_LEAVES``) the rows' carried state
+    (``state_rows``: one ``[rows, ...]`` array per leaf, in traversal
+    order) or zeros for rows that start fresh (None)."""
+    state_in = iter(state_rows or ())
 
     def rows(path, leaf):
         k = getattr(path[-1], "key", None)
-        if k == "conv_state":
-            return next(conv_in) if conv_rows is not None else jnp.zeros(
+        if is_state_leaf(path):
+            return next(state_in) if state_rows is not None else jnp.zeros(
                 row_bt.shape[:1] + leaf.shape[1:], leaf.dtype)
         if k == "block_table":
             return jnp.broadcast_to(row_bt, leaf.shape[:-2] + row_bt.shape)
@@ -334,17 +341,17 @@ class ContinuousBatcher:
                  prefill_rows_max: int | None = None,
                  decode_ahead: bool | None = None,
                  aot_cache=None):
-        if cfg.has_conv:
-            # what a second kind of per-sequence state cannot follow yet
-            # refuses here, loudly, naming the state that caused it
+        if cfg.has_state:
+            # what per-row recurrent state cannot follow yet refuses
+            # here, loudly, naming the state that caused it
             why = None
             if speculative_k is not None:
                 why = ("speculative_k rewinds the cache after each verify "
                        "dispatch")
             elif prefix_cache:
                 why = ("prefix_cache=True lets a request start from another "
-                       "request's shared pages, which hold no conv state at "
-                       "their end — pass prefix_cache=False")
+                       "request's shared pages, which hold no recurrent "
+                       "state at their end — pass prefix_cache=False")
             elif prefill_only:
                 why = ("prefill_only exports K/V pages as the whole of a "
                        "session")
@@ -477,32 +484,44 @@ class ContinuousBatcher:
         #: like a cached one skips straight to prefilling the tail.
         #: Token-exact vs the generators' dense cache on hit and miss
         #: paths alike (the locked greedy oracle covers both).
-        if kv_page_tokens is None:
-            # the page every cell serves from, halved until it divides
-            # the window so that a toy configuration still builds
-            pt = 16
-            while cfg.max_position_embeddings % pt:
-                pt //= 2
+        #: A configuration with NO ``full_attention`` layer owns no K/V:
+        #: no pool, no block table (``_table_pages`` 0), a page
+        #: accountant with nothing to count (``kv_pages.NoPages``), and
+        #: admission bounded by free slots alone.
+        if not cfg.num_attention_layers:
+            self.cfg = dataclasses.replace(cfg, per_row_positions=True)
+            self._pages = NoPages()
+            self._table_pages = 0
         else:
-            pt = int(kv_page_tokens)
-        per_req = -(-cfg.max_position_embeddings // pt)
-        # default pool: every slot can hold a max-length request; smaller
-        # pools are legal — the memory lever — and ``submit`` rejects any
-        # single request the whole pool cannot hold, so admission stays
-        # live
-        pool_pages = (int(kv_pool_pages) if kv_pool_pages is not None
-                      else int(max_batch) * per_req)
-        # dataclass validation (pow2, divisibility, int8/rolling
-        # conflicts) happens in GPTConfig.__post_init__
-        self.cfg = dataclasses.replace(
-            cfg, per_row_positions=True, kv_page_tokens=pt,
-            kv_pool_pages=pool_pages)
-        # pages shared from another request's prompt hold no conv state
-        # at their end, so a configuration with conv layers has no index
-        self._pages = KVPagePool(
-            pool_pages, pt,
-            prefix_cache=(not cfg.has_conv if prefix_cache is None
-                          else bool(prefix_cache)))
+            if kv_page_tokens is None:
+                # the page every cell serves from, halved until it divides
+                # the window so that a toy configuration still builds
+                pt = 16
+                while cfg.max_position_embeddings % pt:
+                    pt //= 2
+            else:
+                pt = int(kv_page_tokens)
+            per_req = -(-cfg.max_position_embeddings // pt)
+            # default pool: every slot can hold a max-length request;
+            # smaller pools are legal — the memory lever — and ``submit``
+            # rejects any single request the whole pool cannot hold, so
+            # admission stays live
+            pool_pages = (int(kv_pool_pages) if kv_pool_pages is not None
+                          else int(max_batch) * per_req)
+            # dataclass validation (pow2, divisibility, int8/rolling
+            # conflicts) happens in GPTConfig.__post_init__
+            self.cfg = dataclasses.replace(
+                cfg, per_row_positions=True, kv_page_tokens=pt,
+                kv_pool_pages=pool_pages)
+            # pages shared from another request's prompt hold no recurrent
+            # state at their end, so a configuration with such layers has
+            # no index
+            self._pages = KVPagePool(
+                pool_pages, pt,
+                prefix_cache=(not cfg.has_state if prefix_cache is None
+                              else bool(prefix_cache)))
+            #: entries of one row's block table
+            self._table_pages = per_req
         self.params = params
         #: the compiled executables are keyed on this tree's structure +
         #: leaf shapes/dtypes; load_params validates every later tree
@@ -562,10 +581,16 @@ class ContinuousBatcher:
         self.expert_assignments = 0
         self.expert_peak_assignments = 0
         self.experts_touched = 0
-        #: rows whose conv state an admission wrote (configurations with
-        #: ``layer_types`` conv layers; 0 otherwise) —
+        #: rows whose recurrent state an admission wrote (configurations
+        #: with conv or retention layers; 0 otherwise) —
         #: ``tfos_replica_state_rows_seated_total``
         self.state_rows_seated = 0
+        #: bytes of per-row recurrent state the decode steps read and
+        #: wrote: host arithmetic, ``models.gpt.state_step_bytes`` of the
+        #: whole batch (a step runs every row, seated or parked) per step
+        #: — ``tfos_replica_state_bytes_moved_total``
+        self.state_bytes_moved = 0
+        self._state_step_bytes = state_step_bytes(self.cfg, self.max_batch)
         #: per decode dispatch and summed over the seated rows:
         #: the pages a row's length covers (``kv_pages_read``) and, only
         #: when the step attends over the pages in place
@@ -751,9 +776,7 @@ class ContinuousBatcher:
         # parked in the (now-stale) prefix cache — a fresh pool of the
         # same geometry drops the index without touching the device-side
         # tables (idle rows are parked at the sentinel)
-        self._pages = KVPagePool(
-            self._pages.total_pages, self._pages.page_tokens,
-            prefix_cache=self._pages.prefix_cache)
+        self._pages = self._pages.fresh()
         self.params = params
 
     def set_role(self, role: str | None) -> None:
@@ -775,7 +798,7 @@ class ContinuousBatcher:
                 f"cannot set_role({role!r}) with live requests "
                 f"(load={self.load()})")
         if role == "prefill":
-            if self.cfg.has_conv:
+            if self.cfg.has_state:
                 raise ValueError(
                     "prefill role exports K/V pages as the whole of a "
                     "session; this configuration keeps "
@@ -805,7 +828,7 @@ class ContinuousBatcher:
         if not isinstance(draft, DraftModel):
             raise TypeError(
                 f"set_draft wants a DraftModel, got {type(draft).__name__}")
-        if self.cfg.has_conv:
+        if self.cfg.has_state:
             raise ValueError(
                 "draft_model speculation rewinds the target's cache after "
                 "each verify dispatch; this configuration keeps "
@@ -1044,7 +1067,7 @@ class ContinuousBatcher:
         pure function of (params, prompt, budget, temperature, top_p,
         seed) the oracle locks.  Returns the local request id."""
         self._check_usable()
-        if self.cfg.has_conv:
+        if self.cfg.has_state:
             raise ValueError(
                 "adopt_session seats a session from its K/V pages alone; "
                 f"this configuration keeps {self.cfg.cache_kinds}")
@@ -1293,10 +1316,10 @@ class ContinuousBatcher:
 
     def _last_logits(self, params, cache, tokens, true_len):
         """A padded prefill's forward: ``(logits at each row's last true
-        position [rows, V], cache, expert stats)``.  With conv layers the
-        model is told the true lengths (its state is taken there) and
+        position [rows, V], cache, expert stats)``.  With recurrent state
+        the model is told the true lengths (the state is taken there) and
         computes the head at that position only."""
-        if self.cfg.has_conv:
+        if self.cfg.has_state:
             logits, cache, stats = _apply(self.model, params, cache,
                                           tokens, lengths=true_len)
             return logits[:, 0], cache, stats
@@ -1425,17 +1448,16 @@ class ContinuousBatcher:
                 break
         return done
 
-    def _conv_rows(self, rows: int) -> list:
-        """Zeroed conv state for ``rows`` fresh rows: one ``[rows, L-1,
-        H]`` array per ``conv_state`` leaf of the cache, in its traversal
-        order (``[]`` for a model without conv layers)."""
+    def _state_rows(self, rows: int) -> list:
+        """Zeroed recurrent state for ``rows`` fresh rows: one ``[rows,
+        ...]`` array per state leaf of the cache, in its traversal order
+        (``[]`` for a model without such layers)."""
         return [jnp.zeros((rows,) + leaf.shape[1:], leaf.dtype)
                 for path, leaf in jax.tree_util.tree_flatten_with_path(
-                    self.cache)[0]
-                if getattr(path[-1], "key", None) == "conv_state"]
+                    self.cache)[0] if is_state_leaf(path)]
 
     def _prefill(self, entries, slots: list[int],
-                 conv_rows: list | None = None) -> np.ndarray:
+                 state_rows: list | None = None) -> np.ndarray:
         """THE prefill: one fused dispatch per admission group that
         (1) prefills every row's TAIL tokens (positions after its
         prefix-cache match) straight into the slot's leased pages via a
@@ -1451,9 +1473,9 @@ class ContinuousBatcher:
 
         Why padding is exact: prefill attention is causal, so pad tokens
         never influence a true last position's logits (selected per row
-        at ``true_len - 1``), and a conv layer takes its state at the
-        row's true length (``ShortConv``'s ``lengths``), where no pad
-        token has entered it; each row's cache counters are then REWOUND
+        at ``true_len - 1``), and a conv or retention layer takes its
+        state at the row's true length (the model's ``lengths``), where no
+        pad token has entered it; each row's cache counters are then REWOUND
         to its true total, after which the positional visibility mask
         hides every pad position (``k_pos > q_pos``) until the decode
         loop overwrites it with a real token's K/V in the same forward
@@ -1462,28 +1484,28 @@ class ContinuousBatcher:
         ``entries`` = ``[(req_tuple, lease, start)]`` where ``start`` is
         the first prompt position fed here (the lease's tail start, or
         past the already-streamed chunks for a chunked admission's
-        final call).  ``conv_rows`` is the conv state that chunked
-        admission carried to here (``_conv_rows``' layout); None = the
+        final call).  ``state_rows`` is the recurrent state that chunked
+        admission carried to here (``_state_rows``' layout); None = the
         rows start from zero state.  The rows' state after their true
-        last token is scattered into the batch's ``conv_state`` rows with
-        the tables.  Pad rows carry all-sentinel block tables (their
+        last token is scattered into the batch's state rows with the
+        tables.  Pad rows carry all-sentinel block tables (their
         writes drop) and slot ``max_batch`` (their scatter drops).
         Commits every lease — prefix-index insertion — after the
         dispatch, so only ALREADY-COMPUTED pages are ever matchable."""
         cfgC = self.cfg.max_position_embeddings
-        P = self.cfg.kv_pool_pages
-        npg = cfgC // self.cfg.kv_page_tokens
+        P = self._pages.total_pages
+        npg = self._table_pages
         Tp = min(_next_pow2(max(req[1].size - start
                                 for req, _, start in entries)), cfgC)
         rp = _next_pow2(len(entries))
-        carried = conv_rows is not None
+        carried = state_rows is not None
         key = ("final", Tp, rp, carried) if carried else ("final", Tp, rp)
         if key not in self._prefill_jit:
             def final_fn(params, cache, tokens, row_bt, row_start,
                          true_len, true_tot, slot_ids, seeds, temps,
-                         top_ps, conv_rows):
+                         top_ps, state_rows):
                 row_cache = _row_view(cache, row_bt, row_start,
-                                      conv_rows if carried else None)
+                                      state_rows if carried else None)
                 last, new_cache, stats = self._last_logits(
                     params, row_cache, tokens, true_len)
                 first = _select_tokens(
@@ -1491,7 +1513,7 @@ class ContinuousBatcher:
 
                 def back(path, b_leaf, r_leaf):
                     k = getattr(path[-1], "key", None)
-                    if k == "conv_state":
+                    if is_state_leaf(path):
                         return b_leaf.at[slot_ids].set(r_leaf, mode="drop")
                     if k == "block_table":
                         m = jnp.moveaxis(b_leaf, -2, 0)
@@ -1518,7 +1540,7 @@ class ContinuousBatcher:
             self._prefill_jit[key] = self._jit(key, final_fn,
                                                donate_argnums=(1,))
         self.prefill_dispatches += 1
-        if self.cfg.has_conv:
+        if self.cfg.has_state:
             self.state_rows_seated += len(entries)
         with self._spans(_obs.BATCHER_PREFILL_DISPATCH):
             row_bt = np.full((rp, npg), P, np.int32)
@@ -1547,7 +1569,7 @@ class ContinuousBatcher:
                 jnp.asarray(row_start), jnp.asarray(true_len),
                 jnp.asarray(true_tot), jnp.asarray(slot_a),
                 jnp.asarray(seed_a), jnp.asarray(temp_a),
-                jnp.asarray(top_a), conv_rows if carried else [])
+                jnp.asarray(top_a), state_rows if carried else [])
         for _, lease, _ in entries:
             self._pages.commit(lease)
         with self._spans(_obs.BATCHER_PREFILL_FETCH):
@@ -1557,29 +1579,29 @@ class ContinuousBatcher:
         """One fixed-chunk prefill executable: streams a chunk of
         the in-flight admission's tail into its leased pages (batch
         block tables/counters untouched — the slot only goes live at
-        the final :meth:`_prefill` call).  The admission's conv
-        state goes in and comes out beside the cache (``_conv_rows``'
-        layout): the reserved slot's own ``conv_state`` row is no place
-        for it, because the decode steps in between run every row."""
+        the final :meth:`_prefill` call).  The admission's recurrent
+        state goes in and comes out beside the cache (``_state_rows``'
+        layout): the reserved slot's own state row is no place for it,
+        because the decode steps in between run every row."""
         C = self.prefill_chunk
         key = ("chunk", C)
         if key not in self._prefill_jit:
             def chunk_fn(params, cache, tokens_row, row_bt, start,
-                         conv_rows):
-                row_cache = _row_view(cache, row_bt, start, conv_rows)
+                         state_rows):
+                row_cache = _row_view(cache, row_bt, start, state_rows)
                 new_cache = _apply(self.model, params, row_cache,
                                    tokens_row)[1]
-                conv_out = []
+                state_out = []
 
                 def back(p, b, r):
                     k = getattr(p[-1], "key", None)
-                    if k == "conv_state":
-                        conv_out.append(r)
-                    return b if k in ("index", "pos", "block_table",
-                                      "conv_state") else r
+                    if is_state_leaf(p):
+                        state_out.append(r)
+                        return b
+                    return b if k in ("index", "pos", "block_table") else r
 
                 return jax.tree_util.tree_map_with_path(
-                    back, cache, new_cache), conv_out
+                    back, cache, new_cache), state_out
 
             self._prefill_jit[key] = self._jit(key, chunk_fn,
                                                donate_argnums=(1,))
@@ -1602,23 +1624,22 @@ class ContinuousBatcher:
         i = inf["done_chunks"]
         if i < n_full:
             start = lease.tail_start + i * C
-            npg = self.cfg.max_position_embeddings \
-                // self.cfg.kv_page_tokens
-            row_bt = np.full((1, npg), self.cfg.kv_pool_pages, np.int32)
+            row_bt = np.full((1, self._table_pages),
+                             self._pages.total_pages, np.int32)
             row_bt[0, :len(lease.page_ids)] = lease.page_ids
-            if "conv" not in inf:
-                inf["conv"] = self._conv_rows(1)
+            if "state" not in inf:
+                inf["state"] = self._state_rows(1)
             with self._spans(_obs.BATCHER_PREFILL_DISPATCH):
-                self.cache, inf["conv"] = self._chunk_jit()(
+                self.cache, inf["state"] = self._chunk_jit()(
                     self.params, self.cache, prompt[None, start:start + C],
-                    row_bt, np.asarray([start], np.int32), inf["conv"])
+                    row_bt, np.asarray([start], np.int32), inf["state"])
             inf["done_chunks"] += 1
             return []
         slot = inf["slot"]
         self._reserved.discard(slot)
         firsts = self._prefill(
             [(req, lease, lease.tail_start + n_full * C)], [slot],
-            conv_rows=inf["conv"] if self.cfg.has_conv else None)
+            state_rows=inf["state"] if self.cfg.has_state else None)
         self._inflight = None
         tok = int(firsts[0])
         self._emit_token(rid, tok)
@@ -1637,8 +1658,8 @@ class ContinuousBatcher:
         setting its cache counters to max_len so its garbage writes hit
         the position guard and DROP instead of landing in pages now
         owned by someone else (the block-table row itself is replaced
-        wholesale at the slot's next admission).  A conv layer's state
-        row is cleared with it."""
+        wholesale at the slot's next admission).  The row's recurrent
+        state is cleared with it."""
         key = ("park",)
         if key not in self._prefill_jit:
             Cmax = self.cfg.max_position_embeddings
@@ -1649,7 +1670,7 @@ class ContinuousBatcher:
                     if k in ("index", "pos"):
                         m = jnp.moveaxis(leaf, -1, 0)
                         return jnp.moveaxis(m.at[slot].set(Cmax), 0, -1)
-                    if k == "conv_state":
+                    if is_state_leaf(path):
                         return leaf.at[slot].set(0)
                     return leaf
                 return jax.tree_util.tree_map_with_path(f, cache)
@@ -1763,11 +1784,14 @@ class ContinuousBatcher:
                                                 donate_argnums=(1,))
         return self._prefill_jit["verify"]
 
-    def _count_kv_pages(self, steps: int = 1, tokens_per_row: int = 1):
+    def _count_step_traffic(self, steps: int = 1, tokens_per_row: int = 1):
         """Account one decode dispatch of ``steps`` steps of
         ``tokens_per_row`` tokens in ``kv_pages_read`` /
-        ``kv_pages_viewed``: host arithmetic over the seated slots'
-        lengths, no device work."""
+        ``kv_pages_viewed`` and ``state_bytes_moved``: host arithmetic
+        over the seated slots' lengths, no device work."""
+        self.state_bytes_moved += steps * self._state_step_bytes
+        if not self._table_pages:
+            return
         pt, C = self.cfg.kv_page_tokens, self.cfg.max_position_embeddings
         lens = [s.prompt_len + len(s.tokens) + tokens_per_row - 1
                 for s in self.slots if s is not None]
@@ -1828,7 +1852,7 @@ class ContinuousBatcher:
         self.decode_dispatches += 1
         self.decode_steps += 1
         with self._spans(_obs.BATCHER_DECODE_DISPATCH):
-            self._count_kv_pages(tokens_per_row=K + 1)
+            self._count_step_traffic(tokens_per_row=K + 1)
             a, bonus, self.cache = self._verify_jit()(
                 self.params, self.cache, jnp.asarray(toks), jnp.asarray(d),
                 jnp.asarray([s.seed if s else 0 for s in self.slots],
@@ -1971,7 +1995,7 @@ class ContinuousBatcher:
         self.decode_dispatches += 1
         self.decode_steps += K
         with self._spans(_obs.BATCHER_DECODE_DISPATCH):
-            self._count_kv_pages(steps=K)
+            self._count_step_traffic(steps=K)
             tokens = jnp.asarray([s.tokens[-1] if s else 0
                                   for s in self.slots], jnp.int32)
             if any(s is not None and s.temperature > 0 for s in self.slots):
@@ -2023,7 +2047,7 @@ class ContinuousBatcher:
         self.decode_steps += 1
         nxt, self._ahead = self._ahead, None
         with self._spans(_obs.BATCHER_DECODE_DISPATCH):
-            self._count_kv_pages()
+            self._count_step_traffic()
             if nxt is not None:
                 pass            # dispatched ahead, during the last turn
             elif any(s is not None and s.temperature > 0

@@ -534,9 +534,10 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
         "hit, compile = miss paid with a compile, error = corrupt "
         "entry or failed write, each degraded to a compile).",
         labelnames=("outcome",))
-    # expert layers and conv state (models/moe.py, models/gpt.py
-    # ShortConv): what the batcher read with its tokens, as deltas; the
-    # paged decode step's page accounting and its steps run ahead likewise
+    # expert layers and per-row recurrent state (models/moe.py, models/gpt.py
+    # ShortConv and PowerRetention): what the batcher read with its tokens
+    # or counted on the host, as deltas; the paged decode step's page
+    # accounting and its steps run ahead likewise
     m_engine = {
         "decode_ahead_dispatches": reg.counter(
             "tfos_replica_decode_ahead_dispatches_total",
@@ -558,8 +559,14 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
             "the expert weights a dispatch had to read."),
         "state_rows_seated": reg.counter(
             "tfos_replica_state_rows_seated_total",
-            "Rows whose conv state an admission wrote (configurations "
-            "with conv layers)."),
+            "Rows whose recurrent state an admission wrote (configurations "
+            "with conv or retention layers)."),
+        "state_bytes_moved": reg.counter(
+            "tfos_replica_state_bytes_moved_total",
+            "Bytes of per-row recurrent state the decode steps read and "
+            "wrote (models.gpt.state_step_bytes of the whole batch per "
+            "step: a retention state once each through the kernel, a "
+            "third time through the jax.numpy arithmetic)."),
         "kv_pages_read": reg.counter(
             "tfos_replica_kv_pages_read_total",
             "KV pages the seated rows' lengths cover, summed over decode "

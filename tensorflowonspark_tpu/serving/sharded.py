@@ -185,16 +185,19 @@ def default_shard_params(cfg, params, mesh):
     from tensorflowonspark_tpu.models import GPT
     from tensorflowonspark_tpu.parallel.sharding import flax_shardings
 
-    if getattr(cfg, "has_conv", False) \
+    if getattr(cfg, "has_state", False) \
             or getattr(cfg, "num_experts", None) is not None:
         # the expert weights [E, H, F] and the conv kernel carry no
-        # partitioning annotations: a mesh would replicate 90 % of such a
-        # model and call it sharded
+        # partitioning annotations, and a retention layer's state (its
+        # step is a pallas_call, which is not partitioned) none by
+        # key/value head: a mesh would replicate most of such a model
+        # and call it sharded
         raise ValueError(
             "default_shard_params lays out the dense-GPT leaves only; a "
-            "configuration with conv layers or experts (layer_types / "
-            "num_experts) has no sharded layout yet — serve it on one "
-            "chip, or pass serve_shard_params= with its own layout")
+            "configuration with conv or retention layers or experts "
+            "(layer_types / num_experts) has no sharded layout yet — "
+            "serve it on one chip, or pass serve_shard_params= with its "
+            "own layout")
     model = GPT(cfg)
     abstract = jax.eval_shape(
         lambda: model.init(jax.random.key(0), jnp.ones((1, 4), jnp.int32)))

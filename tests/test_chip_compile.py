@@ -43,7 +43,7 @@ def one_chip(topo):
         reset_cache
 
     from tensorflowonspark_tpu.models import gpt
-    from tensorflowonspark_tpu.ops import paged_attention
+    from tensorflowonspark_tpu.ops import paged_attention, power_retention
 
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -51,7 +51,8 @@ def one_chip(topo):
     # the test process's default backend is the CPU, where the model's
     # paged decode step takes the gather path and the kernel alone its
     # interpreter: everything this module compiles is for the chip
-    seen = [(m, m._on_tpu) for m in (gpt, paged_attention)]
+    seen = [(m, m._on_tpu) for m in (gpt, paged_attention,
+                                     power_retention)]
     for m, _ in seen:
         m._on_tpu = lambda: True
     try:
@@ -324,3 +325,71 @@ def test_conv_and_expert_layers_compile_for_v5e(one_chip, program):
                and re.search(rf"= bf16\[{B},2,2048\]", ln)) == 3
     # the padded prefill computes the head at one position a row
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+# -- power-retention layers at Brumby-14B-Base's widths (ISSUE 32) ----------
+# Two of the layers at the published widths with the whole vocabulary and
+# the untied head, the cell's 16 rows: the decode step's kernel fits its
+# fast memory and updates the donated state IN PLACE (one live copy of the
+# largest thing on the chip after the weights), and neither program re-lays
+# the embedding table or the head.
+
+_BRUMBY_PROGRAMS = {"decode_B16_T1": (16, 1, False),
+                    "prefill_B2_T512": (2, 512, True)}
+
+
+@pytest.mark.parametrize("program", sorted(_BRUMBY_PROGRAMS))
+def test_retention_layers_compile_for_v5e(one_chip, program):
+    import re
+
+    from tensorflowonspark_tpu.models.gpt import GPT, GPTConfig, init_cache
+    from tensorflowonspark_tpu.ops import power_retention
+
+    B, T, padded = _BRUMBY_PROGRAMS[program]
+    cfg = GPTConfig(
+        vocab_size=151936, hidden_size=5120, num_layers=2, num_heads=40,
+        num_kv_heads=8, intermediate_size=17408,
+        max_position_embeddings=2048, pos_encoding="rope", rope_base=1e6,
+        norm="rmsnorm", mlp="swiglu", use_bias=False, qk_norm=True,
+        layer_types=("retention", "retention"), tie_word_embeddings=False,
+        per_row_positions=True)
+    model = GPT(cfg, decode=True)
+    params = jax.eval_shape(
+        lambda: jax.tree.map(
+            lambda a: a.astype(jnp.bfloat16),
+            GPT(cfg).init(jax.random.key(0),
+                          jnp.zeros((1, 8), jnp.int32))["params"]))
+    cache = jax.eval_shape(lambda p: init_cache(cfg, p, B), params)
+    assert {p[-1].key for p, _ in jax.tree_util.tree_flatten_with_path(
+        cache)[0]} == {"index", "ret_state", "ret_norm"}
+
+    def step(params, cache, tokens, lengths):
+        logits, vars_ = model.apply(
+            {"params": params, "cache": cache}, tokens, mutable=["cache"],
+            **({"lengths": lengths} if padded else {}))
+        return jnp.argmax(logits[:, -1], -1), vars_["cache"]
+
+    def on_chip(tree):
+        return jax.tree.map(lambda t: jax.ShapeDtypeStruct(
+            t.shape, t.dtype, sharding=one_chip), tree)
+
+    compiled = jax.jit(step, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache),
+        jax.ShapeDtypeStruct((B, T), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)).compile()
+    text = compiled.as_text()
+    state = cfg.num_layers * power_retention.state_bytes(B, 8, 128)
+    memory = compiled.memory_analysis()
+    # the state goes out in the buffers it came in: one live copy
+    assert memory.alias_size_in_bytes >= state
+    if padded:
+        assert "tfos_retention_step" not in text
+    else:
+        assert "tfos_retention_step" in text
+        assert text.count("tpu_custom_call") >= cfg.num_layers
+        # beside the weights and the state, a step holds megabytes
+        assert memory.temp_size_in_bytes < 0.1 * state
+    relaid = [ln.strip()[:120] for ln in text.splitlines()
+              if re.search(r"= \w+\[(151936,5120|5120,151936)\]\S* copy\(",
+                           ln)]
+    assert not relaid, relaid

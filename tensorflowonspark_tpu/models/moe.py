@@ -11,10 +11,16 @@ the chosen, and SwiGLU experts of width ``moe_intermediate_size``::
     FFN(u) = sum_k weight_k * W2_e(silu(W1_e u) * W3_e u),  e = sel_k
 
 It is DROPLESS: the ``tokens x k`` assignments are sorted by expert and the
-three matrix products run as grouped matmuls over the sorted rows
-(``jax.lax.ragged_dot``: one group per expert, of whatever size the router
-made it), so no assignment is lost to a capacity.  All experts live on this
-chip.  ``parallel/moe.py`` is another layer (softmax router, capacity,
+three matrix products run as grouped matmuls over the sorted rows (one
+group per expert, of whatever size the router made it), so no assignment is
+lost to a capacity.  On one TPU the grouped products are this repo's kernel
+(``ops.grouped_matmul``, device operation ``tfos_grouped_matmul``: gate and
+up in one call with the activation, down in another; each touched expert's
+weights streamed once a row tile, an untouched expert's never read);
+elsewhere they are ``jax.lax.ragged_dot``.  :func:`streams_experts_once` is
+the rule, decided at trace time from what the step can see, and both paths
+compute the same products from the same parameters.  All experts live on
+this chip.  ``parallel/moe.py`` is another layer (softmax router, capacity,
 ``shard_map`` over ``ep``) and shares nothing with this one.
 
 The router's logits, sigmoid and top-k are float32 at full matmul
@@ -35,8 +41,41 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from tensorflowonspark_tpu.models.bert import _context_mesh
+from tensorflowonspark_tpu.ops.flash_attention import _on_tpu
+from tensorflowonspark_tpu.ops.grouped_matmul import (grouped_dot,
+                                                      grouped_swiglu)
+
 #: the collection ``SparseMoE`` sows its per-call counts into
 STATS = "moe_stats"
+
+#: ``tfos_grouped_matmul`` calls of one expert layer where the kernel runs:
+#: gate and up with the activation, then down
+KERNEL_CALLS_PER_LAYER = 2
+
+
+def streams_experts_once() -> bool:
+    """Whether an expert layer traced here runs its grouped products
+    through ``ops.grouped_matmul`` and not through ``jax.lax.ragged_dot``:
+    the backend is the TPU the kernel is written for (elsewhere it would
+    run under the Pallas interpreter) and no mesh of several devices is in
+    scope (a ``pallas_call`` is not partitioned).  The number of
+    assignments does not enter: on the chip the kernel is the faster at a
+    decode step's few rows an expert and at a prefill's hundreds alike
+    (PERF.md, PR 34), its tiles chosen from the shapes it is called
+    with."""
+    if not _on_tpu():
+        return False
+    mesh = _context_mesh()
+    return mesh is None or mesh.size == 1
+
+
+def grouped_matmul_calls(cfg) -> int:
+    """``tfos_grouped_matmul`` calls in one forward of ``cfg``'s model
+    traced here: what ``tfos_replica_grouped_matmul_calls_total`` adds per
+    dispatched step (0 = the ``ragged_dot`` path, or no expert layer)."""
+    return KERNEL_CALLS_PER_LAYER * cfg.num_expert_layers \
+        if cfg.num_experts and streams_experts_once() else 0
 
 
 def route(u, router, bias, k: int):
@@ -78,14 +117,21 @@ class SparseMoE(nn.Module):
             [jnp.asarray(N * K, jnp.int32), jnp.max(counts),
              jnp.sum(counts > 0, dtype=jnp.int32)]))
         with jax.named_scope("experts"):
-            def grouped(lhs, rhs):
-                return jax.lax.ragged_dot(
-                    lhs, rhs.astype(cfg.dtype), counts,
-                    preferred_element_type=jnp.float32)
+            w_gate, w_up, w_down = (w.astype(cfg.dtype)
+                                    for w in (w_gate, w_up, w_down))
+            if streams_experts_once():
+                h = grouped_swiglu(rows, w_gate, w_up, counts,
+                                   out_dtype=cfg.dtype)
+                y = grouped_dot(h, w_down, counts)           # float32
+            else:
+                def grouped(lhs, rhs):
+                    return jax.lax.ragged_dot(
+                        lhs, rhs, counts,
+                        preferred_element_type=jnp.float32)
 
-            h = (nn.silu(grouped(rows, w_gate))
-                 * grouped(rows, w_up)).astype(cfg.dtype)
-            y = grouped(h, w_down)                           # float32
+                h = (nn.silu(grouped(rows, w_gate))
+                     * grouped(rows, w_up)).astype(cfg.dtype)
+                y = grouped(h, w_down)                       # float32
         with jax.named_scope("combine"):
             back = jnp.argsort(order)      # assignment n*K + k -> its row
             y = y[back].reshape(N, K, H) * weight[:, :, None]

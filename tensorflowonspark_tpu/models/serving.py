@@ -591,6 +591,14 @@ class ContinuousBatcher:
         #: — ``tfos_replica_state_bytes_moved_total``
         self.state_bytes_moved = 0
         self._state_step_bytes = state_step_bytes(self.cfg, self.max_batch)
+        #: ``tfos_grouped_matmul`` kernel calls the dispatched decode and
+        #: prefill programs hold (``models.moe.grouped_matmul_calls`` per
+        #: step: the model's own rule, read once, in the scope the batcher
+        #: is built and stepped in; 0 = the ``ragged_dot`` path ran, or the
+        #: model has no expert layer) —
+        #: ``tfos_replica_grouped_matmul_calls_total``
+        self.grouped_matmul_calls = 0
+        self._step_grouped_matmul_calls = _moe.grouped_matmul_calls(self.cfg)
         #: per decode dispatch and summed over the seated rows:
         #: the pages a row's length covers (``kv_pages_read``) and, only
         #: when the step attends over the pages in place
@@ -1540,6 +1548,7 @@ class ContinuousBatcher:
             self._prefill_jit[key] = self._jit(key, final_fn,
                                                donate_argnums=(1,))
         self.prefill_dispatches += 1
+        self.grouped_matmul_calls += self._step_grouped_matmul_calls
         if self.cfg.has_state:
             self.state_rows_seated += len(entries)
         with self._spans(_obs.BATCHER_PREFILL_DISPATCH):
@@ -1787,9 +1796,11 @@ class ContinuousBatcher:
     def _count_step_traffic(self, steps: int = 1, tokens_per_row: int = 1):
         """Account one decode dispatch of ``steps`` steps of
         ``tokens_per_row`` tokens in ``kv_pages_read`` /
-        ``kv_pages_viewed`` and ``state_bytes_moved``: host arithmetic
-        over the seated slots' lengths, no device work."""
+        ``kv_pages_viewed``, ``state_bytes_moved`` and
+        ``grouped_matmul_calls``: host arithmetic over the seated slots'
+        lengths, no device work."""
         self.state_bytes_moved += steps * self._state_step_bytes
+        self.grouped_matmul_calls += steps * self._step_grouped_matmul_calls
         if not self._table_pages:
             return
         pt, C = self.cfg.kv_page_tokens, self.cfg.max_position_embeddings

@@ -567,6 +567,13 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
             "wrote (models.gpt.state_step_bytes of the whole batch per "
             "step: a retention state once each through the kernel, a "
             "third time through the jax.numpy arithmetic)."),
+        "grouped_matmul_calls": reg.counter(
+            "tfos_replica_grouped_matmul_calls_total",
+            "tfos_grouped_matmul kernel calls (ops.grouped_matmul) the "
+            "dispatched decode and prefill programs held, host arithmetic "
+            "per dispatch (models.moe.grouped_matmul_calls): over decode "
+            "+ prefill dispatches the calls per dispatch, 0 says the "
+            "expert layers' ragged_dot path ran, or the model has none."),
         "kv_pages_read": reg.counter(
             "tfos_replica_kv_pages_read_total",
             "KV pages the seated rows' lengths cover, summed over decode "

@@ -42,17 +42,19 @@ def one_chip(topo):
     from jax.experimental.compilation_cache.compilation_cache import \
         reset_cache
 
-    from tensorflowonspark_tpu.models import gpt
-    from tensorflowonspark_tpu.ops import paged_attention, power_retention
+    from tensorflowonspark_tpu.models import gpt, moe
+    from tensorflowonspark_tpu.ops import (grouped_matmul, paged_attention,
+                                           power_retention)
 
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     reset_cache()
     # the test process's default backend is the CPU, where the model's
-    # paged decode step takes the gather path and the kernel alone its
-    # interpreter: everything this module compiles is for the chip
-    seen = [(m, m._on_tpu) for m in (gpt, paged_attention,
-                                     power_retention)]
+    # paged decode step takes the gather path, its expert layer
+    # ``ragged_dot`` and a kernel alone its interpreter: everything this
+    # module compiles is for the chip
+    seen = [(m, m._on_tpu) for m in (gpt, moe, grouped_matmul,
+                                     paged_attention, power_retention)]
     for m, _ in seen:
         m._on_tpu = lambda: True
     try:
@@ -263,7 +265,10 @@ def test_prefill_is_the_gather_path_program_on_v5e(one_chip, monkeypatch):
 # One period of the pattern (conv, conv, attention, conv; one dense layer,
 # three expert layers) at the published widths, the cell's 32 rows and its
 # 4096 x 16-token pool: the grouped matmul, the conv state and the 512-lane
-# pool rows as the chip's compiler takes them.
+# pool rows as the chip's compiler takes them.  Since ISSUE 34 the grouped
+# products of both programs are the kernel ``ops.grouped_matmul`` (two calls
+# an expert layer: gate and up with the activation, then down), fed the
+# experts' weights in the layout they are stored in.
 
 _LFM2_PROGRAMS = {"decode_B32_T1": (32, 1, False),
                   "prefill_B1_T1024": (1, 1024, True)}
@@ -312,8 +317,18 @@ def test_conv_and_expert_layers_compile_for_v5e(one_chip, program):
         jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)).compile()
     text = compiled.as_text()
     entry = text[text.index("\nENTRY "):].splitlines()
-    # three grouped matmuls per expert layer, as the chip's own operation
-    assert text.count("ragged-dot") >= 9
+    # two kernel calls per expert layer (``models.moe.streams_experts_once``
+    # holds at a decode step's 128 assignments and a prefill's 4096 alike)
+    # and no grouped product of XLA's ...
+    assert len(re.findall(r"= \S+ custom-call\(.*tfos_grouped_matmul",
+                          text)) == 2 * cfg.num_expert_layers == 6
+    assert "ragged-dot" not in text
+    # ... which read the experts' weights where they lie: no instruction
+    # copies or re-lays an operand of a whole layer's experts (231 MB a
+    # call, and as much again of temporaries)
+    assert not [ln.strip()[:120] for ln in text.splitlines()
+                if re.search(r"= bf16\[32,(2048,1792|1792,2048)\]\S* "
+                             r"(copy|transpose|fusion)\(", ln)]
     # the attention layer's two pools: 8 x 64 = 512 lanes, row-major
     pools = [ln for ln in entry if " parameter(" in ln
              and re.search(r"= bf16\[65536,512\]", ln)]
@@ -325,6 +340,41 @@ def test_conv_and_expert_layers_compile_for_v5e(one_chip, program):
                and re.search(rf"= bf16\[{B},2,2048\]", ln)) == 3
     # the padded prefill computes the head at one position a row
     assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9
+
+
+# the kernel alone at every shape the lfm2 cell calls it with: a decode
+# step's 128 assignments, and prefills of 1, 2 and 4 rows of the 1024
+# bucket; its tiles (``ops.grouped_matmul._tiles``) fit the fast memory it
+# asks for, and the weights enter as they are stored
+
+_GROUPED_CALLS = {"decode_128": 128, "prefill_1x1024": 4096,
+                  "prefill_2x1024": 8192, "prefill_4x1024": 16384}
+
+
+@pytest.mark.parametrize("call", sorted(_GROUPED_CALLS))
+def test_grouped_matmul_compiles_at_the_cells_shapes_on_v5e(one_chip, call):
+    from tensorflowonspark_tpu.ops import grouped_matmul as gm
+
+    m, E, H, F = _GROUPED_CALLS[call], 32, 2048, 1792
+
+    def of(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def experts(rows, w_gate, w_up, w_down, counts):
+        h = gm.grouped_swiglu(rows, w_gate, w_up, counts,
+                              out_dtype=jnp.bfloat16)
+        return gm.grouped_dot(h, w_down, counts)
+
+    compiled = jax.jit(experts).lower(
+        of(m, H), of(E, H, F), of(E, H, F), of(E, F, H),
+        of(E, dtype=jnp.int32)).compile()
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert not [ln.strip()[:120] for ln in text.splitlines()
+                if " copy(" in ln and "bf16[32," in ln]
+    # the walk's metadata and the activations between the two calls
+    assert compiled.memory_analysis().temp_size_in_bytes < m * (F * 2 + 64) \
+        + (1 << 20)
 
 
 # -- power-retention layers at Brumby-14B-Base's widths (ISSUE 32) ----------

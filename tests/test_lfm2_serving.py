@@ -398,3 +398,66 @@ def test_a_queued_step_survives_its_readers(reader):
         assert b._ahead is None and not any(b.slots)
         assert {r: v.tolist() for r, v in out.items()} \
             == {r: v.tolist() for r, v in twin.run().items()}
+
+
+# -- the expert layer's kernel: the same requests, kernel against ragged_dot
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """The expert layer's rule sees a TPU backend (``models.moe.
+    streams_experts_once``); the kernel itself still sees the CPU and runs
+    under the interpreter."""
+    monkeypatch.setattr(moe, "_on_tpu", lambda: True)
+
+
+def _calls_per_dispatch(b):
+    return b.grouped_matmul_calls / (b.decode_dispatches
+                                     + b.prefill_dispatches)
+
+
+#: "greedy" is the step-by-step twin (NEVER), "decode_ahead" what a
+#: default-built batcher does
+@pytest.mark.parametrize("mode,kwargs", [("greedy", NEVER),
+                                         ("decode_ahead", {})])
+def test_batcher_streams_through_the_kernel_as_through_ragged_dot(
+        made, monkeypatch, mode, kwargs):
+    cfg, params = made
+    kw = dict(MODES["paged"], **kwargs)
+    with jax.default_matmul_precision("highest"):
+        plain, want, ev_plain = _serve(cfg, params, cfg.vocab_size, kw,
+                                       AHEAD_SCHEDULE, 30)
+        with monkeypatch.context() as m:
+            m.setattr(moe, "_on_tpu", lambda: True)
+            kern, got, ev_kern = _serve(cfg, params, cfg.vocab_size, kw,
+                                        AHEAD_SCHEDULE, 30)
+            text = kern._step.lower(
+                params, kern.cache, jnp.zeros(
+                    (3 + 3 * cfg.num_expert_layers,), jnp.int32)).as_text()
+    assert all(v is not None for v in want.values())
+    assert {i: v.tolist() for i, v in got.items()} \
+        == {i: v.tolist() for i, v in want.items()}
+    assert ev_kern == ev_plain      # token for token, step for step
+    assert "ragged" not in text
+    assert (kern.decode_ahead_dispatches > 0) == (mode == "decode_ahead")
+    # the router's counts ride with the tokens on both paths
+    assert (kern.expert_assignments, kern.expert_peak_assignments,
+            kern.experts_touched) \
+        == (plain.expert_assignments, plain.expert_peak_assignments,
+            plain.experts_touched)
+    assert kern.expert_assignments > 0
+    # a fused gate-and-up call and a down call per expert layer, in every
+    # decode and prefill dispatch; none where ragged_dot ran
+    assert _calls_per_dispatch(kern) == 2 * cfg.num_expert_layers == 6
+    assert plain.grouped_matmul_calls == 0 and plain.decode_dispatches > 0
+
+
+@pytest.mark.parametrize("model,kw", [
+    ("dense-gpt", {}),
+    ("retention", dict(layer_types=("retention",) * 2,
+                       tie_word_embeddings=False))])
+def test_a_model_without_experts_counts_no_kernel_call(as_on_tpu, model, kw):
+    cfg, params = _dense(**kw)
+    b = ContinuousBatcher(cfg, params, max_batch=2)
+    b.submit(_prompt_of(0, 5, cfg.vocab_size), 4)
+    b.run()
+    assert b.decode_dispatches > 0 and b.grouped_matmul_calls == 0

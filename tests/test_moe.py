@@ -86,3 +86,98 @@ def test_moe_capacity_drops_tokens():
 def test_moe_rejects_bad_expert_count():
     with pytest.raises(ValueError, match="must divide"):
         make_moe_layer(HID, FFN, 6, ep=4)
+
+
+# -- the served expert layer (models/moe.py): which grouped product it runs --
+# ``parallel/moe.py`` above is another layer; this one is the dropless
+# SparseMoE of a served decoder block, whose grouped products are the
+# kernel ``ops.grouped_matmul`` on one TPU and ``ragged_dot`` elsewhere.
+
+from tensorflowonspark_tpu.models import GPTConfig  # noqa: E402
+from tensorflowonspark_tpu.models import moe as served  # noqa: E402
+
+
+def _expert_cfg(**kw):
+    return GPTConfig(**{**dict(
+        vocab_size=61, hidden_size=32, num_layers=2, num_heads=4,
+        intermediate_size=64, max_position_embeddings=64, num_experts=8,
+        num_experts_per_tok=4, moe_intermediate_size=16,
+        num_dense_layers=0, mlp="swiglu", dtype=jnp.float32), **kw})
+
+
+def _layer_text(cfg, rows, tokens):
+    """The jaxpr of one expert layer over ``[rows, tokens]``, as text."""
+    layer = served.SparseMoE(cfg)
+    x = jax.ShapeDtypeStruct((rows, tokens, cfg.hidden_size), cfg.dtype)
+    params = jax.eval_shape(lambda: layer.init(
+        jax.random.key(0), jnp.zeros(x.shape, x.dtype)))["params"]
+    return str(jax.make_jaxpr(lambda p, x: layer.apply(
+        {"params": p}, x, mutable=[served.STATS]))(params, x))
+
+
+@pytest.fixture
+def as_on_tpu(monkeypatch):
+    """The rule sees a TPU backend; the kernel still sees the CPU and
+    would run under the interpreter."""
+    monkeypatch.setattr(served, "_on_tpu", lambda: True)
+
+
+@pytest.mark.parametrize("why,rows,tokens", [
+    ("a decode step: 32 rows of one token, 4 assignments an expert", 32, 1),
+    ("a one-row prefill of the 1024 bucket, 128 an expert", 1, 1024),
+    ("a four-row prefill of the 1024 bucket, 512 an expert", 4, 1024),
+])
+def test_on_one_tpu_the_expert_layer_runs_the_kernel_at_every_shape(
+        as_on_tpu, why, rows, tokens):
+    cfg = _expert_cfg()
+    assert served.streams_experts_once(), why
+    assert served.grouped_matmul_calls(cfg) == 2 * cfg.num_expert_layers == 4
+    text = _layer_text(cfg, rows, tokens)
+    assert "ragged_dot" not in text and text.count("pallas_call") == 2, why
+
+
+def test_off_the_tpu_the_expert_layer_keeps_ragged_dot():
+    cfg = _expert_cfg()
+    assert not served.streams_experts_once()
+    assert served.grouped_matmul_calls(cfg) == 0
+    assert _layer_text(cfg, 32, 1).count("ragged_dot_general[") == 3
+
+
+def test_a_mesh_of_several_devices_keeps_ragged_dot(as_on_tpu):
+    from jax.sharding import Mesh
+
+    cfg = _expert_cfg()
+    devs = np.asarray(jax.devices())
+    if devs.size < 2:
+        pytest.skip("one device")
+    with Mesh(devs[:2].reshape(1, 2), ("dp", "tp")):
+        assert not served.streams_experts_once()
+        assert served.grouped_matmul_calls(cfg) == 0
+        assert _layer_text(cfg, 32, 1).count("ragged_dot_general[") == 3
+    with Mesh(devs[:1].reshape(1, 1), ("dp", "tp")):
+        assert served.streams_experts_once()
+
+
+def test_a_model_without_experts_holds_no_kernel_call(as_on_tpu):
+    dense = GPTConfig(num_layers=2, hidden_size=32, num_heads=2,
+                      vocab_size=50, max_position_embeddings=32)
+    assert served.grouped_matmul_calls(dense) == 0
+
+
+@pytest.mark.parametrize("rows,tokens", [(32, 1), (2, 24)])
+def test_both_paths_compute_the_same_layer_and_sow_the_same_counts(
+        monkeypatch, rows, tokens):
+    cfg = _expert_cfg()
+    layer = served.SparseMoE(cfg)
+    x = jax.random.normal(jax.random.key(1), (rows, tokens, 32), jnp.float32)
+    params = layer.init(jax.random.key(0), x)["params"]
+    with jax.default_matmul_precision("highest"):
+        want, stats = layer.apply({"params": params}, x,
+                                  mutable=[served.STATS])
+        monkeypatch.setattr(served, "_on_tpu", lambda: True)
+        got, kstats = layer.apply({"params": params}, x,
+                                  mutable=[served.STATS])
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    assert jax.tree.leaves(kstats)[0].tolist() \
+        == jax.tree.leaves(stats)[0].tolist() \
+        and jax.tree.leaves(stats)[0][0] == rows * tokens * 4

@@ -248,6 +248,9 @@ def served(tmp_path_factory):
             "dispatches": {k: v - counted[k]
                            for k, v in dispatches().items()},
             "batcher": batcher,
+            # read after the loop alone: the loop registers it
+            "kernel_calls": reg.snapshot().get(
+                "tfos_replica_grouped_matmul_calls_total"),
             "split": {n: after[n] - before[n] for n in after},
             "slow_trips": slow.value(phase="decode_fetch") - slow_before}
 
@@ -304,6 +307,17 @@ def test_ahead_dispatches_are_published_beside_decode_dispatches(served):
     assert d["ahead"] == b.decode_ahead_dispatches > 0
     assert d["decode"] == b.decode_dispatches
     assert len(served["ctx"].steps) / 2 < d["ahead"] <= d["decode"]
+
+
+def test_kernel_calls_are_published_and_a_dense_model_moves_none(served):
+    """``tfos_replica_grouped_matmul_calls_total`` is the loop's own
+    registration (its help names the kernel), published like the other
+    engine counters, and a model without experts adds nothing to it."""
+    entry = served["kernel_calls"]
+    assert entry is not None and entry["type"] == "counter"
+    assert "tfos_grouped_matmul" in entry["help"]
+    assert sum(row[-1] for row in entry["samples"]) == 0
+    assert served["batcher"].grouped_matmul_calls == 0
 
 
 def test_stretched_turn_trips_the_slow_step_rule_once(served):

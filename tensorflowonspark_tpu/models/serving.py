@@ -97,6 +97,17 @@ PROGRAM_NAMES = {
     "pexport": "tfos_kv_export", "draft_propose": "tfos_draft"}
 
 
+#: why a decode dispatch had no plain step dispatched ahead behind it
+#: (``ContinuousBatcher._stands_down``, in the order it asks): the batcher
+#: decides each dispatch from the last one's tokens (``speculative_k``,
+#: ``decode_block_steps``); an ``eos_id`` can end a row at any step; a
+#: chunked admission is in flight; a seated row is sampled; a row was seated
+#: since the step was dispatched (or the step was consumed by an admission,
+#: ahead of its prefill's fetch); no seated row goes on; ``settle()`` asked
+STANDDOWNS = ("alternative", "eos", "chunked", "sampled", "admission",
+              "idle", "settle")
+
+
 #: the page axis of a paged pool leaf seen as pages (:func:`_as_pages`)
 #: and of an exported page array ``[..., n, pt, W]``
 _PAGE_AXIS = -3
@@ -132,6 +143,8 @@ class _Slot:
     seed: int = 0
     lease: object = None                        # its PageLease
     prompt_len: int = 0     # positions seated by the admission
+    parked: bool = False    # its row was parked on the device ahead of its
+    #                         last step's fetch (``_plain_step``)
 
 
 def _apply(model, params, cache, tokens, lengths=None):
@@ -324,7 +337,7 @@ class ContinuousBatcher:
     device may already hold the next step (:meth:`settle`).
 
     ``decode_ahead`` is accepted and unread: the batcher runs ahead by
-    rule, wherever the next step's rows are decided (:meth:`_runs_ahead`).
+    rule, wherever the next step's rows are decided (:meth:`_stands_down`).
     """
 
     def __init__(self, cfg: GPTConfig, params, max_batch: int,
@@ -458,23 +471,39 @@ class ContinuousBatcher:
         #: float32 first) grow with its rows and the chip's memory that
         #: the weights leave free does not
         self.prefill_rows_max = prefill_rows_max
-        #: RUN-AHEAD, by rule (:meth:`_runs_ahead`): while every slot is
-        #: seated, greedy, and more than one token short of its budget (and
-        #: no ``eos_id`` can end a row early), the next plain decode step's
-        #: rows are already known, so it is dispatched BEFORE the running
-        #: step's tokens are fetched, fed the running step's tokens as they
-        #: lie on the device.  The device then runs step after step with no
-        #: host turn between them, and a late wake-up of the host shorter
-        #: than a step costs nothing.  Token-exact (the same executable, the
-        #: same inputs); admission is never delayed, because with every slot
-        #: busy and no row finishing nothing could have been admitted anyway.
-        #: ``_ahead`` is the step dispatched ahead and not yet consumed (its
-        #: packed tokens on the device): while it is set ``self.cache`` is
-        #: one step ahead of the slots (:meth:`settle` makes them agree);
-        #: ``decode_ahead_dispatches`` counts them, at most one per decode
-        #: dispatch — ``tfos_replica_decode_ahead_dispatches_total``
-        self._ahead = None
+        #: RUN-AHEAD, by rule (:meth:`_stands_down`): while every seated row
+        #: is greedy and was in the running step, no ``eos_id`` can end a
+        #: row early and one row at least goes on, the next plain decode
+        #: step's rows are already known, so it is dispatched BEFORE the
+        #: running step's tokens are fetched, fed the running step's tokens
+        #: as they lie on the device.  A row at its last token ends at the
+        #: running step BY BUDGET: its parking is dispatched between the two
+        #: steps, so the next step meets it parked.  A free slot stops
+        #: nothing: a step runs every row, seated or parked.  The device then
+        #: runs step after step with no host turn between them, and a late
+        #: wake-up of the host shorter than a step costs nothing.
+        #: Token-exact (the same executable, the same inputs).  A request
+        #: admitted while a step is queued is prefilled BEHIND it and joins
+        #: the decode at the next dispatch (:meth:`_prefill`), which
+        #: ``step()`` makes before it returns, from the host's tokens, as
+        #: soon as the new row's first one is there (``_step_inner``).
+        #: ``_ahead`` is the step dispatched and not yet consumed: its
+        #: packed tokens on the device, and the slots' rows it holds (None
+        #: where a slot was free or parked).  While it is set ``self.cache``
+        #: is one step ahead of the slots (:meth:`settle` makes them agree).
+        #: ``decode_ahead_dispatches`` counts the steps dispatched behind a
+        #: running one, at most one per decode dispatch —
+        #: ``tfos_replica_decode_ahead_dispatches_total``;
+        #: ``decode_ahead_standdowns[why]`` counts the plain steps that had
+        #: none dispatched behind them (:data:`STANDDOWNS`), so the two sum
+        #: to ``decode_dispatches`` —
+        #: ``tfos_replica_decode_ahead_standdowns_total{why}``
+        self._ahead: tuple | None = None
         self.decode_ahead_dispatches = 0
+        self.decode_ahead_standdowns = dict.fromkeys(STANDDOWNS, 0)
+        #: what the queued step finished, where an admission consumed it
+        #: ahead of its own fetch (:meth:`_prefill`), for ``step()`` to return
+        self._settled: list[int] | None = None
         #: THE K/V STORE: a pool of ``kv_pool_pages`` pages of
         #: ``kv_page_tokens`` tokens (a power of two) behind per-row block
         #: tables (``models/gpt`` device side, ``models/kv_pages``
@@ -1499,7 +1528,11 @@ class ContinuousBatcher:
         tables.  Pad rows carry all-sentinel block tables (their
         writes drop) and slot ``max_batch`` (their scatter drops).
         Commits every lease — prefix-index insertion — after the
-        dispatch, so only ALREADY-COMPUTED pages are ever matchable."""
+        dispatch, so only ALREADY-COMPUTED pages are ever matchable.
+        Where a decode step is queued ahead, the dispatch goes behind it
+        and that step is consumed before this one's tokens are fetched
+        (``step()`` then fetches no other decode step: the rows seated
+        here join the next dispatch, made before it returns)."""
         cfgC = self.cfg.max_position_embeddings
         P = self._pages.total_pages
         npg = self._table_pages
@@ -1581,6 +1614,13 @@ class ContinuousBatcher:
                 jnp.asarray(top_a), state_rows if carried else [])
         for _, lease, _ in entries:
             self._pages.commit(lease)
+        if self._ahead is not None:
+            # this prefill lies BEHIND a queued step on the device (it
+            # writes the group's leased pages and free slots' rows, where
+            # that step writes nothing).  Results are fetched in device
+            # order: the step is consumed, and its tokens emitted, before
+            # the prefill is waited for
+            self._settled = self._plain_step(stand_down="admission")
         with self._spans(_obs.BATCHER_PREFILL_FETCH):
             return self._fetch(firsts)
 
@@ -1668,7 +1708,8 @@ class ContinuousBatcher:
         the position guard and DROP instead of landing in pages now
         owned by someone else (the block-table row itself is replaced
         wholesale at the slot's next admission).  The row's recurrent
-        state is cleared with it."""
+        state is cleared with it.  Dispatched by ``_finish``, or ahead of
+        it by ``_plain_step`` for a row that ends by budget."""
         key = ("park",)
         if key not in self._prefill_jit:
             Cmax = self.cfg.max_position_embeddings
@@ -1697,7 +1738,8 @@ class ContinuousBatcher:
         if s.lease is not None:
             self._pages.release(s.lease)
             s.lease = None
-        self._park_slot(i)
+        if not s.parked:    # else parked behind its last step, ahead
+            self._park_slot(i)
 
     # -- decode ------------------------------------------------------------
     def step(self) -> list[int]:
@@ -1793,19 +1835,21 @@ class ContinuousBatcher:
                                                 donate_argnums=(1,))
         return self._prefill_jit["verify"]
 
-    def _count_step_traffic(self, steps: int = 1, tokens_per_row: int = 1):
+    def _count_step_traffic(self, steps: int = 1, tokens_per_row: int = 1,
+                            rows: list | None = None):
         """Account one decode dispatch of ``steps`` steps of
         ``tokens_per_row`` tokens in ``kv_pages_read`` /
         ``kv_pages_viewed``, ``state_bytes_moved`` and
-        ``grouped_matmul_calls``: host arithmetic over the seated slots'
-        lengths, no device work."""
+        ``grouped_matmul_calls``: host arithmetic over the lengths of the
+        dispatch's ``rows`` (None = the seated slots), no device work."""
         self.state_bytes_moved += steps * self._state_step_bytes
         self.grouped_matmul_calls += steps * self._step_grouped_matmul_calls
         if not self._table_pages:
             return
         pt, C = self.cfg.kv_page_tokens, self.cfg.max_position_embeddings
         lens = [s.prompt_len + len(s.tokens) + tokens_per_row - 1
-                for s in self.slots if s is not None]
+                for s in (self.slots if rows is None else rows)
+                if s is not None]
         self.kv_pages_read += sum(-(-min(n + k, C) // pt)
                                   for n in lens for k in range(steps))
         if tokens_per_row == 1 and self._attends_in_place:
@@ -1862,6 +1906,7 @@ class ContinuousBatcher:
             return self._plain_step()
         self.decode_dispatches += 1
         self.decode_steps += 1
+        self.decode_ahead_standdowns["alternative"] += 1
         with self._spans(_obs.BATCHER_DECODE_DISPATCH):
             self._count_step_traffic(tokens_per_row=K + 1)
             a, bonus, self.cache = self._verify_jit()(
@@ -1917,14 +1962,28 @@ class ContinuousBatcher:
                     self.sessions_exported += 1
                     self._finish(i, s)
             return done
-        if not any(self.slots):
+        settled, self._settled = self._settled, None
+        if settled is not None:
+            # an admission consumed the queued step (``_prefill``): that
+            # was this call's decode step
+            done = settled + done
+        elif not any(self.slots):
             return done
-        if self.spec_k is not None:
+        elif self.spec_k is not None:
             return done + self._spec_step()
-        K = self._block_size()
-        if K > 1:
-            return done + self._block_step(K)
-        return done + self._plain_step()
+        else:
+            K = self._block_size()
+            if K > 1:
+                return done + self._block_step(K)
+            done += self._plain_step()
+        if self._ahead is None and self._stands_down() is None:
+            # the step stood down for rows seated this turn (prefilled
+            # behind it, or adopted): their tokens are on the host now, so
+            # the next step is dispatched before the caller's turn goes on,
+            # not after it, and the device starts at once
+            with self._spans(_obs.BATCHER_DECODE_DISPATCH):
+                self._ahead = self._dispatch_step()
+        return done
 
     def _block_size(self) -> int:
         """How many decode steps the next dispatch may scan: bounded by
@@ -2005,6 +2064,7 @@ class ContinuousBatcher:
         done: list[int] = []
         self.decode_dispatches += 1
         self.decode_steps += K
+        self.decode_ahead_standdowns["alternative"] += 1
         with self._spans(_obs.BATCHER_DECODE_DISPATCH):
             self._count_step_traffic(steps=K)
             tokens = jnp.asarray([s.tokens[-1] if s else 0
@@ -2040,59 +2100,104 @@ class ContinuousBatcher:
                         break
         return done
 
-    def _runs_ahead(self) -> bool:
-        """Whether the step AFTER the one about to be fetched is already
-        decided: a batcher that decides each dispatch from the last one's
-        tokens (``speculative_k``, ``decode_block_steps``) never says so;
-        otherwise every slot seated and greedy, none finishing at this
-        step, no ``eos_id``, no chunked admission in flight — so nothing
-        can leave or join before it."""
-        return (self.spec_k is None and self.decode_block_steps is None
-                and self.eos_id is None and self._inflight is None
-                and all(s is not None and s.temperature <= 0
-                        and s.remaining > 1 for s in self.slots))
+    def _stands_down(self, rows: list | None = None) -> str | None:
+        """Why the next plain decode step is not decided yet, one of
+        :data:`STANDDOWNS`, or None where it is.  ``rows`` are the slots'
+        rows of the step about to be fetched, which the next would be
+        dispatched behind, fed its tokens as they lie on the device; None
+        = no step awaits its fetch, and the next is fed the host's tokens.
+        A batcher that decides each dispatch from the last one's tokens
+        (``speculative_k``, ``decode_block_steps``) never knows; nor one
+        whose rows an ``eos_id`` can end at any step, or with a chunked
+        admission in flight (it takes its slot at a step of its own); a
+        sampled row's step needs its host-side sampler state; a row seated
+        since that step was dispatched has its token on the host, not in
+        the step's output; and with no seated row left after it there is
+        no next step.  Otherwise nothing can join before the next step,
+        what leaves it leaves by budget, and a free slot changes nothing:
+        a step runs every row, seated or parked."""
+        if self.spec_k is not None or self.decode_block_steps is not None:
+            return "alternative"
+        if self.eos_id is not None:
+            return "eos"
+        if self._inflight is not None:
+            return "chunked"
+        if any(s is not None and s.temperature > 0 for s in self.slots):
+            return "sampled"
+        if rows is not None and any(s is not None and s is not r
+                                    for s, r in zip(self.slots, rows)):
+            return "admission"
+        if not any(s is not None and (rows is None or s.remaining > 1)
+                   for s in self.slots):
+            return "idle"
+        return None
 
-    def _plain_step(self, run_ahead: bool = True) -> list[int]:
+    @property
+    def step_queued(self) -> bool:
+        """Whether a dispatched decode step awaits its fetch: the device
+        has work whatever the host does next, so a serving loop need not
+        wait for a request on its behalf."""
+        return self._ahead is not None
+
+    def _dispatch_step(self) -> tuple:
+        """Dispatch one plain decode step of the seated rows, fed the
+        host's tokens: its packed tokens, on the device, and its rows."""
+        if any(s is not None and s.temperature > 0 for s in self.slots):
+            tokens = jnp.asarray([s.tokens[-1] if s else 0
+                                  for s in self.slots], jnp.int32)
+            nxt, self.cache = self._step_sample(
+                self.params, self.cache, tokens,
+                jnp.asarray([s.seed if s else 0 for s in self.slots],
+                            jnp.int32),
+                jnp.asarray([len(s.tokens) if s else 0
+                             for s in self.slots], jnp.int32),
+                jnp.asarray([s.temperature if s else 0.0
+                             for s in self.slots], jnp.float32),
+                jnp.asarray([s.top_p if s else 1.0 for s in self.slots],
+                            jnp.float32))
+        else:
+            # padded to the packed length for a model with experts
+            # (``step_greedy``)
+            tokens = np.zeros(
+                self.max_batch + 3 * self.cfg.num_expert_layers, np.int32)
+            tokens[:self.max_batch] = [s.tokens[-1] if s else 0
+                                       for s in self.slots]
+            nxt, self.cache = self._step(self.params, self.cache,
+                                         jnp.asarray(tokens))
+        return nxt, list(self.slots)
+
+    def _plain_step(self, stand_down: str | None = None) -> list[int]:
+        """One plain decode step's turn: take the step that is queued, or
+        dispatch one; unless the rule (or the caller, naming its reason in
+        ``stand_down``) stands down, park the rows that end at it by
+        budget and dispatch the next step behind it; then fetch it, emit
+        its rows' tokens and finish what finished."""
         done: list[int] = []
         self.decode_dispatches += 1
         self.decode_steps += 1
-        nxt, self._ahead = self._ahead, None
+        queued, self._ahead = self._ahead, None
         with self._spans(_obs.BATCHER_DECODE_DISPATCH):
-            self._count_step_traffic()
-            if nxt is not None:
-                pass            # dispatched ahead, during the last turn
-            elif any(s is not None and s.temperature > 0
-                     for s in self.slots):
-                tokens = jnp.asarray([s.tokens[-1] if s else 0
-                                      for s in self.slots], jnp.int32)
-                nxt, self.cache = self._step_sample(
-                    self.params, self.cache, tokens,
-                    jnp.asarray([s.seed if s else 0 for s in self.slots],
-                                jnp.int32),
-                    jnp.asarray([len(s.tokens) if s else 0
-                                 for s in self.slots], jnp.int32),
-                    jnp.asarray([s.temperature if s else 0.0
-                                 for s in self.slots], jnp.float32),
-                    jnp.asarray([s.top_p if s else 1.0 for s in self.slots],
-                                jnp.float32))
-            else:
-                # padded to the packed length for a model with experts
-                # (``step_greedy``)
-                tokens = np.zeros(
-                    self.max_batch + 3 * self.cfg.num_expert_layers,
-                    np.int32)
-                tokens[:self.max_batch] = [s.tokens[-1] if s else 0
-                                           for s in self.slots]
-                nxt, self.cache = self._step(self.params, self.cache,
-                                             jnp.asarray(tokens))
-            if run_ahead and self._runs_ahead():
-                self._ahead, self.cache = self._step(self.params,
-                                                     self.cache, nxt)
+            nxt, rows = queued or self._dispatch_step()
+            self._count_step_traffic(rows=rows)
+            why = stand_down or self._stands_down(rows)
+            if why is None:
+                # device order: this step, the parking of the rows that end
+                # at it (their writes in the next step meet the position
+                # guard, as they would one turn later), the next step
+                for i, s in enumerate(self.slots):
+                    if s is not None and s.remaining == 1:
+                        self._park_slot(i)
+                        s.parked = True
+                ahead, self.cache = self._step(self.params, self.cache, nxt)
+                self._ahead = (ahead, [None if s is None or s.parked else s
+                                       for s in self.slots])
                 self.decode_ahead_dispatches += 1
+            else:
+                self.decode_ahead_standdowns[why] += 1
         with self._spans(_obs.BATCHER_DECODE_FETCH):
             nxt = self._fetch(nxt)
         with self._spans(_obs.BATCHER_EMIT):
-            for i, s in enumerate(self.slots):
+            for i, s in enumerate(rows):
                 if s is None:
                     continue
                 tok = int(nxt[i])
@@ -2113,20 +2218,31 @@ class ContinuousBatcher:
 
         For a caller that reads ``self.cache`` against the slots between
         two ``step()`` calls (a logit probe); nothing inside the package
-        has to.  While a step is queued every slot is seated and no
-        chunked admission is in flight, so ``_admit`` seats and adopts
-        nothing and no slot is finished or parked before ``_plain_step``
-        consumes it; ``unload_params`` (hence ``load_params``) and
-        ``set_role`` want an idle batcher, and the serve loop swaps models
-        and exits only when idle; ``run()`` ends with no slot seated, hence
-        none queued; the page traffic between turns
-        (``export_prefix_cache``, ``import_prefix_cache``) touches only
-        indexed prompt pages and free pages, where no decode step writes.
-        A queued step keeps the parameters it was dispatched with, as any
-        step in flight does."""
+        has to.  A queued step writes the rows it holds — the next
+        position of each in the row's own leased pages, its counters and
+        its recurrent state — and, of a free or parked row, the state
+        alone (its K/V writes drop at the position guard; a row that ends
+        by budget was parked ahead of it).  What may run while it is
+        queued touches none of that: ``_admit`` and ``_admit_adopts``
+        (hence ``adopt_session``) dispatch BEHIND it, into free slots'
+        rows, whose state they replace whole, and into leased pages, free
+        or shared prompt pages until then — pages released at a finish
+        among them, which the step, the row parked, no longer writes — and
+        ``_prefill`` consumes the step before it waits for its own tokens;
+        a chunked admission streams into its leased pages and keeps its
+        state beside the cache; the page traffic between turns
+        (``export_prefix_cache``, hence ``serve_clone_request``, and
+        ``import_prefix_cache``) reads indexed prompt pages and writes
+        free pages, where no decode step writes.  ``unload_params`` (hence
+        ``load_params``), ``set_role`` and a hot swap want an idle
+        batcher, and none is queued without a seated row that goes on:
+        ``run()`` ends, and the serve loop exits, with none queued;
+        ``take_sessions`` drains a prefill-only batcher, which never
+        decodes.  A queued step keeps the parameters it was dispatched
+        with, as any step in flight does."""
         if self._ahead is None:
             return []
-        return self._guarded(self._plain_step, run_ahead=False)
+        return self._guarded(self._plain_step, stand_down="settle")
 
     def result(self, request_id: int, *, pop: bool = False) \
             -> np.ndarray | None:

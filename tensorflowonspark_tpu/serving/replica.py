@@ -53,7 +53,9 @@ tier:
   ``serve_batcher_kwargs`` (extra ``ContinuousBatcher`` kwargs, e.g.
   ``decode_block_steps``/``speculative_k`` — note blocks trade intake
   latency for dispatch amortization);
-- ``serve_idle_poll`` / ``serve_busy_poll`` — intake timeouts (secs).
+- ``serve_idle_poll`` / ``serve_busy_poll`` — intake timeouts (secs): how
+  long a sweep of the request queue waits with nothing seated / with a
+  slot free beside seated rows and no decode step queued ahead.
 """
 
 from __future__ import annotations
@@ -542,7 +544,7 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
         "decode_ahead_dispatches": reg.counter(
             "tfos_replica_decode_ahead_dispatches_total",
             "Plain decode steps dispatched before the running step's "
-            "tokens were fetched (ContinuousBatcher._runs_ahead): over "
+            "tokens were fetched (ContinuousBatcher._stands_down): over "
             "tfos_replica_decode_dispatches_total, the share of decode "
             "steps whose host turn hid behind the device."),
         "expert_assignments": reg.counter(
@@ -584,10 +586,17 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
             "decode dispatches that attended over the pages in place "
             "(ops.paged_attention): read / viewed is the share the kernel "
             "touches, 0 says the whole-view gather ran.")}
+    m_standdowns = reg.counter(
+        "tfos_replica_decode_ahead_standdowns_total",
+        "Decode dispatches that had no plain step dispatched ahead behind "
+        "them, by what stood in its way (models.serving.STANDDOWNS): with "
+        "tfos_replica_decode_ahead_dispatches_total they sum to "
+        "tfos_replica_decode_dispatches_total.", labelnames=("why",))
     last = {"decode_dispatches": 0, "prefill_dispatches": 0,
             **dict.fromkeys(m_engine, 0),
             "spec_proposed": 0, "spec_accepted": 0,
             "sessions_exported": 0, "sessions_adopted": 0,
+            "standdowns": {},
             "hit": 0, "miss": 0, "partial": 0,
             "aot_loads": 0, "aot_compiles": 0, "aot_errors": 0}
 
@@ -613,6 +622,12 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
             if cur > last[attr]:
                 m_sessions.inc(cur - last[attr], direction=direction)
                 last[attr] = cur
+        for why, cur in getattr(batcher, "decode_ahead_standdowns",
+                                {}).items():
+            before = last["standdowns"].get(why, 0)
+            if cur > before:
+                m_standdowns.inc(cur - before, why=why)
+                last["standdowns"][why] = cur
         take_lens = getattr(batcher, "take_spec_accept_lens", None)
         if take_lens is not None:
             for n in take_lens():
@@ -653,11 +668,15 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
 
     def next_item(free: bool, draining: bool):
         """One read of the request queue.  With every slot busy the wait
-        is near zero (a control sweep); with nothing seated the replica
-        is waiting for requests, which is the ``idle`` phase."""
+        is near zero (a control sweep), and so it is while the batcher
+        holds a step queued ahead: the device has its work, and a wait
+        longer than that step would leave it idle after it.  With
+        nothing seated the replica is waiting for requests, which is the
+        ``idle`` phase."""
         seated = busy()
-        timeout = (busy_poll if seated
-                   else (0.05 if draining else idle_poll)) if free else 0.001
+        timeout = 0.001 if not free or getattr(
+            batcher, "step_queued", False) else (
+                busy_poll if seated else (0.05 if draining else idle_poll))
         if seated:
             return mgr.queue_get(REQUEST_QUEUE, timeout=timeout)
         with spans(_obs.SERVE_IDLE):

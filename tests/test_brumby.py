@@ -202,6 +202,44 @@ def test_a_parked_row_is_cleared_and_its_slot_reseated(made):
     assert first in b._results
 
 
+def test_a_row_parked_ahead_is_cleared_before_the_queued_step(made):
+    """A row that ends by budget is parked, its retention state zeroed,
+    BEHIND its last step and ahead of the step queued next, before either
+    is fetched; the request then prefilled behind that queued step starts
+    from its own state, and the row beside it, which the queued step ran,
+    stays where the reference is."""
+    cfg, params = made
+    b = ContinuousBatcher(cfg, params, max_batch=2)
+    p0, p1, p2 = _prompt(32, 9), _prompt(33, 6), _prompt(34, 11)
+    first = b.submit(p0, 3)
+    b.submit(p1, 14)
+    b.step()
+    cleared = []
+    park = b._park_slot
+    b._park_slot = lambda i: (park(i), cleared.append(float(jnp.abs(
+        b.cache["layer_0"]["ret"]["ret_state"][i]).max())))[0]
+    assert b.step() == [first]
+    # parked once, ahead of the fetch: zero there, with the next step queued
+    assert cleared == [0.0] and b.step_queued and b.slots[0] is None
+    third = b.submit(p2, 8)
+    b.step()
+    # ... which the prefill consumed; the next step, the new row in it,
+    # was dispatched as soon as its first token was on the host
+    assert b.slots[0].request_id == third and b.step_queued
+    assert b.decode_ahead_standdowns["admission"] == 1
+    assert b.state_rows_seated == 3
+    for _ in range(2):
+        got = _probe(b, params)
+        for i, p in ((0, p2), (1, p1)):
+            seq = np.concatenate([p, b.slots[i].tokens])
+            np.testing.assert_allclose(got[i], _ref_logits(params, seq)[-1],
+                                       atol=TOL)
+        b.step()
+    assert cleared == [0.0]
+    assert sum(b.decode_ahead_standdowns.values()) \
+        == b.decode_dispatches - b.decode_ahead_dispatches
+
+
 def test_chunked_admission_carries_the_state_beside_the_cache(made):
     cfg, params = made
     p = _prompt(40, 23)

@@ -192,15 +192,16 @@ def _serve(cfg, params, vocab, kwargs, schedule, steps, between=None,
     """Drive a batcher of 3 slots through ``schedule`` (step -> [(prompt
     id, prompt length, budget)]), calling ``between(b, step)`` after each
     ``step()``; returns it, the finished streams and the tokens each turn
-    emitted, in order."""
+    emitted, in order: (turn, request id, token)."""
     b = ContinuousBatcher(cfg, params, max_batch=3, **kwargs)
-    events, rids = [], {}
+    events, rids, turn = [], {}, [0]
     for step in range(steps):
+        turn[0] = step
         for i, n, budget in schedule.get(step, []):
             rids[i] = b.submit(
                 _prompt_of(i, n, vocab), budget, **submit,
-                on_token=lambda rid, tok, step=step: events.append(
-                    (step, rid, tok)))
+                on_token=lambda rid, tok: events.append(
+                    (turn[0], rid, tok)))
         b.step()
         if between is not None:
             between(b, step)
@@ -209,16 +210,28 @@ def _serve(cfg, params, vocab, kwargs, schedule, steps, between=None,
 
 #: three slots filled at once with unequal budgets, a fourth request that
 #: waits for the first to finish, so that steps with every slot seated
-#: (the only ones that may run ahead) alternate with finishes, a free
-#: slot, and an admission
+#: alternate with finishes by budget, a free slot, and an admission behind
+#: a queued step
 AHEAD_SCHEDULE = {0: [(0, 11, 9), (1, 5, 14), (2, 7, 20)], 3: [(3, 6, 8)]}
+
+
+def _streams(events):
+    """request id -> [(step of the ``step()`` that emitted it, token)]."""
+    out = {}
+    for step, rid, tok in events:
+        out.setdefault(rid, []).append((step, tok))
+    return out
 
 
 @pytest.mark.parametrize("model,mode", [("lfm2", "paged"), ("lfm2", "default"),
                                         ("dense-gpt", "paged")])
 def test_decode_ahead_serves_the_same_tokens_at_the_same_steps(
         made, model, mode):
-    """A default-built batcher runs ahead; its twin never does."""
+    """A default-built batcher runs ahead; its twin never does.  Every
+    request gets its twin's tokens; a row admitted behind a queued step
+    gets its first token in the ``step()`` of its admission, as its twin
+    does, and joins the decode at the next dispatch: none of its tokens
+    comes more than one ``step()`` after the twin's."""
     cfg, params = _toy(made, model)
     vocab = cfg.vocab_size
     with jax.default_matmul_precision("highest"):
@@ -230,56 +243,192 @@ def test_decode_ahead_serves_the_same_tokens_at_the_same_steps(
     assert all(v is not None for v in want.values())
     assert {i: v.tolist() for i, v in got.items()} \
         == {i: v.tolist() for i, v in want.items()}
-    assert ev_on == ev_off      # token for token, step for step
+    s_on, s_off = _streams(ev_on), _streams(ev_off)
+    assert s_on.keys() == s_off.keys()
+    late = 0
+    for rid, twin in s_off.items():
+        mine = s_on[rid]
+        assert [t for _, t in mine] == [t for _, t in twin]
+        assert mine[0][0] == twin[0][0], "first token: step of admission"
+        lags = [a - b for (a, _), (b, _) in zip(mine, twin)]
+        assert set(lags) <= {0, 1}, (rid, lags)
+        late += any(lags)
+    assert late == 1        # the one row admitted behind a queued step
     assert off.decode_ahead_dispatches == 0
-    # every slot was seated over steps 1..8 and 10..13 or so: most of
-    # those steps were queued ahead, and each was consumed
-    assert on.decode_ahead_dispatches >= 6
+    # all but the admission's step, the one after it and the last were
+    # queued ahead, and each was consumed
+    assert on.decode_ahead_dispatches >= on.decode_dispatches - 3
     assert on._ahead is None
     assert on.decode_dispatches == off.decode_dispatches
-    assert (on.expert_assignments, on.experts_touched) \
-        == (off.expert_assignments, off.experts_touched)
+    for b in (on, off):
+        assert sum(b.decode_ahead_standdowns.values()) \
+            == b.decode_dispatches - b.decode_ahead_dispatches
+    assert off.decode_ahead_standdowns["eos"] == off.decode_dispatches
+    assert on.decode_ahead_standdowns["admission"] == 1
+    assert on.decode_ahead_standdowns["idle"] == 1
+    # a step runs every row, seated or parked (what a parked row is fed,
+    # and so which experts it touches, is not the twin's)
+    assert on.expert_assignments == off.expert_assignments
 
 
 @pytest.mark.parametrize("why,kwargs,schedule,submit", [
-    ("an eos_id can end a row at any step", dict(eos_id=1),
-     AHEAD_SCHEDULE, {}),
-    ("a sampled row's step needs its host-side sampler state", {},
-     AHEAD_SCHEDULE, dict(temperature=0.7, seed=3)),
-    ("a chunked admission in flight takes its slot at an unknown step",
-     dict(prefill_chunk=4), {0: [(0, 11, 20), (1, 5, 20)],
-                             2: [(2, 30, 4)]}, {}),
-    ("a row at its last token leaves at this step", {},
-     {0: [(0, 11, 2), (1, 5, 2), (2, 7, 2)]}, {}),
+    ("eos", dict(eos_id=1), AHEAD_SCHEDULE, {}),
+    ("sampled", {}, AHEAD_SCHEDULE, dict(temperature=0.7, seed=3)),
+    ("chunked", dict(prefill_chunk=4), {0: [(0, 11, 20), (1, 5, 20)],
+                                        2: [(2, 30, 4)]}, {}),
+    ("idle", {}, {0: [(0, 11, 2), (1, 5, 2), (2, 7, 2)]}, {}),
 ])
 def test_decode_ahead_stands_down(made, why, kwargs, schedule, submit):
+    """An ``eos_id`` can end a row at any step; a sampled row's step needs
+    its host-side sampler state; a chunked admission in flight takes its
+    slot at a step of its own; and where every row is at its last token
+    there is no next step."""
     cfg, params = made
     seen = []
 
     def watch(b, step):
         # the chunked case: no step is queued while the admission streams
-        seen.append((b._inflight is not None, b._ahead is not None))
+        seen.append((b._inflight is not None and any(b.slots),
+                     b.step_queued))
 
     b, got, _ = _serve(cfg, params, 211, dict(MODES["paged"], **kwargs),
                        schedule, 30, between=watch, **submit)
     assert not any(inflight and ahead for inflight, ahead in seen), why
-    if "prefill_chunk" in kwargs:
+    if why == "chunked":
         assert any(inflight for inflight, _ in seen)
         ran = [ahead for inflight, ahead in seen if not inflight]
         assert b.decode_ahead_dispatches == sum(ran) > 0
+        assert b.decode_ahead_standdowns[why] \
+            == sum(inflight for inflight, _ in seen)
     else:
         assert b.decode_ahead_dispatches == 0, why
+        assert b.decode_ahead_standdowns[why] == b.decode_dispatches > 0
+    assert sum(b.decode_ahead_standdowns.values()) \
+        == b.decode_dispatches - b.decode_ahead_dispatches
     assert all(v is not None for v in got.values())
 
 
-def test_decode_ahead_waits_for_every_slot_to_be_seated(made):
-    """With a slot free a request may be admitted at the next step, so
-    that step is not dispatched before it is known."""
+def _same_tokens_as_the_twin(cfg, params, kwargs, schedule, steps=24,
+                             between=None):
+    """Serve ``schedule`` by a default-built batcher (``between`` as in
+    ``_serve``) and by its twin that never runs ahead; returns the first,
+    its tokens held to the twin's."""
+    vocab = cfg.vocab_size
+    with jax.default_matmul_precision("highest"):
+        _, want, _ = _serve(cfg, params, vocab, dict(kwargs, **NEVER),
+                            schedule, steps)
+        b, got, _ = _serve(cfg, params, vocab, kwargs, schedule, steps,
+                           between=between)
+    assert all(v is not None for v in want.values())
+    assert {i: v.tolist() for i, v in got.items()} \
+        == {i: v.tolist() for i, v in want.items()}
+    return b
+
+
+def test_decode_ahead_runs_with_a_slot_free(made):
+    """A step runs every row, seated or parked: with a slot free and
+    nothing admitted the next step's rows are decided all the same."""
     cfg, params = made
-    b, got, _ = _serve(cfg, params, 211, MODES["paged"],
-                       {0: [(0, 11, 9), (1, 5, 14)]}, 16)
-    assert b.decode_ahead_dispatches == 0
-    assert all(v is not None for v in got.values())
+    b = _same_tokens_as_the_twin(cfg, params, MODES["paged"],
+                                 {0: [(0, 11, 9), (1, 5, 14)]}, 16)
+    # every step but the last had the next one queued behind it
+    assert b.decode_ahead_dispatches == b.decode_dispatches - 1 == 12
+    assert b.decode_ahead_standdowns["idle"] == 1
+
+
+@pytest.mark.parametrize("model", ["lfm2", "dense-gpt"])
+def test_a_row_that_ends_by_budget_is_parked_ahead_and_once(made, model):
+    """Rows at their last token end at the step about to be fetched: they
+    are parked behind it and the next step is queued behind that, both
+    before the fetch; the finish does not park them again.  The last row
+    to end has no step to park ahead of."""
+    cfg, params = _toy(made, model)
+    order = []
+
+    def spy(b, _step):
+        if order:
+            return
+        park, fetch = b._park_slot, b._fetch
+        b._park_slot = lambda i: (order.append(("park", i)), park(i))[1]
+        b._fetch = lambda *a, **k: (order.append(("fetch",)),
+                                    fetch(*a, **k))[1]
+
+    b = _same_tokens_as_the_twin(
+        cfg, params, MODES["paged"],
+        {0: [(0, 11, 3), (1, 5, 3), (2, 7, 6)]}, 8, between=spy)
+    # step 1 makes the third tokens of rows 0 and 1: both parked, and the
+    # next step dispatched, before its fetch; row 2 parks at its finish
+    assert order[:3] == [("park", 0), ("park", 1), ("fetch",)]
+    assert [e for e in order if e[0] == "park"] \
+        == [("park", 0), ("park", 1), ("park", 2)]
+    assert order[-2:] == [("fetch",), ("park", 2)]
+    assert b.decode_ahead_dispatches == b.decode_dispatches - 1
+
+
+def _row_pages(b, request_id):
+    """The leased pages of the seated request, and their contents."""
+    s = next(s for s in b.slots if s and s.request_id == request_id)
+    return list(s.lease.page_ids), b._gather_pages(s.lease.page_ids)
+
+
+def test_pages_released_at_a_finish_hold_what_the_next_row_wrote():
+    """A pool of exactly three rows' pages: the request that waits is
+    leased the pages its predecessor released, while a step is queued
+    that ran the predecessor's row parked.  That step wrote none of them:
+    page for page they hold what the twin's hold."""
+    cfg, params = _dense(dtype=jnp.float32)
+    kwargs = dict(kv_page_tokens=4, kv_pool_pages=21, prefix_cache=False)
+    found = {}
+    with jax.default_matmul_precision("highest"):
+        for name, kw in (("on", kwargs), ("off", dict(kwargs, **NEVER))):
+            b = ContinuousBatcher(cfg, params, max_batch=3, **kw)
+            for i, n, budget in ((0, 11, 5), (1, 5, 20), (2, 7, 20)):
+                b.submit(_prompt_of(i, n, 50), budget)
+            last = b.submit(_prompt_of(3, 9, 50), 12)
+            while b.result(0) is None:
+                b.step()
+            assert b.step_queued == (name == "on")
+            b.step()        # leased what request 0 released
+            assert any(s and s.request_id == last for s in b.slots)
+            while len(next(s for s in b.slots if s
+                           and s.request_id == last).tokens) < 4:
+                b.step()
+            b.settle()
+            found[name] = b
+        on, off = found["on"], found["off"]
+        n = len(next(s for s in on.slots
+                     if s and s.request_id == last).tokens)
+        while len(next(s for s in off.slots
+                       if s and s.request_id == last).tokens) < n:
+            off.step()
+        ids_on, kv_on = _row_pages(on, last)
+        ids_off, kv_off = _row_pages(off, last)
+    assert ids_on == ids_off and len(ids_on) == 6
+    for a, b in zip(kv_on, kv_off):
+        np.testing.assert_array_equal(a, b)
+
+
+def test_a_slot_parked_ahead_is_reseated_from_zero_state(made):
+    """The conv state of a row that ended by budget is cleared between its
+    last step and the step queued behind it; the next request seated there
+    starts from ITS prefill, and the row beside it, which the queued step
+    ran, is where the reference is."""
+    cfg, params = made
+    with jax.default_matmul_precision("highest"):
+        b = ContinuousBatcher(cfg, params, max_batch=2, **MODES["paged"])
+        prompts = {b.submit(_prompt(0, 9), 3): _prompt(0, 9),
+                   b.submit(_prompt(1, 6), 14): _prompt(1, 6)}
+        b.step()
+        assert b.step() == [0]       # parked ahead of this fetch
+        assert b.step_queued and b.slots[0] is None
+        assert b.decode_ahead_standdowns == dict.fromkeys(
+            b.decode_ahead_standdowns, 0)
+        prompts[b.submit(_prompt(2, 7), 8)] = _prompt(2, 7)
+        b.step()                        # prefilled behind the queued step
+        assert b.slots[0] is not None and b.state_rows_seated == 3
+        assert _check_slots(b, params, prompts) == 2
+        b.step()
+        assert _check_slots(b, params, prompts) == 2
 
 
 @pytest.mark.parametrize("other", [dict(speculative_k=2),
@@ -299,43 +448,49 @@ def test_decode_ahead_stands_down_for_its_alternatives(other):
                                               budget))[0]
             assert got[i].tolist() == solo[n:].tolist(), (other, i)
     assert b.decode_ahead_dispatches == 0 and b._ahead is None
+    assert b.decode_ahead_standdowns["alternative"] \
+        == b.decode_dispatches > 0
 
 
 # -- settle(), and who may meet a queued step -------------------------------
 
-def _queued(cfg, params, kwargs):
-    """A batcher stopped between two turns with a step queued ahead."""
+def _queued(cfg, params, kwargs, seated=3):
+    """A batcher stopped between two turns with a step queued ahead,
+    ``seated`` of its three slots taken."""
     b = ContinuousBatcher(cfg, params, max_batch=3, **kwargs)
     prompts = {}
-    for i, n, budget in AHEAD_SCHEDULE[0]:
+    for i, n, budget in AHEAD_SCHEDULE[0][:seated]:
         prompts[b.submit(_prompt_of(i, n, cfg.vocab_size), budget)] \
             = _prompt_of(i, n, cfg.vocab_size)
     for _ in range(3):
         b.step()
-    assert b._ahead is not None
+    assert b.step_queued and sum(s is not None for s in b.slots) == seated
     return b, prompts
 
 
-def test_settle_makes_cache_and_slots_agree(made):
+@pytest.mark.parametrize("seated", [3, 2])
+def test_settle_makes_cache_and_slots_agree(made, seated):
     """After it the probe's logits match the reference at every seated
-    slot; it emits the queued step's tokens, dispatches nothing, and a
-    second call does nothing."""
+    slot, a slot free or not; it emits the queued step's tokens,
+    dispatches nothing, and a second call does nothing."""
     cfg, params = made
     with jax.default_matmul_precision("highest"):
-        b, prompts = _queued(cfg, params, MODES["paged"])
-        before = [len(s.tokens) for s in b.slots]
+        b, prompts = _queued(cfg, params, MODES["paged"], seated)
+        before = [len(s.tokens) for s in b.slots if s]
         counters = (b.decode_dispatches, b.decode_ahead_dispatches)
         assert b.settle() == []
-        assert b._ahead is None
-        assert [len(s.tokens) for s in b.slots] == [n + 1 for n in before]
+        assert b._ahead is None and b.decode_ahead_standdowns["settle"] == 1
+        assert [len(s.tokens) for s in b.slots if s] \
+            == [n + 1 for n in before]
         assert (b.decode_dispatches, b.decode_ahead_dispatches) \
             == (counters[0] + 1, counters[1])
-        assert _check_slots(b, params, prompts) == 3
+        assert _check_slots(b, params, prompts) == seated
         assert b.settle() == []
-        assert [len(s.tokens) for s in b.slots] == [n + 1 for n in before]
+        assert [len(s.tokens) for s in b.slots if s] \
+            == [n + 1 for n in before]
         assert b.decode_dispatches == counters[0] + 1
         # and the stream goes on where a twin that never settled is
-        twin, _ = _queued(cfg, params, MODES["paged"])
+        twin, _ = _queued(cfg, params, MODES["paged"], seated)
         assert {r: v.tolist() for r, v in b.run().items()} \
             == {r: v.tolist() for r, v in twin.run().items()}
 
@@ -345,10 +500,10 @@ def test_settle_returns_what_the_queued_step_finished():
     b = ContinuousBatcher(cfg, params, max_batch=2)
     short = b.submit(_prompt_of(0, 6, 50), 2)
     b.submit(_prompt_of(1, 9, 50), 9)
-    # both seated with their first tokens; short's second is its last, so
-    # the step that makes it had none queued behind it
+    # both seated with their first tokens; short's second is its last: it
+    # is parked behind the step that makes it, the next queued behind that
     assert b.step() == [short]
-    assert b._ahead is None and b.settle() == []
+    assert b.step_queued and b.settle() == []
     b = ContinuousBatcher(cfg, params, max_batch=2)
     short = b.submit(_prompt_of(0, 6, 50), 3)
     b.submit(_prompt_of(1, 9, 50), 9)
@@ -359,45 +514,90 @@ def test_settle_returns_what_the_queued_step_finished():
     assert b.slots[0] is None or b.slots[1] is None
 
 
-@pytest.mark.parametrize("reader", ["load_params", "park", "run",
-                                    "export_prefix_cache"])
-def test_a_queued_step_survives_its_readers(reader):
+def _session(cfg, params, kwargs, prompt, budget):
+    """A handoff session of ``prompt``, from a prefill-only batcher."""
+    p = ContinuousBatcher(cfg, params, max_batch=1, prefill_only=True,
+                          **kwargs)
+    p.submit(prompt, budget)
+    p.step()
+    return p.take_sessions()[0][1]
+
+
+@pytest.mark.parametrize("reader,seated", [
+    ("load_params", 3), ("run", 3), ("run", 2),
+    ("export_prefix_cache", 3), ("export_prefix_cache", 2),
+    ("import_prefix_cache", 2), ("adopt_session", 2), ("submit", 2),
+    ("unload_params", 2)])
+def test_a_queued_step_survives_its_readers(reader, seated):
     """Each path that reads or replaces the cache, the parameters or a
-    slot between two turns, where it can meet a queued step."""
+    slot between two turns, where it can meet a queued step, with every
+    slot seated or one free."""
     cfg, params = _dense()
     # load_params rebuilds the page index (it is for an idle batcher, which
     # has no step queued; unload_params refuses a busy one): called on a
     # busy one all the same, only a pool without an index survives it
     kwargs = dict(kv_page_tokens=4, prefix_cache=reader != "load_params")
     with jax.default_matmul_precision("highest"):
-        b, _ = _queued(cfg, params, kwargs)
-        twin, _ = _queued(cfg, params, kwargs)
+        b, _ = _queued(cfg, params, kwargs, seated)
+        # the twin meets the same reader with its queued step consumed
+        twin, _ = _queued(cfg, params, kwargs, seated)
+        twin.settle()
         queued = b._ahead
         if reader == "load_params":
             # the step in flight keeps the parameters it was dispatched
             # with and is not lost: consumed by the next turn as it lies
             b.load_params(jax.tree.map(np.asarray, params))
             assert b._ahead is queued
-        elif reader == "park":
-            # a slot is only ever parked by the turn that consumed the
-            # queued step: none is queued behind a row that ends
-            parked = []
-            park = b._park_slot
-            b._park_slot = lambda i: (parked.append(b._ahead), park(i))[1]
-            b.run()
-            assert len(parked) == 3 and all(a is None for a in parked)
+        elif reader == "unload_params":
+            # none is queued without a seated row that goes on, and a
+            # busy batcher refuses
+            with pytest.raises(RuntimeError, match="live requests"):
+                b.unload_params()
         elif reader == "export_prefix_cache":
             # indexed prompt pages are written by no decode step: the
             # snapshot is the same with the step queued or settled
             got = b.export_prefix_cache()
-            twin.settle()
             want = twin.export_prefix_cache()
             assert got["page_hashes"] == want["page_hashes"] != []
             assert b._ahead is queued
+        elif reader == "import_prefix_cache":
+            # a donated page set lands in free pages, behind the step
+            donor = ContinuousBatcher(cfg, params, max_batch=1, **kwargs)
+            donor.submit(_prompt_of(7, 13, 50), 2)
+            donor.run()
+            export = donor.export_prefix_cache()
+            assert b.import_prefix_cache(export) \
+                == twin.import_prefix_cache(export) == 3
+            assert b._ahead is queued
+            for x in (b, twin):     # and the next admission matches them
+                x.submit(_prompt_of(7, 13, 50), 5)
+        elif reader == "adopt_session":
+            # seated behind the queued step, into the free slot's row and
+            # freshly leased pages; it joins the decode at the next dispatch
+            session = _session(cfg, params, kwargs, _prompt_of(5, 10, 50), 7)
+            for x in (b, twin):
+                x.adopt_session(session)
+            b.step()
+            assert sum(s is not None for s in b.slots) == 3
+            assert b.decode_ahead_standdowns["admission"] == 1
+            assert b.step_queued        # dispatched from the host's tokens
+        elif reader == "submit":
+            # prefilled behind the queued step, which is consumed first
+            for x in (b, twin):
+                x.submit(_prompt_of(5, 10, 50), 7)
+            ahead = b.decode_ahead_dispatches
+            b.step()
+            assert sum(s is not None for s in b.slots) == 3
+            assert b.decode_ahead_standdowns["admission"] == 1
+            # the next step went out as soon as the first token was on
+            # the host: queued, though behind no running step
+            assert b.step_queued and b.decode_ahead_dispatches == ahead
         out = b.run()
         assert b._ahead is None and not any(b.slots)
         assert {r: v.tolist() for r, v in out.items()} \
             == {r: v.tolist() for r, v in twin.run().items()}
+        assert sum(b.decode_ahead_standdowns.values()) \
+            == b.decode_dispatches - b.decode_ahead_dispatches
 
 
 # -- the expert layer's kernel: the same requests, kernel against ragged_dot

@@ -23,7 +23,7 @@ from tensorflowonspark_tpu import metrics, observability as obs, tracing
 from tensorflowonspark_tpu.marker import EndOfFeed
 from tensorflowonspark_tpu.models import GPT, GPTConfig, ContinuousBatcher
 from tensorflowonspark_tpu.models import serving as serving_mod
-from tensorflowonspark_tpu.models.serving import DraftModel
+from tensorflowonspark_tpu.models.serving import STANDDOWNS, DraftModel
 from tensorflowonspark_tpu.serving import replica
 from tensorflowonspark_tpu.serving.scheduler import (REQUEST_QUEUE,
                                                      RESPONSE_QUEUE)
@@ -122,9 +122,13 @@ class _Mgr:
     def __init__(self):
         self.requests: queue.Queue = queue.Queue()
         self.responses: list = []
+        #: (timeout, what ``probe()`` said) of every read of the queue
+        self.sweeps: list = []
+        self.probe = lambda: None
 
     def queue_get(self, name, timeout=None):
         assert name == REQUEST_QUEUE
+        self.sweeps.append((timeout, self.probe()))
         return self.requests.get(timeout=timeout)
 
     def queue_put(self, name, item, timeout=None):
@@ -165,7 +169,9 @@ def _feeder(mgr: _Mgr, errors: list):
 
     try:
         for i in range(FAST_REQUESTS):
-            mgr.requests.put(_gen(i, _prompt(i, 5 + i), 18))
+            # unequal budgets: a row ends while the one beside it goes on,
+            # and the next request is admitted behind a queued step
+            mgr.requests.put(_gen(i, _prompt(i, 5 + i), 12 + 3 * i))
         wait_done(FAST_REQUESTS)
         mgr.requests.put({"op": "model", "event": "swap", "model": "toy",
                           "version": "slow", "swap_token": 1,
@@ -196,6 +202,9 @@ def served(tmp_path_factory):
             batcher.submit(_prompt(50 + i, 6), 3)
         batcher.run()
     ctx = _Ctx(workdir)
+    ctx.mgr.probe = lambda: {"free": batcher.has_free_slot(),
+                             "queued": batcher.step_queued,
+                             "seated": any(batcher.slots)}
     args = {"serve_model_builder": _toy_builder, "serve_idle_poll": 0.05}
     errors: list = []
     feeder = threading.Thread(target=_feeder, args=(ctx.mgr, errors),
@@ -208,11 +217,15 @@ def served(tmp_path_factory):
     def clocks():
         return {n: c.value() for n, c in phases.seconds.items()}
 
+    standdowns = reg.counter("tfos_replica_decode_ahead_standdowns_total",
+                             "", labelnames=("why",))
+
     def dispatches():
         return {"ahead": reg.counter(
                     "tfos_replica_decode_ahead_dispatches_total").value(),
                 "decode": reg.counter(
-                    "tfos_replica_decode_dispatches_total").value()}
+                    "tfos_replica_decode_dispatches_total").value(),
+                **{why: standdowns.value(why=why) for why in STANDDOWNS}}
 
     options = jax.profiler.ProfileOptions()
     options.python_tracer_level = 0     # as benchmark/child.start_trace
@@ -301,12 +314,38 @@ def test_phase_seconds_sum_to_the_loops_wall_time(served):
 
 def test_ahead_dispatches_are_published_beside_decode_dispatches(served):
     """``tfos_replica_decode_ahead_dispatches_total`` rises with the
-    batcher's attribute (two slots, six 18-token streams: most turns find
-    both seated) and is a share of the decode dispatches."""
+    batcher's attribute (two slots, six streams of 12 to 27 tokens: most
+    turns find the next step decided) and is a share of the decode
+    dispatches."""
     d, b = served["dispatches"], served["batcher"]
     assert d["ahead"] == b.decode_ahead_dispatches > 0
     assert d["decode"] == b.decode_dispatches
     assert len(served["ctx"].steps) / 2 < d["ahead"] <= d["decode"]
+
+
+def test_standdowns_are_published_and_sum_to_the_steps_not_run_ahead(served):
+    """``tfos_replica_decode_ahead_standdowns_total{why}``: every decode
+    dispatch either had the next step dispatched behind it or says what
+    stood in the way.  Here: the turn that admits a request behind a
+    queued step, and the last step of a stream with no row beside it."""
+    d, b = served["dispatches"], served["batcher"]
+    assert {why: d[why] for why in STANDDOWNS} == b.decode_ahead_standdowns
+    assert sum(d[why] for why in STANDDOWNS) == d["decode"] - d["ahead"]
+    assert d["admission"] >= FAST_REQUESTS - 2 and d["idle"] >= 2
+    assert d["eos"] == d["sampled"] == d["chunked"] == d["alternative"] == 0
+
+
+def test_a_sweep_does_not_wait_for_a_request_past_a_queued_step(served):
+    """While the batcher holds a step queued ahead the device has its
+    work: the sweep with a slot free then waits no longer than the sweep
+    with none free, whatever ``serve_busy_poll`` is; with nothing queued
+    ``serve_busy_poll`` keeps its meaning."""
+    sweeps = [(t, p) for t, p in served["ctx"].mgr.sweeps if p["seated"]]
+    full = {t for t, p in sweeps if not p["free"]}
+    queued = {t for t, p in sweeps if p["free"] and p["queued"]}
+    plain = {t for t, p in sweeps if p["free"] and not p["queued"]}
+    assert full == queued == {0.001}
+    assert plain <= {0.005}      # the default ``serve_busy_poll``
 
 
 def test_kernel_calls_are_published_and_a_dense_model_moves_none(served):

@@ -146,7 +146,7 @@ def token_gaps(requests: list[dict], t0: float, t1: float) -> list[float]:
 def gap_modes(gaps: list[float], width: float = 0.05) -> list[dict]:
     """The modes of a pooled gap distribution: clusters of sorted values in
     which each lies within ``width`` of the cluster's first.  For the
-    check that the 95th percentile sits inside a mode and not on an edge."""
+    run's account of where its gaps lie (the ``serve window`` fact)."""
     modes: list[list[float]] = []
     for g in sorted(gaps):
         if modes and g <= modes[-1][0] * (1.0 + width):
@@ -158,6 +158,23 @@ def gap_modes(gaps: list[float], width: float = 0.05) -> list[dict]:
              "share": len(m) / n} for m in modes]
 
 
+def tail_in_mode(gaps: list[float], q: float, around: float = 0.5,
+                 width: float = 0.05) -> dict:
+    """Whether the ``q``-th percentile of the pooled gaps lies inside a
+    mode: the percentiles ``around`` points below and above it lie within
+    ``width`` of it (a mode's width in ``gap_modes``), so that a few gaps
+    more or fewer in the tail move the reading by less than that and not
+    along the slope between two modes (where a 95th percentile spread 2.4
+    .. 12.9 % over five runs of one tree; my chip runs, PR 37)."""
+    below, at, above = (nearest_rank(gaps, p)
+                        for p in (q - around, q, q + around))
+    return {"percentile": q, "inside_a_mode": above - below <= width * at,
+            "ms": {str(q - around): 1e3 * below, str(q): 1e3 * at,
+                   str(q + around): 1e3 * above},
+            "span_share": (above - below) / at,
+            "beyond": sum(1 for g in gaps if g > at)}
+
+
 # ---------------------------------------------------------------- idle share
 
 #: points by which the trace session's idle share may differ from the
@@ -165,30 +182,55 @@ def gap_modes(gaps: list[float], width: float = 0.05) -> list[dict]:
 IDLE_AGREE_POINTS = 5.0
 
 
-def idle_share(reduced: dict, device_s_per_step: float, steps: int,
-               window_s: float) -> dict:
+def device_seconds(reduced: dict, dispatched: dict, steps: float) -> dict:
+    """The device seconds of a measured window, from a traced session and
+    the window's own counts: for every group of programs in ``dispatched``
+    (``{(program names): runs in the window}``) the group's mean run in the
+    session times its runs in the window, and every other program of the
+    session at its seconds per traced step times the window's ``steps``.
+    Each program is counted by its own runs, so a session that holds more
+    prefills than the window's share (or none) no longer tilts the figure
+    (it read -2.96 % idle in one cell and 12.2 % against a traced 0.9 % in
+    another while only the main program's time x steps was counted; my
+    chip runs, PRs 32 and 37).  A group that ran in the window and not in
+    the session is named under ``missing`` and counts nothing."""
+    total, missing, counted = 0.0, [], set()
+    for names, runs in dispatched.items():
+        ran = [reduced["programs"][n] for n in names
+               if n in reduced["programs"]]
+        counted.update(names)
+        n = sum(p["runs"] for p in ran)
+        if n:
+            total += sum(p["seconds"] for p in ran) / n * runs
+        elif runs:
+            missing.append(names[0])
+    rest = sum(p["seconds"] for name, p in reduced["programs"].items()
+               if name not in counted)
+    total += rest / reduced["steps"] * steps
+    return {"seconds": total, "missing": missing}
+
+
+def idle_share(reduced: dict, device_s: float, window_s: float) -> dict:
     """The device's idle share of a traced run, from two sides: 1 - busy /
-    window of the profiler's session (``reduced``), and 1 - (device time
-    per step x the measured window's steps) / the measured window.  Where
+    window of the profiler's session (``reduced``), and 1 - ``device_s`` /
+    ``window_s`` of the run's measured window (``device_seconds``).  Where
     they agree the session's own figures stand.  Where they differ by more
     than ``IDLE_AGREE_POINTS`` the session did not see what the window saw:
     it sat between two stalls, or the profiler held the device back (on
     this installation some sessions leave the device idle 0.2 .. 1.2 s
     between ResNet-50 steps and read 64 .. 91 % idle, others of the same
     process read 0.015 %; my chip runs, PR 24, PERF.md section 6).  The run
-    then reports the window's figures, says so, and gives no idle gaps
-    from that session."""
+    then reports the window's figures and says so."""
     from_trace = 100.0 * (1.0 - reduced["busy_s"] / reduced["window_s"])
-    busy = device_s_per_step * steps
-    from_window = 100.0 * (1.0 - busy / window_s)
+    from_window = 100.0 * (1.0 - device_s / window_s)
     sound = abs(from_trace - from_window) <= IDLE_AGREE_POINTS
     out = {"from_trace": from_trace, "from_window": from_window,
            "traced_steps": reduced["steps"], "differ": not sound,
            "value": from_trace if sound else from_window,
-           "busy_s": reduced["busy_s"] if sound else busy,
+           "busy_s": reduced["busy_s"] if sound else device_s,
            "window_s": reduced["window_s"] if sound else window_s}
     say("idle share cross-check", reported="trace session" if sound
-        else "measured window", **out)
+        else "measured window", session_set_aside=not sound, **out)
     return out
 
 
@@ -208,9 +250,11 @@ def memory_peak_bytes(stats_per_device: list[dict]) -> int:
 
 
 def result_line(*, correct: bool, attempted: int, failed: int,
-                metrics: dict, device: dict, breakdown: dict | None = None
-                ) -> str:
-    """The last line of a run: exactly the contract's keys."""
+                metrics: dict, device: dict, breakdown: dict | None = None,
+                compared: list | None = None, **facts) -> str:
+    """The last line of a run: the contract's keys, then any ``facts`` (the
+    contract: the driver ignores any other key), and last ``compared``: every
+    number ``correct`` was decided from, beside its limit."""
     out = {"correct": bool(correct), "attempted": int(attempted),
            "failed": int(failed),
            "metrics": {k: {"value": float(v["value"]), "unit": v["unit"]}
@@ -218,12 +262,40 @@ def result_line(*, correct: bool, attempted: int, failed: int,
            "device": device}
     if breakdown is not None:
         out["breakdown"] = breakdown
+    out.update(facts)
+    out["compared"] = {r["name"]: {"value": r["value"], "limit": r["limit"]}
+                       for r in compared or ()}
     return json.dumps(out)
 
 
 def manifest() -> dict:
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
         return json.load(f)
+
+
+def declared_for(section: str, cell: str | None) -> list[dict]:
+    """The entries of the manifest's ``section`` that are ``cell``'s: those
+    without a ``workloads`` key, and those that list it.  (A dense model's
+    roofline reader finds numbers in an expert model's run too; they
+    describe no program that ran, and the manifest does not list that cell
+    for it.)  A cell the manifest does not hold, a rehearsal at toy size,
+    gets every entry."""
+    declared = manifest()
+    if cell not in {w["name"] for w in declared["workloads"]}:
+        return declared[section]
+    return [m for m in declared[section]
+            if cell in m.get("workloads", (cell,))]
+
+
+def reported_as(values: dict, cell: dict) -> dict:
+    """``values`` under the names ``cell`` reports them by: its file's
+    ``metric_names`` (``{the harness's name: the manifest's}``) renames
+    what it lists.  A cell whose runs spread more widely than the others'
+    reports the same quantities under names of its own, with bounds of
+    their own, and does not widen the bounds the steadier cells stand on
+    (PERF.md section 2)."""
+    names = cell.get("metric_names", {})
+    return {names.get(k, k): v for k, v in values.items()}
 
 
 def pick_metrics(values: dict, declared: list[dict]) -> dict:
@@ -236,14 +308,33 @@ def pick_metrics(values: dict, declared: list[dict]) -> dict:
     return out
 
 
+def reader_of(name: str, cell: dict):
+    """The reader of the per-layer metric ``cell`` reports as ``name``:
+    ``layer_metrics/<name>.py``, or, for a name the cell's ``metric_names``
+    gives a metric, the reader of the name it stands for.  None where
+    there is no such file: a rehearsal cell, which gets every entry, has
+    no reader for another cell's own names."""
+    for harness_name, own in cell.get("metric_names", {}).items():
+        if own == name:
+            name = harness_name
+    if not os.path.exists(os.path.join(BENCH, "layer_metrics", f"{name}.py")):
+        return None
+    return load_module("layer_metrics", name)
+
+
 def layer_metrics(run: dict) -> dict:
-    """Every per-layer metric the manifest declares whose reader
-    (``layer_metrics/<name>.py``) finds something to read in ``run``."""
+    """Every per-layer metric the manifest declares for the run's cell
+    whose reader (``reader_of``) finds something to read in ``run``."""
+    cell = run["cell"]
+    listed = cell.get("name") in {w["name"] for w in manifest()["workloads"]}
     values = {}
-    for m in manifest()["per_layer"]:
-        reader = load_module("layer_metrics", m["name"])
+    for m in declared_for("per_layer", cell.get("name")):
+        reader = reader_of(m["name"], cell)
+        if reader is None:
+            if listed:
+                raise ValueError(f"no reader for {m['name']!r}")
+            continue
         value = reader.read(run)
         if value is not None:
             values[m["name"]] = float(value)
     return values
-

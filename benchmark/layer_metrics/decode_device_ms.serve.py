@@ -6,8 +6,7 @@ program holds the device longest, so it keeps its meaning when the decode
 step gets fast and a prefill overtakes it.  A program that gives no such
 name (before PR 25) reads nothing."""
 
-DECODE = ("jit_tfos_decode", "jit_tfos_decode_sampled",
-          "jit_tfos_decode_block")
+from benchmark.trace import DECODE_PROGRAMS as DECODE
 
 
 def read(run):

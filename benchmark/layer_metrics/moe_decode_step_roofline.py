@@ -33,7 +33,9 @@ def decode_experts_touched(run):
     return max(touched, 0.0) / decodes
 
 
-def read(run):
+def step_work(run):
+    """``(work, seconds a run, facts)`` of the decode step, or None where
+    there is nothing to read (``step_mfu.serve`` reads the same)."""
     trace = run.get("trace")
     if run["kind"] != "serve-closed" or not trace \
             or not run.get("mean_context_tokens"):
@@ -47,10 +49,19 @@ def read(run):
         / c["tfos_replica_decode_dispatches_total"]
     work = shapes_lfm2.decode_step(run["cell"]["config_data"], rows,
                                    rows * run["mean_context_tokens"], touched)
-    seconds = program["seconds"] / program["runs"]
+    return work, program["seconds"] / program["runs"], {
+        "program": PROGRAM, "rows": rows,
+        "experts_touched_per_step": touched}
+
+
+def read(run):
+    found = step_work(run)
+    if found is None:
+        return None
+    work, seconds, facts = found
     roof = shapes.roofline(work, harness.peaks_for(run["device"]["kind"]),
                            seconds)
-    by_scope = (trace.get("scopes") or {}).get(PROGRAM)
+    by_scope = (run["trace"].get("scopes") or {}).get(PROGRAM)
     if by_scope:            # the run's account of where the step's time went
         harness.say("decode device time by scope", program=PROGRAM,
                     runs=by_scope["runs"], ms_per_run={
@@ -59,6 +70,5 @@ def read(run):
                                key=lambda kv: -kv[1])},
                     program_ms=1e3 * by_scope["seconds"] / by_scope["runs"])
     harness.say("roofline", metric="moe_decode_step_roofline",
-                program=PROGRAM, rows=rows, experts_touched_per_step=touched,
-                device_ms=1e3 * seconds, **roof)
+                device_ms=1e3 * seconds, **facts, **roof)
     return roof["share"]

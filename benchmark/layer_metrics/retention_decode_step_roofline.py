@@ -11,7 +11,9 @@ from benchmark import harness, shapes, shapes_brumby
 PROGRAM = "jit_tfos_decode"
 
 
-def read(run):
+def step_work(run):
+    """``(work, seconds a run, facts)`` of the decode step, or None where
+    there is nothing to read (``step_mfu.serve`` reads the same)."""
     trace = run.get("trace")
     if run["kind"] != "serve-closed" or not trace:
         return None
@@ -21,9 +23,17 @@ def read(run):
     if rows is None or not program or not program["runs"]:
         return None
     work = shapes_brumby.decode_step(run["cell"]["config_data"], rows)
-    seconds = program["seconds"] / program["runs"]
+    return work, program["seconds"] / program["runs"], {
+        "program": PROGRAM, "rows": rows}
+
+
+def read(run):
+    found = step_work(run)
+    if found is None:
+        return None
+    work, seconds, facts = found
     roof = shapes.roofline(work, harness.peaks_for(run["device"]["kind"]),
                            seconds)
     harness.say("roofline", metric="retention_decode_step_roofline",
-                program=PROGRAM, rows=rows, device_ms=1e3 * seconds, **roof)
+                device_ms=1e3 * seconds, **facts, **roof)
     return roof["share"]

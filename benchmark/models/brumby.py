@@ -31,10 +31,10 @@ lfm2 = harness.load_module("models", "lfm2")
 Asker = gpt2.Asker
 verify_worker = gpt2.verify_worker
 
-#: the counters read beside ``lfm2.Observer``'s (which has the rows seated
-#: and the phase clocks): the state's bytes, and the steps queued ahead
-ENGINE_COUNTERS = ("tfos_replica_state_bytes_moved_total",
-                   "tfos_replica_decode_ahead_dispatches_total")
+#: the counter read beside ``lfm2.Observer``'s (which has the rows
+#: seated, and from ``gpt2.Observer`` the phase clocks and the steps queued
+#: ahead): the state's bytes
+ENGINE_COUNTERS = ("tfos_replica_state_bytes_moved_total",)
 
 
 def gpt_config(cfg: dict):

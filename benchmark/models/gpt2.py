@@ -20,11 +20,14 @@ import time
 
 from benchmark import child, harness
 
-#: the program's counters the serve metrics read (``serving/replica.py``)
+#: the program's counters the serve metrics read (``serving/replica.py``);
+#: the last, the decode steps dispatched behind a running one, is read by
+#: no metric and printed with the others in the ``serve window`` fact
 COUNTERS = ("tfos_replica_steps_total", "tfos_replica_tokens_total",
             "tfos_replica_decode_dispatches_total",
             "tfos_replica_prefill_dispatches_total",
-            "tfos_replica_requests_total")
+            "tfos_replica_requests_total",
+            "tfos_replica_decode_ahead_dispatches_total")
 
 
 def gpt_config(cfg: dict):
@@ -74,7 +77,9 @@ class Observer(threading.Thread):
     every ``POLL_S`` to see whether the traced steps are done.  Questions:
     ``snapshot`` (time, counters, compile log, memory),
     ``trace_start`` (stops by itself after ``steps`` runs of the serving
-    loop or ``max_s`` seconds), ``trace_result`` (the reduced trace)."""
+    loop or ``max_s`` seconds; the runner starts it where a request is
+    near its end, so that the session holds an admission),
+    ``trace_result`` (the reduced trace)."""
 
     POLL_S = 0.01
 
@@ -90,7 +95,17 @@ class Observer(threading.Thread):
         from tensorflowonspark_tpu import metrics
 
         reg = metrics.get_registry()
-        return {name: float(reg.counter(name).value()) for name in COUNTERS}
+        out = {name: float(reg.counter(name).value()) for name in COUNTERS}
+        # the loop thread's phase clocks (docs/observability.md), as
+        # ``phase_seconds.<phase>``: their window deltas are printed in the
+        # run's "serve window" fact, say which phase of a turn a slow run
+        # lost its time in, and ``host_turn_ms.serve`` reads them
+        from tensorflowonspark_tpu import observability
+
+        out.update({f"phase_seconds.{name.rsplit('/', 1)[1]}":
+                    float(observability.phase_seconds(name).value())
+                    for name in observability.REPLICA_PHASES})
+        return out
 
     def snapshot(self) -> dict:
         return {"t": time.monotonic(), "t_child": self.t_child,
@@ -106,8 +121,7 @@ class Observer(threading.Thread):
             return self.snapshot()
         if op == "trace_start":
             child.start_trace(self.trace_dir)
-            steps = self.counters()["tfos_replica_steps_total"]
-            self.tracing_until = (steps + ask["steps"],
+            self.tracing_until = (self.steps() + ask["steps"],
                                   time.monotonic() + ask["max_s"])
             return {"t": time.monotonic()}
         if op == "trace_result":
@@ -119,14 +133,19 @@ class Observer(threading.Thread):
             return {"trace": trace.reduce_dir(self.trace_dir)}
         raise ValueError(f"unknown question {op!r}")
 
+    def steps(self) -> float:
+        from tensorflowonspark_tpu import metrics
+
+        return float(metrics.get_registry().counter(
+            "tfos_replica_steps_total").value())
+
     def _stop_trace(self, force: bool = False) -> None:
         import jax
 
         if self.tracing_until is None:
             return
         steps, deadline = self.tracing_until
-        if force or time.monotonic() >= deadline or \
-                self.counters()["tfos_replica_steps_total"] >= steps:
+        if force or time.monotonic() >= deadline or self.steps() >= steps:
             jax.profiler.stop_trace()
             self.tracing_until, self.traced = None, True
 

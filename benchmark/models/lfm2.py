@@ -63,8 +63,9 @@ def gpt_config(cfg: dict):
 
 
 class Observer(gpt2.Observer):
-    """``gpt2.Observer`` that also reads the engine counters and joins the
-    device seconds by named scope to the reduced trace."""
+    """``gpt2.Observer`` (the five counters, the steps queued ahead, the
+    phase clocks) that also reads the engine counters and joins the device
+    seconds by named scope to the reduced trace."""
 
     def counters(self) -> dict:
         from tensorflowonspark_tpu import metrics
@@ -73,15 +74,6 @@ class Observer(gpt2.Observer):
         reg = metrics.get_registry()
         out.update({name: float(reg.counter(name).value())
                     for name in ENGINE_COUNTERS})
-        # the loop thread's phase clocks (docs/observability.md): their
-        # window deltas are printed with the counters' in the run's "serve
-        # window" fact and say which phase of a turn a slow run lost its
-        # time in; no metric reads them
-        from tensorflowonspark_tpu import observability
-
-        out.update({f"phase_seconds.{name.rsplit('/', 1)[1]}":
-                    float(observability.phase_seconds(name).value())
-                    for name in observability.REPLICA_PHASES})
         return out
 
     def answer(self, ask: dict) -> dict:

@@ -53,25 +53,36 @@ def result_of(run: dict, trace: int) -> str:
     """The result line of a runner's account: with ``--trace 0`` the
     cell's end-to-end metrics, with ``--trace 1`` its per-layer metrics,
     the device's busy time and window, and the breakdown."""
-    declared = harness.manifest()
     device = dict(run["device"])
-    breakdown = None
+    breakdown, facts = None, {}
     if trace:
-        values = harness.layer_metrics(run)
-        metrics = harness.pick_metrics(values, declared["per_layer"])
+        metrics = harness.pick_metrics(
+            harness.layer_metrics(run), harness.manifest()["per_layer"])
         reduced, idle = run.get("trace"), run.get("idle")
         if reduced and idle:
             device["busy_s"] = idle["busy_s"]
             device["window_s"] = idle["window_s"]
+            # a session set aside for its idle share (``harness.idle_share``)
+            # still says which of the serving loop's spans its gaps fell
+            # under; a train session the profiler held back says nothing
+            # of the window's gaps, and gives none
+            keep = not idle["differ"] or run["kind"] == "serve-closed"
             breakdown = {"device_ops": reduced["device_ops"],
-                         "idle_gaps": [] if idle["differ"]
-                         else reduced["idle_gaps"]}
+                         "idle_gaps": reduced["idle_gaps"] if keep else []}
+            if idle["differ"]:
+                facts["session_set_aside"] = True
     else:
-        metrics = harness.pick_metrics(run["values"], declared["end_to_end"])
+        metrics = harness.pick_metrics(
+            harness.reported_as(run["values"], run["cell"]),
+            harness.declared_for("end_to_end", run["cell"].get("name")))
+    compared = run.get("compared") or []
+    for row in compared:      # the run's last lines on standard error
+        print(f"compared {row['name']} {row['value']!r} limit "
+              f"{row['limit']!r}", file=sys.stderr, flush=True)
     return harness.result_line(
         correct=run["correct"], attempted=run["attempted"],
         failed=run["failed"], metrics=metrics, device=device,
-        breakdown=breakdown)
+        breakdown=breakdown, compared=compared, **facts)
 
 
 def main(argv=None) -> int:

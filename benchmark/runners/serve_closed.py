@@ -34,6 +34,7 @@ import time
 import numpy as np
 
 from benchmark import harness, traffic_gen
+from benchmark.trace import DECODE_PROGRAMS, PREFILL_PROGRAMS
 
 #: streams the reference scores after a run (one batched forward)
 SAMPLE = 4
@@ -149,6 +150,19 @@ def _between_steps(arrivals: list, after: float, half_step: float) -> None:
     time.sleep(max(0.0, hit + half_step - time.monotonic()))
 
 
+def _until_a_finish_is_near(callers: list, within: int, deadline: float
+                            ) -> None:
+    """Sleep until some caller's request has at most ``within`` tokens to
+    go, or ``deadline``."""
+    def near() -> bool:
+        return any(c.requests and c.requests[-1]["done"] is None and
+                   c.requests[-1]["budget"] - len(c.requests[-1]["tokens"])
+                   <= within for c in callers)
+
+    while not near() and time.monotonic() < deadline:
+        time.sleep(0.005)
+
+
 def _serve(cell: dict, opts: dict) -> dict:
     """One life of the tier: boot, warm up, window, drain, shut down."""
     from tensorflowonspark_tpu.serving import ServingCluster
@@ -210,8 +224,14 @@ def _serve(cell: dict, opts: dict) -> dict:
         s0 = asker.ask("snapshot")
         t0 = s0["t"]
         if opts["trace"]:
+            # the callers started together, so their admissions come in
+            # waves a request's length apart: the session starts where a
+            # finish, and with it an admission, is near
+            _until_a_finish_is_near(callers, int(traffic["trace_steps"]) // 2,
+                                    t0 + 0.5 * opts["seconds"])
             asker.ask("trace_start", steps=int(traffic["trace_steps"]),
-                      max_s=max(5.0, opts["seconds"] - 2.0))
+                      max_s=max(5.0, t0 + opts["seconds"] - 2.0
+                                - time.monotonic()))
         time.sleep(max(0.0, t0 + opts["seconds"] - time.monotonic()))
         _between_steps(arrivals, t0 + opts["seconds"], half_step)
         s1 = asker.ask("snapshot")
@@ -258,6 +278,14 @@ def _verify(cell: dict, opts: dict, items: list) -> dict:
             return json.load(f)
     finally:
         shutil.rmtree(workdir, ignore_errors=True)
+
+
+def _pairs(requests: list[dict], client: int):
+    """Caller ``client``'s consecutive requests, the first of each pair
+    completed."""
+    own = sorted((r for r in requests if r["client"] == client),
+                 key=lambda r: r["k"])
+    return [(a, b) for a, b in zip(own, own[1:]) if a["done"]]
 
 
 def _sample(finished: list[dict], seed: int) -> list[dict]:
@@ -307,6 +335,21 @@ def run(cell: dict, opts: dict, started: float) -> dict:
                 for k in out["s0"]["counters"]}
     quantiles = {str(q): 1e3 * harness.nearest_rank(gaps, q)
                  for q in (50, 75, 90, 92, 95, 98, 99)} if gaps else {}
+    steps = counters["tfos_replica_steps_total"]
+    # how late the generator ran: a closed-loop caller's own time from one
+    # request's end to the next one's send
+    turnaround = [1e3 * (b["sent"] - a["done"]) for c in range(
+        int(traffic["clients"])) for a, b in _pairs(requests, c)
+        if t0 <= b["sent"] < t1]
+    # the loop thread's phase clocks, where the observer reads them
+    phases = {k.split(".", 1)[1]: 1e3 * v / steps
+              for k, v in counters.items()
+              if k.startswith("phase_seconds.") and steps}
+    # the context a served token saw, and with the rows of a step what the
+    # pool held live: a pool's reserve and a start-up peak are not that
+    live = [len(r["prompt"]) + i for r in requests
+            for i, t in enumerate(r["recv"]) if t0 <= t < t1]
+    decodes = counters["tfos_replica_decode_dispatches_total"]
     longest = sorted(((b - a, b - t0, r["client"]) for r in requests
                       for a, b in zip(r["recv"], r["recv"][1:])
                       if t0 <= b < t1), reverse=True)[:4]
@@ -320,9 +363,26 @@ def run(cell: dict, opts: dict, started: float) -> dict:
                 gap_modes=sorted(harness.gap_modes(gaps),
                                  key=lambda m: -m["share"])[:8],
                 first_tokens=len(ttft), warm=out["warm"]["groups_per_burst"],
+                turns_per_s=steps / window_s,
+                live_context_tokens=statistics.fmean(live) * counters[
+                    "tfos_replica_tokens_total"] / decodes
+                if live and decodes else None,
+                caller_turnaround_ms={
+                    "n": len(turnaround),
+                    "p50": harness.nearest_rank(turnaround, 50),
+                    "p95": harness.nearest_rank(turnaround, 95)}
+                if turnaround else {},
+                phase_ms_per_step=phases,
+                host_turn_ms=harness.load_module(
+                    "layer_metrics", "host_turn_ms.serve").read(
+                        {"kind": "serve-closed", "counters": counters}),
                 counters=counters,
                 memory_stats=out["s2"]["memory_stats"][0]
                 if out["s2"]["memory_stats"] else {})
+
+    if gaps:
+        harness.say("tail", metric="gap_p99_ms",
+                    **harness.tail_in_mode(gaps, 99))
 
     # ---- the plain reference, after the tier has freed the chip
     picked = _sample(finished, opts["seed"])
@@ -349,20 +409,22 @@ def run(cell: dict, opts: dict, started: float) -> dict:
     checks.add("compiles_in_window", compiles, 0)
 
     values = {"tokens_per_s": tokens_in_window / window_s,
-              "gap_p95_ms": 1e3 * harness.nearest_rank(gaps, 95)
-              if gaps else None,
+              "gap_p99_ms": quantiles.get("99"),
               "setup_s": t0 - started}
-    live = [len(r["prompt"]) + i for r in requests
-            for i, t in enumerate(r["recv"]) if t0 <= t < t1]
     device = dict(out["device"], memory_peak_bytes=harness.memory_peak_bytes(
         out["s2"]["memory_stats"]))
-    steps = counters["tfos_replica_steps_total"]
     reduced, idle = out["trace"], None
     if reduced:
-        per_step = sum(p["seconds"] for p in reduced["programs"].values()) \
-            / reduced["steps"]
-        idle = harness.idle_share(reduced, per_step, steps, window_s)
-    return {"correct": checks.correct, "attempted": len(touched),
+        busy = harness.device_seconds(reduced, {
+            DECODE_PROGRAMS: counters["tfos_replica_decode_dispatches_total"],
+            PREFILL_PROGRAMS: counters[
+                "tfos_replica_prefill_dispatches_total"]}, steps)
+        idle = harness.idle_share(reduced, busy["seconds"], window_s)
+        if busy["missing"]:
+            harness.say("programs the window ran and the session did not",
+                        programs=busy["missing"])
+    return {"correct": checks.correct, "compared": checks.rows,
+            "attempted": len(touched),
             "failed": failed, "values": values, "device": device,
             "kind": "serve-closed", "cell": cell, "window_s": window_s,
             "steps": steps, "counters": counters, "trace": reduced,

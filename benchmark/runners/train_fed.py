@@ -291,11 +291,12 @@ def run(cell: dict, opts: dict, started: float) -> dict:
                   memory_peak_bytes(report["memory_stats"]))
     reduced, idle = report.get("trace"), None
     if reduced:
-        program = reduced["programs"][reduced["main_program"]]
-        idle = harness.idle_share(
-            reduced, program["seconds"] / program["runs"], report["steps"],
-            window_s)
-    return {"correct": checks.correct, "attempted": report["steps"],
+        device_s = harness.device_seconds(
+            reduced, {(reduced["main_program"],): report["steps"]},
+            report["steps"])["seconds"]
+        idle = harness.idle_share(reduced, device_s, window_s)
+    return {"correct": checks.correct, "compared": checks.rows,
+            "attempted": report["steps"],
             "failed": 0, "values": values, "device": device,
             "kind": "train-fed", "cell": cell, "report": report,
             "window_s": window_s, "steps": report["steps"],

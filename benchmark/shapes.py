@@ -74,9 +74,12 @@ def gpt_decode_step(cfg: dict, rows: float, live_tokens: float,
 def roofline(work: dict, peaks: dict, seconds: float) -> dict:
     """Share of the roofline: the least time the chip could take (the
     larger of operations over peak FLOP/s and bytes over peak bytes/s)
-    over the time it took, in per cent, and which of the two bounds."""
+    over the time it took, in per cent, and which of the two bounds; and
+    ``mfu``, the operations' side alone: their share of the peak FLOP/s
+    over that time, whichever side bounds."""
     t_flops = work["flops"] / peaks["bf16_flops_per_s"]
     t_bytes = work["bytes"] / peaks["hbm_bytes_per_s"]
     return {"share": 100.0 * max(t_flops, t_bytes) / seconds,
             "bound": "compute" if t_flops >= t_bytes else "memory",
-            "least_s": max(t_flops, t_bytes)}
+            "least_s": max(t_flops, t_bytes),
+            "mfu": 100.0 * t_flops / seconds}

@@ -9,12 +9,16 @@ Modules`` (one event per run of a compiled program, named
 by its HLO text).  Host threads are other planes; the benchmark's own spans
 there are ``TraceAnnotation`` events named ``bench/<span>``.
 
-The window is cut at the starts of runs of the *main* program (the one
-that holds the device longest): from the start of its first run in the
-trace to the start of a later one, so that whatever the host does between
-two runs is inside the window and an idle share cannot be read from between
-two stalls.  With a ``period`` the window is a whole number of periods of
-that many runs.
+The window is cut at the starts of runs of the *main* program: from the
+start of its first run in the trace to the start of a later one, so that
+whatever the host does between two runs is inside the window and an idle
+share cannot be read from between two stalls.  The main program is chosen
+BY NAME, the step program of the loop (``MAIN_PROGRAMS``, the names
+``models/serving.py::PROGRAM_NAMES`` and the train step give), so that a
+long prefill (121 ms against a 26 ms decode step in one cell) cannot
+overtake it; only a trace that holds none of those names falls back to the
+program that holds the device longest.  With a ``period`` the window is a
+whole number of periods of that many runs.
 """
 
 from __future__ import annotations
@@ -29,6 +33,13 @@ DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 #: sequencing, not the host's doing
 MIN_GAP_S = 20e-6
 HOST_SPAN_PREFIXES = ("bench/", "tfos/")
+#: the serving loop's decode step, whichever of its three programs ran
+DECODE_PROGRAMS = ("jit_tfos_decode", "jit_tfos_decode_sampled",
+                   "jit_tfos_decode_block")
+#: the one prefill (every bucket and group size gives that name)
+PREFILL_PROGRAMS = ("jit_tfos_prefill",)
+#: the programs a loop turn or a train step is counted by
+MAIN_PROGRAMS = DECODE_PROGRAMS + ("jit_tfos_train_step",)
 
 
 def load(path: str) -> list[dict]:
@@ -100,7 +111,8 @@ def reduce(planes: list[dict], period: int | None = None) -> dict | None:
         seconds[program_name(name)] = seconds.get(program_name(name), 0) + dur
     if not seconds:
         return None
-    main = max(seconds, key=lambda k: (seconds[k], k))
+    named = [name for name in MAIN_PROGRAMS if name in seconds]
+    main = max(named or seconds, key=lambda k: (seconds[k], k))
     runs = [e for e in modules if program_name(e[0]) == main]
     if len(runs) < 2:
         return None

@@ -42,6 +42,16 @@ def test_toy_brumby_cell_boots_and_its_counters_move(capfd):
     got = state.read({"kind": "serve-closed", "counters": window["counters"],
                       "cell": harness.load_cell("toy-brumby-batch-decode")})
     assert got == pytest.approx(3 * 4 * 3 * 2 * 17 * 192 * 4 / 1e9)
+    # the three readers are in the manifest for this cell alone (PR 38);
+    # on the CPU only the counter's reader finds something to read
+    listed = {m["name"]: m for m in harness.manifest()["per_layer"]}
+    for name in NEW_METRICS:
+        assert listed[name]["workloads"] == [CELL]
+        assert listed[name]["moves"] == "tokens_per_s"
+    assert result["metrics"]["state_bytes_per_step.serve"]["value"] \
+        == pytest.approx(got)
+    assert result["metrics"]["host_turn_ms.serve"]["value"] > 0
+    assert not set(NEW_METRICS[:2]) & set(result["metrics"])
     # the fp8 control fails the comparison the sound streams pass
     control = next(s for s in said if s["fact"] == "control")
     limits = harness.load_cell("toy-brumby-batch-decode")[

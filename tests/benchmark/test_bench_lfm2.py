@@ -23,14 +23,19 @@ def test_toy_lfm2_cell_boots_and_its_counters_move(capfd):
     result = run_toy("toy-lfm2-batch-decode", 3000000328, trace=1)
     assert result["correct"] is True and result["failed"] == 0
     assert result["metrics"]["decode_rows_per_step"]["value"] > 0
-    # the four new readers are files beside the others and in no manifest
-    # yet (tests/benchmark/test_bench_phase_metrics.py pins the manifest's
-    # last three per-layer entries; PERF.md section 7), so the harness
-    # does not call them
-    listed = {m["name"] for m in harness.manifest()["per_layer"]}
+    # the four readers are in the manifest for this cell alone (PR 38);
+    # on the CPU there is no device trace, so only the counters' reader
+    # finds something to read
+    listed = {m["name"]: m for m in harness.manifest()["per_layer"]}
     for name in NEW_METRICS:
-        assert name not in result["metrics"] and name not in listed
+        assert listed[name]["workloads"] == [CELL]
+        assert listed[name]["moves"] == "tokens_per_s"
         assert callable(harness.load_module("layer_metrics", name).read)
+    assert result["metrics"]["expert_peak_load.serve"]["value"] >= 1.0
+    assert result["metrics"]["host_turn_ms.serve"]["value"] > 0
+    assert not {"moe_decode_step_roofline", "expert_matmul_roofline.serve",
+                "conv_device_ms.serve", "step_mfu.serve"} \
+        & set(result["metrics"])
     out = capfd.readouterr().out
     window = next(json.loads(line) for line in out.splitlines()
                   if line.startswith('{"fact": "serve window"'))
@@ -102,6 +107,26 @@ def test_the_new_readers_read_a_stored_reduced_trace(capsys):
     assert all(s["bound"] == "memory" and
                s["experts_touched_per_step"] == pytest.approx(14 * 31)
                for s in said)
+
+
+def test_a_cell_reports_only_the_metrics_that_list_it():
+    """The dense model's roofline reader finds numbers in this cell's run
+    too (24.5 % of a program that never ran; my chip runs, PR 28); the
+    manifest does not list the cell for it, and the harness no longer
+    calls it there.  A cell in no manifest still gets every reader."""
+    run = _stored_run()
+    assert harness.load_module(
+        "layer_metrics", "decode_step_roofline").read(run) is not None
+    got = harness.layer_metrics(dict(run, window_s=45.0, warmup_s=30.0,
+                                     ttft_ms=[70.0], idle=None))
+    assert "decode_step_roofline" not in got
+    assert {"moe_decode_step_roofline", "step_mfu.serve",
+            "decode_device_ms.serve"} <= set(got)
+    toy = dict(run, cell=dict(run["cell"], name="toy-lfm2-batch-decode"))
+    assert "decode_step_roofline" in harness.layer_metrics(
+        dict(toy, window_s=45.0, warmup_s=30.0, ttft_ms=[70.0], idle=None))
+    names = [m["name"] for m in harness.declared_for("end_to_end", CELL)]
+    assert names == ["tokens_per_s", "gap_p99_ms", "setup_s"]
 
 
 @pytest.mark.parametrize("strip", ["counters", "scopes", "trace", "config"])
@@ -225,10 +250,12 @@ def test_the_list_keeps_its_separation_and_its_one_bucket():
                         for p, n in reqs), reverse=True)[:clients])
     assert pages <= kw["kv_pool_pages"] and kw["prefix_cache"] is False
     assert max(traffic["warm_groups"]) == kw["prefill_rows_max"]
-    # the steps between two admissions are queued on the device one ahead
-    # (PERF.md section 6, PR 28: the host's late wake-ups made the rate
-    # spread wider than half its bound)
-    assert kw["decode_ahead"] is True
+    # nothing the batcher does not read: run-ahead is its rule since PR 31
+    assert set(kw) == {"kv_page_tokens", "kv_pool_pages", "prefix_cache",
+                       "prefill_rows_max"}
+    tool = harness.load_module("tools", "make_request_list")
+    assert tool.request_list(32, 12, (513, 1024), (128, 320), 4, 24)[
+        "requests"] == reqs
 
 
 def test_fp8_control_fails_the_served_logit_comparison_with_experts():

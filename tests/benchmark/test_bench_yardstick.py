@@ -78,10 +78,11 @@ def test_every_entry_finds_its_files_and_every_arrow_its_metric(manifest):
     for m in manifest["per_layer"]:
         assert set(m) <= {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-        assert callable(harness.load_module("layer_metrics", m["name"]).read)
         moved = end_to_end[m["moves"]]
         for cell in m.get("workloads", cells):
             assert cell in cells
+            assert callable(harness.reader_of(
+                m["name"], harness.load_cell(cell)).read), (m["name"], cell)
             assert cell in moved.get("workloads", cells), (m["name"], cell)
     share = sum(w["chips"] == 4 for w in manifest["workloads"])
     assert share <= max(1, len(manifest["workloads"]) // 4)
@@ -140,16 +141,34 @@ def test_a_serve_windows_edge_falls_between_two_steps():
 
 
 def test_the_request_list_is_the_cells_own_and_not_the_seeds():
-    traffic = harness.load_json("traffic", "batch-decode.json")
+    cell = harness.load_cell("gpt2xl-batch-decode")
+    assert cell["traffic"] == "batch-decode-16-xlong-out"
+    traffic, cfg = cell["traffic_data"], cell["config_data"]
     clients = traffic["clients"]
     assert clients == traffic["max_batch"] == 16
+    # 14 a caller: a window completes about 130 requests, 8 a caller, so
+    # none repeats
+    assert len(traffic["requests"]) == 224
     entries = [traffic_gen.client_entries(traffic, i) for i in range(clients)]
     assert sum(len(e) for e in entries) == len(traffic["requests"])
     assert entries[3][1] == tuple(traffic["requests"][3 + clients])
     for prompt, output in traffic["requests"]:
-        assert 129 <= prompt <= 256 and 64 <= output <= 192
+        assert 129 <= prompt <= 256 and 512 <= output <= 750
         assert 1 << (prompt - 1).bit_length() == 256      # one bucket
-        assert prompt + output <= 448
+        assert prompt + output <= cfg["max_position_embeddings"] == 1024
+    # nothing is asked of the batcher but its page size: the default pool
+    # (every slot a whole window) is what seats these rows, and no dead key
+    assert traffic["batcher_kwargs"] == {"kv_page_tokens": 16}
+    assert traffic["warm_groups"] == [1, 2, 4, 8, 16]
+    assert traffic["open_after_completions"] == clients
+    assert traffic["trace_steps"] == 96
+    # the tool at its defaults writes this list, to the pair
+    tool = harness.load_module("tools", "make_request_list")
+    assert tool.request_list(16, 14, (129, 256), (512, 750), 4, 39)[
+        "requests"] == traffic["requests"]
+    # where the lengths come from, and which cell shows the admissions
+    assert "interactive_conditional_samples.py" in traffic["drawn_from"]
+    assert "lfm2-8b-a1b-batch-decode" in traffic["drawn_from"]
     # the seed makes the token ids, never the lengths
     a = traffic_gen.prompt_ids(1, 2, 0, 140, 50257)
     b = traffic_gen.prompt_ids(3000000301, 2, 0, 140, 50257)
@@ -215,12 +234,13 @@ def test_reduction_counts_whole_fetch_periods_and_names_the_gaps():
 
 
 def _idle(reduced, steps, window_s, capsys):
-    program = reduced["programs"][reduced["main_program"]]
-    idle = harness.idle_share(reduced, program["seconds"] / program["runs"],
-                              steps, window_s)
+    device = harness.device_seconds(
+        reduced, {(reduced["main_program"],): steps}, steps)
+    assert device["missing"] == []
+    idle = harness.idle_share(reduced, device["seconds"], window_s)
     said = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
     assert said["fact"] == "idle share cross-check"
-    assert said["differ"] is idle["differ"]
+    assert said["differ"] is idle["differ"] is said["session_set_aside"]
     return idle, said
 
 
@@ -250,6 +270,108 @@ def test_idle_cross_check_trips_on_a_window_between_two_fetches(capsys):
         (reduced["busy_s"], reduced["window_s"])
 
 
+def _serving(turns, prefill_every, decode_s=0.0155, prefill_s=0.0313,
+             host_s=0.0003):
+    """A serving loop's device line: a decode run a turn, a prefill run
+    before every ``prefill_every``-th, the host's ``host_s`` between."""
+    modules, ops, t = [], [], 1.0
+    for i in range(turns):
+        if i % prefill_every == prefill_every - 1:
+            modules.append(("jit_tfos_prefill(9)", t, prefill_s))
+            ops.append(("%fusion.7 = bf16[4]{0} fusion(...)", t, prefill_s))
+            t += prefill_s + host_s
+        modules.append(("jit_tfos_decode(5)", t, decode_s))
+        ops.append(("%fusion.3 = bf16[4]{0} fusion(...)", t, decode_s))
+        t += decode_s + host_s
+    return [{"name": "/device:TPU:0", "lines": [
+        {"name": "XLA Modules", "events": modules},
+        {"name": "XLA Ops", "events": ops}]}]
+
+
+def test_the_windows_device_seconds_count_each_program_by_its_own_runs(
+        capsys):
+    """A session of 23 turns that holds 4 prefills where the window's share
+    would give 3 read -2.96 % idle while the decode program's time x steps
+    stood for the window (my chip runs, PR 37): each program's mean run x
+    its dispatches in the window agrees with the session."""
+    reduced = trace_mod.reduce(_serving(24, 6))
+    assert reduced["main_program"] == "jit_tfos_decode"
+    assert reduced["programs"]["jit_tfos_prefill"]["runs"] == 4
+    decodes, prefills = 2209, 301
+    window_s = decodes * 0.0158 + prefills * 0.0316
+    device = harness.device_seconds(reduced, {
+        trace_mod.DECODE_PROGRAMS: decodes,
+        trace_mod.PREFILL_PROGRAMS: prefills}, decodes)
+    assert device == {"seconds": pytest.approx(
+        decodes * 0.0155 + prefills * 0.0313), "missing": []}
+    idle = harness.idle_share(reduced, device["seconds"], window_s)
+    assert idle["differ"] is False and 0.0 < idle["from_window"] < 3.0
+    # the old figure: all of the session's device time a step x the steps
+    old = sum(p["seconds"] for p in reduced["programs"].values()) \
+        / reduced["steps"] * decodes
+    assert old > window_s
+    # a session that held no admission names what it could not count
+    quiet = trace_mod.reduce(_serving(24, 100))
+    device = harness.device_seconds(quiet, {
+        trace_mod.DECODE_PROGRAMS: decodes,
+        trace_mod.PREFILL_PROGRAMS: prefills}, decodes)
+    assert device["missing"] == ["jit_tfos_prefill"]
+    assert device["seconds"] == pytest.approx(decodes * 0.0155)
+    # a small program beside the two counts by the loop's steps
+    reduced["programs"]["jit_tfos_kv_park"] = {"runs": 3, "seconds": 0.0003}
+    more = harness.device_seconds(reduced, {
+        trace_mod.DECODE_PROGRAMS: decodes,
+        trace_mod.PREFILL_PROGRAMS: prefills}, decodes)
+    assert more["seconds"] - (decodes * 0.0155 + prefills * 0.0313) \
+        == pytest.approx(0.0003 / reduced["steps"] * decodes)
+    capsys.readouterr()
+
+
+def test_the_main_program_is_chosen_by_name_not_by_device_time():
+    """A 121 ms prefill every fourth turn holds the device longer than
+    the 26 ms decode step; the window is still cut at the decode step's
+    runs.  A trace without any of the names keeps the old rule."""
+    reduced = trace_mod.reduce(_serving(12, 4, decode_s=0.026,
+                                        prefill_s=0.121))
+    programs = reduced["programs"]
+    assert programs["jit_tfos_prefill"]["seconds"] \
+        > programs["jit_tfos_decode"]["seconds"]
+    assert reduced["main_program"] == "jit_tfos_decode"
+    assert reduced["steps"] == 11
+    assert trace_mod.reduce(_synthetic())["main_program"] == "jit_step"
+    train = _synthetic()
+    for line in train[0]["lines"]:
+        if line["name"] == "XLA Modules":
+            line["events"] = [("jit_tfos_train_step(1)", s, d / 4)
+                              for _, s, d in line["events"]] \
+                + [("jit_other(2)", s + d / 4, d / 2)
+                   for _, s, d in line["events"]]
+    assert trace_mod.reduce(train)["main_program"] == "jit_tfos_train_step"
+
+
+def test_the_tail_says_whether_it_lies_inside_a_mode():
+    # 3.5 % of the gaps are the admission's turn: the 99th percentile and
+    # its neighbours half a point either way lie within 5 % of each other
+    gaps = [0.0125] * 9650 + [0.0262 + 1e-6 * i for i in range(350)]
+    tail = harness.tail_in_mode(gaps, 99)
+    assert tail["inside_a_mode"] is True and tail["beyond"] == 100
+    assert tail["span_share"] == pytest.approx(1e-4 / 0.02645, rel=0.01)
+    assert tail["ms"]["99"] == pytest.approx(1e3 * harness.nearest_rank(
+        gaps, 99))
+    assert set(tail["ms"]) == {"98.5", "99", "99.5"}
+    # on the slope between two modes it does not (PR 37's 95th percentile:
+    # quantile 92 at 11.9 ms, 95 at 13.6 .. 15.7, 98 at 20.5 .. 24.1)
+    slope = [0.0085] * 9200 + [0.0119 + 2e-5 * i for i in range(600)] \
+        + [0.0258] * 200
+    tail = harness.tail_in_mode(slope, 95, around=1.0)
+    assert tail["inside_a_mode"] is False and tail["span_share"] > 0.2
+    assert harness.tail_in_mode(slope, 99)["inside_a_mode"] is True
+    # a mode that smears over 20 .. 27 ms holds the 99th percentile and not
+    # its neighbours (gpt2xl-batch-decode, my chip runs, PR 38)
+    smeared = [0.009] * 9800 + [0.020 + 3.5e-5 * i for i in range(200)]
+    assert harness.tail_in_mode(smeared, 99)["inside_a_mode"] is False
+
+
 def test_a_session_the_profiler_held_back_is_set_aside(capsys):
     """PR 24's fault: under the profiler ten steps took 4.8 s for 1.26 s
     of device work (idle 74 %) while the run's own window idled 0.2 %.
@@ -276,8 +398,9 @@ def test_a_session_the_profiler_held_back_is_set_aside(capsys):
         pytest.approx(0.2, abs=1e-6)
     assert line["device"]["busy_s"] == pytest.approx(35.8)
     assert line["device"]["window_s"] == pytest.approx(35.8 / 0.998)
-    assert line["breakdown"]["idle_gaps"] == []
+    assert line["breakdown"]["idle_gaps"] == []      # a train session's
     assert line["breakdown"]["device_ops"]
+    assert line["session_set_aside"] is True
     capsys.readouterr()
 
 

@@ -302,6 +302,93 @@ def phase_seconds(name: str):
         labelnames=("phase",)).labels(phase=name.rsplit("/", 1)[1])
 
 
+# -------------------------------------------------------------- hop clocks
+#
+# A request's way through the DRIVER process, clocked as counters and never
+# as spans: the driver holds no device, so its threads share no clock with a
+# device trace, and a ``tfos/`` annotation off the replica's loop thread
+# would take device gaps from the loop's ten spans in the benchmark's
+# reduction (rule i above).  All stamps are ``time.time()``, the clock
+# ``tracing.py``'s events use and the one that means anything in two
+# processes: ``dispatch`` and ``fetch`` lay the driver's clock against the
+# replica's, exact on one host and within clock sync across hosts.
+
+#: a request's hops in the order it takes them (docs/observability.md
+#: "Hop clocks"): the way in up to the first token's put, per request, then
+#: the way back, per token message
+SERVING_HOPS = ("accept", "pending", "dispatch", "seat",
+                "fetch", "pump", "send")
+#: the message that carries a request's first token (and every per-request
+#: hop of the way in), and every later token message
+HOP_TOKENS = ("first", "next")
+
+
+class HopClock:
+    """One hop of one token kind: the bound children of the two hop
+    families, made once outside the paths that ``add`` to them."""
+
+    __slots__ = ("seconds", "messages")
+
+    def __init__(self, seconds, messages):
+        self.seconds, self.messages = seconds, messages
+
+    def add(self, secs: float) -> None:
+        """One message (or request) took ``secs`` over this hop; a negative
+        difference of two processes' clocks counts 0."""
+        self.seconds.inc(secs if secs > 0.0 else 0.0)
+        self.messages.inc()
+
+
+def hop_clocks() -> dict | None:
+    """``{token: {hop: HopClock}}`` over :data:`HOP_TOKENS` and
+    :data:`SERVING_HOPS`, bound to ``tfos_serving_hop_seconds_total`` and
+    ``tfos_serving_hop_messages_total`` of this process's registry; None
+    under ``TFOS_NO_TELEMETRY=1``, so that a path tests one attribute and
+    is otherwise what it was."""
+    reg = _metrics.get_registry()
+    if not reg.enabled:
+        return None
+    seconds = reg.counter(
+        "tfos_serving_hop_seconds_total",
+        "Seconds requests and token messages took over each hop through "
+        "the driver process, summed; token=first is the message with a "
+        "request's first token and the request's way in, token=next every "
+        "later token message.",
+        labelnames=("hop", "token"))
+    messages = reg.counter(
+        "tfos_serving_hop_messages_total",
+        "Messages (or requests) tfos_serving_hop_seconds_total sums over, "
+        "so that seconds over messages is a hop's mean.",
+        labelnames=("hop", "token"))
+    return {token: {hop: HopClock(seconds.labels(hop=hop, token=token),
+                                  messages.labels(hop=hop, token=token))
+                    for hop in SERVING_HOPS} for token in HOP_TOKENS}
+
+
+def hop_totals(clocks: dict | None) -> dict:
+    """``{(hop, token): (seconds, messages)}`` as the clocks stand now: two
+    of these bracket a stretch of time for :func:`hop_means`."""
+    return {(hop, token): (clock.seconds.value(), clock.messages.value())
+            for token, hops in (clocks or {}).items()
+            for hop, clock in hops.items()}
+
+
+def hop_means(clocks: dict | None, since: dict | None = None) -> dict:
+    """``{hop: {token: {"mean_ms", "count"}}}`` of the hops that counted
+    anything: the operator's view of :func:`hop_clocks`, over the
+    process's life, or over the time since an earlier :func:`hop_totals`
+    (the first requests of a replica wait for its programs for seconds,
+    and a mean over the process's life never forgets them)."""
+    out: dict = {}
+    for (hop, token), (secs, n) in hop_totals(clocks).items():
+        secs0, n0 = (since or {}).get((hop, token), (0.0, 0.0))
+        if n - n0:
+            out.setdefault(hop, {})[token] = {
+                "mean_ms": 1e3 * (secs - secs0) / (n - n0),
+                "count": int(n - n0)}
+    return out
+
+
 # ------------------------------------------------------------ health events
 
 class EventLog:

@@ -99,7 +99,6 @@ class ServeFrontend(MessageSocket):
         self._listener: socket.socket | None = None
         self._conns: set[socket.socket] = set()
         self._conns_lock = threading.Lock()
-        self.connections = 0
         #: trace -> replayed ServeRequest a driver failover re-queued
         #: (``serving.failover.resume_driver`` wires these); claimed
         #: one-shot by the first resume naming the trace
@@ -110,6 +109,10 @@ class ServeFrontend(MessageSocket):
         self._m_ops = tpu_metrics.get_registry().counter(
             "tfos_frontend_requests_total",
             "Frontend operations received, by op.", labelnames=("op",))
+        #: the hops this edge clocks (observability.hop_clocks; None under
+        #: TFOS_NO_TELEMETRY=1): ``accept`` per request, ``pump`` and
+        #: ``send`` per TOK frame written
+        self._hops = observability.hop_clocks()
 
     # -- lifecycle ---------------------------------------------------------
     def start(self) -> tuple[str, int]:
@@ -166,7 +169,6 @@ class ServeFrontend(MessageSocket):
             conn.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
             with self._conns_lock:
                 self._conns.add(conn)
-            self.connections += 1
             threading.Thread(target=self._serve_conn, args=(conn,),
                              daemon=True).start()
 
@@ -212,6 +214,7 @@ class ServeFrontend(MessageSocket):
         timeout = msg.get("timeout")
         if timeout is None:
             timeout = self.default_timeout
+        t_recv = time.time() if self._hops is not None else 0.0
         try:
             # the edge stamps the trace id (honoring a client-supplied
             # one): every downstream event for this request carries it
@@ -230,6 +233,8 @@ class ServeFrontend(MessageSocket):
         except (ValueError, TypeError, KeyError) as e:
             self.send(conn, ("ERR", "bad_request", str(e)))
             return
+        if t_recv and req.t_submit:
+            self._hops["first"]["accept"].add(req.t_submit - t_recv)
         self._pump_request(conn, req, stream)
 
     def _pump_request(self, conn: socket.socket, req, stream: bool,
@@ -256,6 +261,9 @@ class ServeFrontend(MessageSocket):
                 except queue.Empty:
                     continue        # loop re-checks remaining (<= 0 now)
                 if ev[0] == "tok":
+                    # further elements: the scheduler clocks hops, and these
+                    # are its stamp of the message's ``get`` and token kind
+                    t_here = time.time() if len(ev) > 2 else 0.0
                     toks = ev[1]
                     if skip:
                         cut = min(skip, len(toks))
@@ -263,6 +271,10 @@ class ServeFrontend(MessageSocket):
                         toks = toks[cut:]
                     if stream and toks:
                         self.send(conn, ("TOK", toks))
+                        if t_here:
+                            hops = self._hops[ev[3]]
+                            hops["pump"].add(t_here - ev[2])
+                            hops["send"].add(time.time() - t_here)
                 elif ev[0] == "done":
                     self.send(conn, ("DONE",
                                      ev[1] if stream

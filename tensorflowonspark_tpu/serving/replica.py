@@ -478,8 +478,8 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
     def on_token(brid: int, tok: int) -> None:
         deltas.setdefault(brid, []).append(int(tok))
 
-    # batcher rid -> (scheduler rid, trace id)
-    rid_map: dict[int, tuple[int, str | None]] = {}
+    # batcher rid -> (scheduler rid, trace id, wall clock of its intake)
+    rid_map: dict[int, tuple[int, str | None, float | None]] = {}
     first_sent: set[int] = set()        # batcher rids past first delta
     stopping = False
     steps = 0
@@ -489,6 +489,9 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
     # payload back to the driver (health.HeartbeatReporter); spans land
     # in <working_dir>/trace_events.jsonl (tracing.py)
     reg = _metrics.get_registry()
+    # the two stamps the driver's hop clocks read (observability.hop_clocks):
+    # ``t_put`` on every tok/done message, ``t_in`` on a request's first
+    put_stamp = (lambda: {"t_put": _time.time()}) if reg.enabled else dict
     m_steps = reg.counter("tfos_replica_steps_total",
                           "Decode steps executed by this replica.")
     m_tokens = reg.counter("tfos_replica_tokens_total",
@@ -890,7 +893,7 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
                                            "event": "error", "error": str(e),
                                            **role_extra})
                             continue
-                        rid_map[brid] = (item["rid"], item.get("trace"))
+                        rid_map[brid] = (item["rid"], item.get("trace"), None)
                         tracer.event(
                             "replica_adopt", item.get("trace"),
                             rid=item["rid"], replica=ctx.executor_id,
@@ -916,7 +919,8 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
                                        "event": "error",
                                        "error": str(e), **role_extra})
                         continue
-                    rid_map[brid] = (item["rid"], item.get("trace"))
+                    rid_map[brid] = (item["rid"], item.get("trace"),
+                                     _time.time() if reg.enabled else None)
                     tracer.event("replica_intake", item.get("trace"),
                                  rid=item["rid"], replica=ctx.executor_id,
                                  prompt_tokens=len(item["prompt"]))
@@ -964,21 +968,24 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
                 publish_engine_counters()
             with spans(_obs.SERVE_FLUSH):
                 for brid, toks in deltas.items():
-                    rid, trace = rid_map[brid]
+                    rid, trace, t_in = rid_map[brid]
+                    first = {}
                     if brid not in first_sent:
                         first_sent.add(brid)
                         tracer.event("replica_first_token", trace, rid=rid,
                                      replica=ctx.executor_id)
+                        if t_in is not None:
+                            first = {"t_in": t_in}
                     m_tokens.inc(len(toks))
                     mgr.queue_put(RESPONSE_QUEUE,
                                   {"rid": rid, "event": "tok",
                                    "tokens": toks, "load": load,
                                    "free_pages": free_pages, **spec_extra,
-                                   **role_extra})
+                                   **role_extra, **first, **put_stamp()})
                 deltas.clear()
                 for brid in done:
                     batcher.result(brid, pop=True)  # tokens already streamed
-                    rid, trace = rid_map.pop(brid)
+                    rid, trace, _ = rid_map.pop(brid)
                     first_sent.discard(brid)
                     tracer.event("replica_done", trace, rid=rid,
                                  replica=ctx.executor_id)
@@ -986,7 +993,7 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
                     mgr.queue_put(RESPONSE_QUEUE,
                                   {"rid": rid, "event": "done", "load": load,
                                    "free_pages": free_pages, **spec_extra,
-                                   **role_extra})
+                                   **role_extra, **put_stamp()})
                     served += 1
                 if role == "prefill":
                     # prefill pool: flush each admitted request's exported
@@ -995,7 +1002,7 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
                     # session's KV pages ride the queue/shm plane like any
                     # bulk tensor — zero-copy on a shared host.
                     for brid, session in batcher.take_sessions():
-                        rid, trace = rid_map.pop(brid)
+                        rid, trace, _ = rid_map.pop(brid)
                         first_sent.discard(brid)
                         tracer.event(
                             "replica_handoff", trace, rid=rid,

@@ -223,7 +223,9 @@ class ServeRequest:
     """One in-flight generate request, owned by the scheduler.
 
     ``events`` is the delivery channel to whoever is waiting (the
-    frontend's connection thread): ``("tok", [t...])`` deltas,
+    frontend's connection thread): ``("tok", [t...])`` deltas (where the
+    scheduler clocks hops, with the stamp of ``_recv_loop``'s ``get`` and
+    the hop clocks' token kind as a third and fourth element),
     ``("done", n_tokens)``, or ``("err", reason, message)``.  ``tokens``
     accumulates every delta already delivered — the replay-dedup source
     and the non-streaming result.
@@ -233,7 +235,7 @@ class ServeRequest:
                  "seed", "deadline", "events", "tokens", "attempts",
                  "replica", "skip", "created", "first_token_at", "finished",
                  "trace", "tenant", "priority", "session", "model",
-                 "session_version")
+                 "session_version", "t_submit", "t_routed")
 
     def __init__(self, rid: int, prompt, max_new_tokens: int,
                  temperature: float, top_p: float, seed: int,
@@ -269,6 +271,9 @@ class ServeRequest:
         #: weights would silently emit wrong tokens)
         self.session: dict | None = None
         self.session_version: str | None = None
+        #: ``time.time()`` at the end of ``submit`` and at the last routing,
+        #: for the hop clocks (0.0: not stamped, the hop is skipped)
+        self.t_submit = self.t_routed = 0.0
 
     def message(self) -> dict:
         """The wire message the replica loop consumes (``trace`` rides
@@ -557,6 +562,11 @@ class ReplicaScheduler:
             "Device-weighted routable capacity: sum of alive, "
             "non-draining replica gang weights.")
         reg.add_collect_hook(self._collect_gauges)
+        #: a request's hops through this process (observability.hop_clocks;
+        #: None under TFOS_NO_TELEMETRY=1): ``pending`` is clocked in the
+        #: dispatch loop, ``dispatch``, ``seat`` and ``fetch`` in
+        #: ``_clock_fetch``
+        self._hops = observability.hop_clocks()
         # audit events are enqueued (GIL-atomic append) and written by a
         # dedicated thread: a stalled disk must never block the request
         # path, which emits under the global scheduler lock
@@ -881,6 +891,8 @@ class ReplicaScheduler:
             self._emit("request_admitted", rid=rid, trace=req.trace,
                        depth=depth, tenant=ten.name, priority=eff_priority,
                        model=model)
+            if self._hops is not None:
+                req.t_submit = time.time()
             self._work.notify()
         return req
 
@@ -1468,6 +1480,9 @@ class ReplicaScheduler:
                            "rate": None if t.bucket is None
                            else t.bucket.rate}
                     for name, t in self.tenants.items()},
+                # a request's way through this process, hop by hop: mean
+                # ms and count by token kind (docs/observability.md)
+                "hops": observability.hop_means(self._hops),
             }
 
     def emit_event(self, kind: str, **fields) -> None:
@@ -1710,6 +1725,11 @@ class ReplicaScheduler:
                                pages=int((session or {}).get("pages", 0)))
                 else:
                     req.attempts += 1
+                    if self._hops is not None:
+                        req.t_routed = time.time()
+                        if req.attempts == 1 and req.t_submit:
+                            self._hops["first"]["pending"].add(
+                                req.t_routed - req.t_submit)
                     msg = req.message()
                     self._emit("request_routed", rid=req.rid,
                                trace=req.trace, replica=rep.eid,
@@ -1764,6 +1784,7 @@ class ReplicaScheduler:
 
     # -- replica responses -------------------------------------------------
     def _recv_loop(self, rep: _Replica) -> None:
+        clocked = self._hops is not None
         while not self._stop.is_set() and rep.alive:
             try:
                 if rep.recv_cli is None:
@@ -1779,9 +1800,29 @@ class ReplicaScheduler:
                 return
             if not isinstance(msg, dict):
                 continue
-            self._handle_response(rep, msg)
+            self._handle_response(rep, msg, time.time() if clocked else 0.0)
 
-    def _handle_response(self, rep: _Replica, msg: dict) -> None:
+    def _clock_fetch(self, req: ServeRequest, msg: dict, token: str,
+                     t_got: float) -> None:
+        """Clock what the replica's stamps on a token message give: its
+        ``fetch``, and under ``token`` ``first`` the end of the request's
+        way in (``dispatch``, ``seat``).  A message without stamps (a
+        replica of an older build) clocks nothing."""
+        t_put = msg.get("t_put")
+        if t_put is None:
+            return
+        hops = self._hops[token]
+        if token == "first":
+            t_in = msg.get("t_in")
+            if t_in is not None and req.t_routed:
+                hops["dispatch"].add(t_in - req.t_routed)
+                hops["seat"].add(t_put - t_in)
+        hops["fetch"].add(t_got - t_put)
+
+    def _handle_response(self, rep: _Replica, msg: dict,
+                         t_got: float = 0.0) -> None:
+        """``t_got``: ``time.time()`` when ``_recv_loop`` held ``msg``, for
+        the hop clocks (0.0: not clocked)."""
         rid = msg.get("rid")
         event = msg.get("event")
         with self._lock:
@@ -1897,7 +1938,8 @@ class ReplicaScheduler:
                     toks = toks[cut:]
                 if not toks:
                     return
-                if req.first_token_at is None:
+                first = req.first_token_at is None
+                if first:
                     req.first_token_at = time.monotonic()
                     ttft = req.first_token_at - req.created
                     self.ttft.record(ttft)
@@ -1909,7 +1951,13 @@ class ReplicaScheduler:
                                trace=req.trace, replica=rep.eid,
                                ttft_secs=round(ttft, 6))
                 req.tokens.extend(toks)
-                req.events.put(("tok", toks))
+                if t_got:
+                    # the frontend's ``pump`` starts where ``fetch`` ended
+                    token = "first" if first else "next"
+                    self._clock_fetch(req, msg, token, t_got)
+                    req.events.put(("tok", toks, t_got, token))
+                else:
+                    req.events.put(("tok", toks))
             elif event == "done":
                 rep.outstanding.pop(rid, None)
                 rep.served += 1

@@ -275,6 +275,49 @@ def test_loop_served_every_request(served):
     assert any(r.get("event") == "model_swapped" for r in ctx.mgr.responses)
 
 
+def test_flush_stamps_its_messages_and_a_requests_first_with_its_intake(
+        served):
+    """The two stamps the driver's hop clocks read (``hop_clocks``):
+    ``t_put`` on every ``tok`` and ``done`` message, ``t_in`` (the wall
+    clock of ``replica_intake``) on a request's first ``tok`` alone; both
+    ``time.time()`` of this process, in order."""
+    t_end = time.time()
+    by_rid: dict = {}
+    for r in served["ctx"].mgr.responses:
+        if r.get("event") in ("tok", "done"):
+            by_rid.setdefault(r["rid"], []).append(r)
+    assert len(by_rid) == FAST_REQUESTS + 1
+    for rid, msgs in by_rid.items():
+        assert [m["event"] for m in msgs[:-1]] == ["tok"] * (len(msgs) - 1)
+        assert msgs[-1]["event"] == "done"
+        assert ["t_in" in m for m in msgs] == [True] + [False] * (
+            len(msgs) - 1), rid
+        puts = [m["t_put"] for m in msgs]
+        assert msgs[0]["t_in"] < puts[0] and puts == sorted(puts)
+        assert t_end - 600 < msgs[0]["t_in"] and puts[-1] <= t_end
+    other = [r for r in served["ctx"].mgr.responses
+             if r.get("event") not in ("tok", "done")]
+    assert other and not any("t_put" in r or "t_in" in r for r in other)
+
+
+def test_no_stamp_is_added_without_telemetry(monkeypatch, tmp_path):
+    """``TFOS_NO_TELEMETRY=1``: the messages are the ones they were."""
+    monkeypatch.setenv(metrics.DISABLE_ENV, "1")
+    monkeypatch.setattr(metrics, "_default_registry", None)
+    cfg, params = _make()
+    batcher = ContinuousBatcher(cfg, params, max_batch=2, kv_page_tokens=8)
+    ctx = _Ctx(str(tmp_path))
+    ctx.mgr.requests.put(_gen(0, _prompt(0, 5), 4))
+    ctx.mgr.requests.put(EndOfFeed())
+    replica.run_serve_loop({"serve_model_builder": _toy_builder,
+                            "serve_idle_poll": 0.05}, ctx, batcher)
+    msgs = ctx.mgr.responses
+    assert {m["event"] for m in msgs[:-1]} == {"tok"}
+    assert msgs[-1]["event"] == "done"
+    assert sum(len(m["tokens"]) for m in msgs[:-1]) == 4
+    assert not any("t_put" in m or "t_in" in m for m in msgs)
+
+
 @pytest.mark.parametrize("name", obs.REPLICA_PHASES)
 def test_every_phase_is_a_span_on_a_host_plane(served, name):
     found = {n for events in served["host"].values() for n, _, _ in events}

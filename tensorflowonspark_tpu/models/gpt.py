@@ -156,8 +156,10 @@ class GPTConfig:
     # row, no pages, no position counter (``cache_kinds``).
     layer_types: tuple | None = None
     conv_L_cache: int = 3
-    # tokens per chunk of a retention layer's chunked form (a block of
-    # tokens on either path), and the normaliser's epsilon
+    # tokens per block inside a retention layer's call on a block of
+    # tokens, on either path (``retention_chunked``: the queries that
+    # attend the call's keys at once, and the keys whose feature map is
+    # made at once), and the normaliser's epsilon
     retention_chunk: int = 128
     retention_eps: float = 1e-6
     # False = a separate output head ``lm_head [hidden, vocab]``, stored
@@ -710,10 +712,11 @@ class PowerRetention(nn.Module):
     ``decode=True`` carries ``ret_state [B, Hkv, D, F]`` and ``ret_norm
     [B, Hkv, F]`` (float32) and a position counter ``index``.  One token a
     row is the recurrent step (scope ``step``: the kernel on the TPU); a
-    block of tokens is the chunked form (scope ``chunk``), which with
-    ``lengths [B]`` leaves the state as it was after each right-padded
-    row's last valid token.  ``decode=False`` is the chunked form from an
-    empty state."""
+    block of tokens is the chunked form (scope ``chunk``: the attention
+    form inside the call, the carried state queried only where it holds
+    something), which with ``lengths [B]`` leaves the state as it was
+    after each right-padded row's last valid token.  ``decode=False`` is
+    the chunked form from an empty state."""
 
     cfg: GPTConfig
     decode: bool = False

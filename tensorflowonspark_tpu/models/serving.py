@@ -614,6 +614,11 @@ class ContinuousBatcher:
         #: with conv or retention layers; 0 otherwise) —
         #: ``tfos_replica_state_rows_seated_total``
         self.state_rows_seated = 0
+        #: prefill dispatches that brought recurrent state with them (a
+        #: chunked admission's final call): over ``prefill_dispatches``,
+        #: the share whose retention layers had a state to query —
+        #: ``tfos_replica_carried_prefills_total``
+        self.carried_prefills = 0
         #: bytes of per-row recurrent state the decode steps read and
         #: wrote: host arithmetic, ``models.gpt.state_step_bytes`` of the
         #: whole batch (a step runs every row, seated or parked) per step
@@ -1581,6 +1586,7 @@ class ContinuousBatcher:
             self._prefill_jit[key] = self._jit(key, final_fn,
                                                donate_argnums=(1,))
         self.prefill_dispatches += 1
+        self.carried_prefills += carried
         self.grouped_matmul_calls += self._step_grouped_matmul_calls
         if self.cfg.has_state:
             self.state_rows_seated += len(entries)

@@ -34,8 +34,19 @@ Three entry points:
   heads that share the key/value head, and writes it back IN PLACE
   (``input_output_aliases``); elsewhere :func:`retention_step_reference`,
   the same arithmetic in ``jax.numpy`` (three passes over the state).
-- :func:`retention_chunked`: the chunked form for a block of tokens (a
-  prefill): inside a chunk the attention form, across chunks the state.
+- :func:`retention_chunked`: a block of tokens (a prefill, a training
+  call): inside one call the attention form, the state only across calls.
+  With ``c_t`` the running sum of ``g`` inside the call and ``(S_in,
+  z_in)`` what the tokens before it left::
+
+      y_t   = (sum_{j<=t in call} a_tj v_j + e^{c_t} S_in phi(q_t))
+            / (sum_{j<=t in call} a_tj + e^{c_t} z_in . phi(q_t) + eps)
+      S_out = e^{c_T} S_in + sum_s e^{c_T - c_s} v_s phi(k_s)^T
+
+  The ``S_in``/``z_in`` terms, the only use of ``phi(q)``, are computed
+  only when the incoming normaliser holds something; ``chunk`` bounds
+  memory (the block of queries that attends the call's keys at once, and
+  the block in which ``phi(k)`` is made and added into the state).
 - :func:`retention_attention`: the attention form over a whole block with
   no state: the definition, which the other two are tested against.
 """
@@ -263,6 +274,14 @@ def _powers(q, k):
     return s * s
 
 
+def _decay(cq, ck, causal):
+    """``exp(c_t - c_s)`` where ``causal [T, S]`` holds and 0 elsewhere:
+    ``cq [B, Hkv, T]``, ``ck [B, Hkv, S]`` running sums of ``g`` ->
+    ``[B, Hkv, T, S]``."""
+    return jnp.where(causal, jnp.exp(jnp.where(
+        causal, cq[..., :, None] - ck[..., None, :], 0.0)), 0.0)
+
+
 def retention_attention(q, k, v, g, eps: float):
     """The attention form over a whole block, no state: ``q [B, T, H, d]``,
     ``k``/``v [B, T, Hkv, d]``, ``g [B, T, Hkv]`` -> ``[B, T, H, d]``
@@ -273,24 +292,41 @@ def retention_attention(q, k, v, g, eps: float):
     k, v, g = (x.astype(jnp.float32) for x in (k, v, g))
     c = jnp.cumsum(g, axis=1).transpose(0, 2, 1)            # [B, Hkv, T]
     causal = jnp.arange(T)[:, None] >= jnp.arange(T)[None, :]
-    w = jnp.where(causal, jnp.exp(jnp.where(
-        causal, c[..., :, None] - c[..., None, :], 0.0)), 0.0)
-    a = _powers(q, k) * w[:, :, None]
+    a = _powers(q, k) * _decay(c, c, causal)[:, :, None]
     num = jnp.einsum("bmgts,bsmd->btmgd", a, v, precision=_HIGHEST)
     den = jnp.sum(a, axis=-1).transpose(0, 3, 1, 2)         # [B, T, Hkv, G]
     return (num / (den[..., None] + eps)).reshape(B, T, H, d)
 
 
+def _from_state(state, z, qc, into):
+    """What a carried state adds to a query block's sums: ``state``/``z``
+    as in the step, ``qc [B, C, Hkv, G, d]``, ``into [B, Hkv, 1, C]`` the
+    decay from the call's start to each query -> ``(num [B, Hkv, G, C, d],
+    den [B, Hkv, G, C])``.  The one place a block of tokens makes
+    ``phi(q)``."""
+    fq = phi(qc)                                            # [B,C,Hkv,G,F]
+    num = jnp.einsum("btmgf,bmdf->bmgtd", fq, state, precision=_HIGHEST)
+    den = jnp.einsum("btmgf,bmf->bmgt", fq, z, precision=_HIGHEST)
+    return into[..., None] * num, into * den
+
+
 def retention_chunked(state, z, q, k, v, g, eps: float, chunk: int,
                       lengths=None):
-    """The chunked form: ``state``/``z`` as in the step, ``q [B, T, H,
-    d]``, ``k``/``v [B, T, Hkv, d]``, ``g [B, T, Hkv]``; ``T`` is cut into
-    chunks of ``chunk`` tokens (the last may be shorter).  Inside a chunk
-    the attention form; the chunks before it reach a token through the
-    state.  ``lengths [B]``: valid tokens of each right-padded row; a pad
-    token neither decays nor enters the state, so the state that comes
-    back is the one after each row's last valid token.  Returns ``(y [B,
-    T, H, d] float32, state, z)``."""
+    """A block of tokens (one call): ``state``/``z`` as in the step, ``q
+    [B, T, H, d]``, ``k``/``v [B, T, Hkv, d]``, ``g [B, T, Hkv]``.  The
+    call's tokens reach each other by the attention form; the tokens
+    before the call reach them through ``state``/``z``, which are queried
+    only where they hold something: the normaliser is a decayed sum of
+    ``phi(k)``, whose diagonal entries are squares, so it is all zero
+    exactly when no key has entered, and then (a fresh prompt, a training
+    call) no ``phi(q)`` is made at all.  ``chunk`` bounds memory: ``T`` is
+    cut into blocks of ``chunk`` tokens (the last is padded), a block of
+    queries attends the call's keys at once (weights ``[B, Hkv, G, chunk,
+    T]``), and ``phi(k)`` is made and added into the state a block at a
+    time (``[B, chunk, Hkv, F]``).  ``lengths [B]``: valid tokens of each
+    right-padded row; a pad token neither decays nor enters the state, so
+    the state that comes back is the one after each row's last valid
+    token.  Returns ``(y [B, T, H, d] float32, state, z)``."""
     B, T, H, d = q.shape
     Hkv = k.shape[2]
     G = H // Hkv
@@ -304,41 +340,41 @@ def retention_chunked(state, z, q, k, v, g, eps: float, chunk: int,
     n = -(-T // C)
     pad = n * C - T
     if pad:
-        # whole chunks for the scan: pad tokens as above (g 0, k 0)
+        # whole blocks for the scan: pad tokens as above (g 0, k 0)
         q, k, v = (jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
                    for x in (q, k, v))
         g = jnp.pad(g, ((0, 0), (0, pad), (0, 0)))
-    causal = jnp.arange(C)[:, None] >= jnp.arange(C)[None, :]
+    c = jnp.cumsum(g, axis=1)               # [B, n*C, Hkv]: from the start
+    ck = c.transpose(0, 2, 1)                               # [B, Hkv, n*C]
+    end = c[:, -1]                                          # [B, Hkv]
+    carried = jnp.any(z != 0)
+    pos = jnp.arange(n * C)
 
-    def chunks(x):      # [B, n*C, ...] -> [n, B, C, ...]
+    def blocks(x):      # [B, n*C, ...] -> [n, B, C, ...]
         return jnp.moveaxis(x.reshape((B, n, C) + x.shape[2:]), 1, 0)
 
     def one(carry, xs):
         S, zz = carry
-        qc, kc, vc, gc = xs
-        c = jnp.cumsum(gc, axis=1).transpose(0, 2, 1)       # [B, Hkv, C]
-        w = jnp.where(causal, jnp.exp(jnp.where(
-            causal, c[..., :, None] - c[..., None, :], 0.0)), 0.0)
-        a = _powers(qc, kc) * w[:, :, None]                 # [B,Hkv,G,C,C]
-        fq = phi(qc)                                        # [B,C,Hkv,G,F]
-        into = jnp.exp(c)[:, :, None, :]                    # [B,Hkv,1,C]
-        num = jnp.einsum("bmgts,bsmd->bmgtd", a, vc, precision=_HIGHEST) \
-            + into[..., None] * jnp.einsum("btmgf,bmdf->bmgtd", fq, S,
-                                           precision=_HIGHEST)
-        den = jnp.sum(a, axis=-1) \
-            + into * jnp.einsum("btmgf,bmf->bmgt", fq, zz,
-                                precision=_HIGHEST)
-        y = num / (den[..., None] + eps)                    # [B,Hkv,G,C,d]
-        # what each key still weighs at the chunk's end
-        out = jnp.exp(c[..., -1:] - c)                      # [B, Hkv, C]
-        fk = phi(kc) * out.transpose(0, 2, 1)[..., None]    # [B, C, Hkv, F]
-        last = jnp.exp(c[..., -1])                          # [B, Hkv]
-        S = last[..., None, None] * S + jnp.einsum(
-            "bsmd,bsmf->bmdf", vc, fk, precision=_HIGHEST)
-        zz = last[..., None] * zz + jnp.sum(fk, axis=1)
+        at, qc, kc, vc, cc = xs
+        cq = cc.transpose(0, 2, 1)                          # [B, Hkv, C]
+        w = _decay(cq, ck, at[:, None] >= pos[None, :])     # [B,Hkv,C,n*C]
+        a = _powers(qc, k) * w[:, :, None]                  # [B,Hkv,G,C,n*C]
+        num = jnp.einsum("bmgts,bsmd->bmgtd", a, v, precision=_HIGHEST)
+        den = jnp.sum(a, axis=-1)
+        into = jnp.exp(cq)[:, :, None, :]                   # [B,Hkv,1,C]
+        snum, sden = lax.cond(
+            carried, lambda: _from_state(state, z, qc, into),
+            lambda: (jnp.zeros_like(num), jnp.zeros_like(den)))
+        y = (num + snum) / ((den + sden)[..., None] + eps)  # [B,Hkv,G,C,d]
+        # what each key still weighs at the call's end
+        fk = phi(kc) * jnp.exp(end[:, None] - cc)[..., None]   # [B,C,Hkv,F]
+        S = S + jnp.einsum("bsmd,bsmf->bmdf", vc, fk, precision=_HIGHEST)
+        zz = zz + jnp.sum(fk, axis=1)
         return (S, zz), y.transpose(0, 3, 1, 2, 4)          # [B,C,Hkv,G,d]
 
-    (state, z), ys = lax.scan(one, (state, z),
-                              tuple(chunks(x) for x in (q, k, v, g)))
+    last = jnp.exp(end)
+    (state, z), ys = lax.scan(
+        one, (last[..., None, None] * state, last[..., None] * z),
+        (pos.reshape(n, C),) + tuple(blocks(x) for x in (q, k, v, c)))
     y = jnp.moveaxis(ys, 0, 1).reshape(B, n * C, H, d)[:, :T]
     return y, state, z

@@ -566,6 +566,13 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
             "tfos_replica_state_rows_seated_total",
             "Rows whose recurrent state an admission wrote (configurations "
             "with conv or retention layers)."),
+        "carried_prefills": reg.counter(
+            "tfos_replica_carried_prefills_total",
+            "Prefill dispatches that brought recurrent state with them (a "
+            "chunked admission's final call): 1 - this over "
+            "tfos_replica_prefill_dispatches_total is the share of "
+            "prefills whose retention layers had no state to query "
+            "(ops.power_retention.retention_chunked)."),
         "state_bytes_moved": reg.counter(
             "tfos_replica_state_bytes_moved_total",
             "Bytes of per-row recurrent state the decode steps read and "

@@ -241,16 +241,26 @@ def test_a_row_parked_ahead_is_cleared_before_the_queued_step(made):
 
 
 def test_chunked_admission_carries_the_state_beside_the_cache(made):
+    """``carried_prefills`` counts the final dispatches that brought
+    ``inf["state"]``: none without ``prefill_chunk``, one per chunked
+    request with it (both prompts here are longer than a chunk).  A
+    chunked request's FIRST slice is handed zeros as an INPUT
+    (``_advance_inflight``: ``self._state_rows(1)``), not made inside the
+    program, so it skips the state's query only because
+    ``retention_chunked`` decides that from the normaliser it is given;
+    a flag from the caller that made the zeros would miss it."""
     cfg, params = made
     p = _prompt(40, 23)
     whole = ContinuousBatcher(cfg, params, max_batch=2)
     rid = whole.submit(p, 7)
     want = whole.run()[rid]
+    assert (whole.prefill_dispatches, whole.carried_prefills) == (1, 0)
     b = ContinuousBatcher(cfg, params, max_batch=2, prefill_chunk=4)
     other = b.submit(_prompt(41, 6), 12)
     rid = b.submit(p, 7)
     out = b.run()
     assert out[rid].tolist() == want.tolist() and other in out
+    assert (b.prefill_dispatches, b.carried_prefills) == (2, 2)
 
 
 # ----------------------------------------------------------- refusals

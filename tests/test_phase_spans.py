@@ -264,6 +264,8 @@ def served(tmp_path_factory):
             # read after the loop alone: the loop registers it
             "kernel_calls": reg.snapshot().get(
                 "tfos_replica_grouped_matmul_calls_total"),
+            "carried_prefills": reg.snapshot().get(
+                "tfos_replica_carried_prefills_total"),
             "split": {n: after[n] - before[n] for n in after},
             "slow_trips": slow.value(phase="decode_fetch") - slow_before}
 
@@ -400,6 +402,20 @@ def test_kernel_calls_are_published_and_a_dense_model_moves_none(served):
     assert "tfos_grouped_matmul" in entry["help"]
     assert sum(row[-1] for row in entry["samples"]) == 0
     assert served["batcher"].grouped_matmul_calls == 0
+
+
+def test_carried_prefills_are_published_beside_the_prefill_dispatches(
+        served):
+    """``tfos_replica_carried_prefills_total`` is published like the other
+    engine counters; this loop's prefills brought no recurrent state (a
+    dense model, no chunked admission), so it stays 0 beside prefill
+    dispatches that moved."""
+    entry = served["carried_prefills"]
+    assert entry is not None and entry["type"] == "counter"
+    assert "recurrent state" in entry["help"]
+    assert sum(row[-1] for row in entry["samples"]) == 0
+    assert served["batcher"].carried_prefills == 0
+    assert served["batcher"].prefill_dispatches > 0
 
 
 def test_stretched_turn_trips_the_slow_step_rule_once(served):

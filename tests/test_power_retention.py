@@ -1,6 +1,7 @@
 """``ops/power_retention.py``: the feature map's identity, the three forms
 of one function (attention, chunked, recurrent), padding that stays out of
-the state, and the decode-step kernel (Pallas interpreter) against the
+the state, a call that continues a carried state and one that has none to
+query, and the decode-step kernel (Pallas interpreter) against the
 ``jax.numpy`` arithmetic.  Everything float32 at highest precision: what
 differs between the forms is the order of sums."""
 
@@ -90,6 +91,122 @@ def test_a_chunked_block_continues_a_carried_state():
     got, _, _ = pr.retention_chunked(S, z, q[:, 20:], k[:, 20:], v[:, 20:],
                                      g[:, 20:], EPS, 8)
     np.testing.assert_allclose(got, want[:, 20:], atol=2e-5)
+
+
+@pytest.mark.parametrize("split", [1, 20, 36])
+def test_a_call_split_in_two_is_the_one_call(split):
+    """A carried call with ``lengths`` set: the second call's tokens reach
+    the first's through the state alone, and a row whose valid tokens all
+    lay in the first call (20 of them, split at 36: none left) hands its
+    state on as it got it."""
+    q, k, v, g = _inputs()
+    lengths = jnp.asarray([20, 37])
+    want, S, z = pr.retention_chunked(*pr.init_state(2, 2, 16), q, k, v, g,
+                                      EPS, 8, lengths=lengths)
+    a, b = (slice(None), slice(None, split)), (slice(None), slice(split, None))
+    y1, S1, z1 = pr.retention_chunked(
+        *pr.init_state(2, 2, 16), q[a], k[a], v[a], g[a], EPS, 8,
+        lengths=jnp.minimum(lengths, split))
+    y2, S2, z2 = pr.retention_chunked(
+        S1, z1, q[b], k[b], v[b], g[b], EPS, 8,
+        lengths=jnp.maximum(lengths - split, 0))
+    got = jnp.concatenate([y1, y2], axis=1)
+    for row, n in enumerate(lengths.tolist()):
+        np.testing.assert_allclose(got[row, :n], want[row, :n], atol=2e-5)
+    np.testing.assert_allclose(S2, S, atol=5e-6)
+    np.testing.assert_allclose(z2, z, atol=5e-6)
+
+
+def test_a_fresh_row_beside_a_carried_row():
+    """One call whose incoming state is zero for row 0 and carried for row
+    1: the state is queried (row 1 holds something) and adds nothing to
+    row 0."""
+    q, k, v, g = _inputs()
+    want = pr.retention_attention(q, k, v, g, EPS)
+    _, S, z = pr.retention_chunked(*pr.init_state(2, 2, 16), q[:, :20],
+                                   k[:, :20], v[:, :20], g[:, :20], EPS, 8)
+    S, z = S.at[0].set(0.0), z.at[0].set(0.0)
+    got, S2, z2 = pr.retention_chunked(S, z, q[:, 20:], k[:, 20:], v[:, 20:],
+                                       g[:, 20:], EPS, 8)
+    alone = pr.retention_attention(q[:1, 20:], k[:1, 20:], v[:1, 20:],
+                                   g[:1, 20:], EPS)
+    np.testing.assert_allclose(got[0], alone[0], atol=2e-5)
+    np.testing.assert_allclose(got[1], want[1, 20:], atol=2e-5)
+    _, S0, z0 = _recurrent(q[:1, 20:], k[:1, 20:], v[:1, 20:], g[:1, 20:])
+    _, S1, z1 = _recurrent(q[1:], k[1:], v[1:], g[1:])
+    np.testing.assert_allclose(S2, jnp.concatenate([S0, S1]), atol=5e-6)
+    np.testing.assert_allclose(z2, jnp.concatenate([z0, z1]), atol=5e-6)
+
+
+@pytest.mark.parametrize("T,chunk", [(37, 5), (37, 36), (9, 4), (8, 64)])
+def test_a_last_block_shorter_than_the_chunk(T, chunk):
+    """``T`` not a multiple of ``chunk`` (and a chunk longer than the
+    call): the padded tail of the last block reaches neither the outputs
+    nor the state."""
+    q, k, v, g = _inputs(T=T)
+    got, S, z = pr.retention_chunked(*pr.init_state(2, 2, 16), q, k, v, g,
+                                     EPS, chunk)
+    assert got.shape == q.shape
+    np.testing.assert_allclose(got, pr.retention_attention(q, k, v, g, EPS),
+                               atol=2e-5)
+    _, S2, z2 = _recurrent(q, k, v, g)
+    np.testing.assert_allclose(S, S2, atol=5e-6)
+    np.testing.assert_allclose(z, z2, atol=5e-6)
+
+
+def _equations(jaxpr, inside=()):
+    """Every equation of ``jaxpr`` and of the programs its equations hold
+    (a scan's body, a conditional's branches), each with the path of
+    ``(primitive, branch)`` it lies under."""
+    for eqn in jaxpr.eqns:
+        yield inside, eqn
+        for value in eqn.params.values():
+            held = value if isinstance(value, (list, tuple)) else [value]
+            for i, sub in enumerate(held):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _equations(
+                        sub, inside + ((eqn.primitive.name, i),))
+
+
+def _products_of_phi_q(jaxpr, G, F):
+    """Paths of the ``dot_general`` equations with an operand that has
+    both an ``F``-sized and a ``G``-sized axis: ``phi(q)`` of the grouped
+    query heads."""
+    return [inside for inside, eqn in _equations(jaxpr)
+            if eqn.primitive.name == "dot_general"
+            and any(F in x.aval.shape and G in x.aval.shape
+                    for x in eqn.invars)]
+
+
+def test_phi_q_is_made_only_for_a_state_that_holds_something():
+    """At the served heads' shapes (d = 128, F = 8704, G = 5 query heads a
+    key/value head): the program holds the products of ``phi(q)`` with the
+    state and with its normaliser once each, both inside the branch taken
+    when the incoming normaliser is not all zero; the other branch and
+    everything outside the conditional hold none, and a call that makes
+    its zero state itself compiles to a program with no conditional and no
+    ``[..., G, F]`` array at all."""
+    B, T, H, Hkv, d, chunk = 1, 16, 10, 2, 128, 8
+    G, F = H // Hkv, pr.feature_dim(d)
+    q, k, v, g = _inputs(B=B, T=T, H=H, Hkv=Hkv, d=d)
+
+    def given(S, z):
+        return pr.retention_chunked(S, z, q, k, v, g, EPS, chunk)
+
+    jaxpr = jax.make_jaxpr(given)(*pr.init_state(B, Hkv, d)).jaxpr
+    conds = [inside for inside, eqn in _equations(jaxpr)
+             if eqn.primitive.name == "cond"]
+    assert conds == [(("scan", 0),)]
+    # branch 1 is the true one: lax.cond orders them (false, true)
+    assert _products_of_phi_q(jaxpr, G, F) == [(("scan", 0), ("cond", 1))] * 2
+
+    def fresh():
+        return pr.retention_chunked(*pr.init_state(B, Hkv, d), q, k, v, g,
+                                    EPS, chunk)
+
+    program = jax.jit(fresh).lower().compile().as_text()
+    assert "conditional" not in program and f"{G},{F}]" not in program
 
 
 def test_padding_enters_neither_the_state_nor_the_running_decay():

@@ -29,6 +29,7 @@ import jax.numpy as jnp
 from tensorflowonspark_tpu.models.bert import _context_mesh, _dense
 from tensorflowonspark_tpu.ops import paged_attention as _paged
 from tensorflowonspark_tpu.ops import power_retention as _retention
+from tensorflowonspark_tpu.ops import ssm as _ssm
 from tensorflowonspark_tpu.ops.flash_attention import _on_tpu
 
 
@@ -45,6 +46,10 @@ class GPTConfig:
     # in groups via a grouped einsum (repeated K/V never materialise); a
     # custom ``attention_fn`` gets K/V broadcast to num_heads once.
     num_kv_heads: int | None = None
+    # Size of one attention head where it is not ``hidden_size //
+    # num_heads`` (None = that): the projections are ``hidden_size ->
+    # num_heads * head_dim`` and back.
+    attn_head_dim: int | None = None
     intermediate_size: int = 3072
     max_position_embeddings: int = 1024
     dropout_rate: float = 0.0
@@ -53,7 +58,8 @@ class GPTConfig:
     # embeddings applied to q/k (no position table at all) — relative
     # positions by construction, the long-context-friendly default of
     # modern decoders.  K is cached post-rotation, so decode matches the
-    # full forward exactly.
+    # full forward exactly.  "none" = no table and no rotation: the
+    # recurrent layers of a hybrid carry the order (Nemotron-H).
     pos_encoding: str = "learned"
     rope_base: float = 10000.0
     # "layernorm" (GPT-2) or "rmsnorm" (Llama-class: no mean-centering, no
@@ -139,8 +145,15 @@ class GPTConfig:
     # RMSNorm over each head's values of q and of k (learned scale per
     # head position), BEFORE the rotation.
     qk_norm: bool = False
-    # Per-layer token mixer: ``"full_attention"``, ``"conv"`` or
-    # ``"retention"``, one entry per layer (None = attention everywhere).
+    # Per-layer token mixer: ``"full_attention"``, ``"conv"``,
+    # ``"retention"``, ``"mamba2"`` or ``"experts"``, one entry per layer
+    # (None = attention everywhere).  A mamba2 layer is a MAMBA-2 mixer
+    # (:class:`Mamba2Mixer`, ``ops.ssm``), which keeps TWO kinds of
+    # per-row state on the decode path: ``ssm_state`` (float32, ``[B, G,
+    # N, (H/G) * P]``) and ``ssm_conv``, the last ``ssm_conv_kernel - 1``
+    # inputs of its depthwise convolution over ``ssm_conv_channels``
+    # channels.  An experts layer is the expert layer (``models.moe``) as
+    # the block's ONLY operator, which needs ``mixer_only``.
     # A retention layer is POWER RETENTION (:class:`PowerRetention`,
     # ``ops.power_retention``): attention weighted by the square of the
     # query-key product under a learned per-token decay, carried on the
@@ -155,7 +168,21 @@ class GPTConfig:
     # ``conv_state [B, conv_L_cache - 1, H]`` cache leaf, fixed size per
     # row, no pages, no position counter (``cache_kinds``).
     layer_types: tuple | None = None
+    # True = a block is its one operator and nothing else, ``x +
+    # Op(norm(x))``: no second norm, no feed-forward half (Nemotron-H: a
+    # Mamba-2 mixer, the experts or attention, one a layer).
+    mixer_only: bool = False
     conv_L_cache: int = 3
+    # The Mamba-2 mixer's sizes: heads of ``ssm_head_dim`` values (the
+    # inner width is their product, whatever ``hidden_size`` is), groups
+    # that share B and C, the state size per value, the convolution's taps
+    # and the tokens of one chunk of the scan over a block of tokens.
+    ssm_num_heads: int = 0
+    ssm_head_dim: int = 64
+    ssm_groups: int = 1
+    ssm_state_size: int = 128
+    ssm_conv_kernel: int = 4
+    ssm_chunk: int = 128
     # tokens per block inside a retention layer's call on a block of
     # tokens, on either path (``retention_chunked``: the queries that
     # attend the call's keys at once, and the keys whose feature map is
@@ -175,11 +202,33 @@ class GPTConfig:
     num_experts: int | None = None
     num_experts_per_tok: int = 1
     moe_intermediate_size: int | None = None
+    # The experts THIS CHIP HOLDS, ``(first, count)`` of the
+    # ``num_experts`` the router scores (None = all of them): the layer
+    # routes over all, computes the part of the result its own experts
+    # give and leaves the rest out (the chip's share of a deployment that
+    # divides every expert layer over several chips; no exchange here).
+    experts_held: tuple | None = None
+    # A shared expert of this width beside the routed ones, unweighted
+    # (None = none); the experts' activation, ``"swiglu"`` (three
+    # matrices) or ``"relu2"`` (two: ``W2 relu(W1 u)**2``); the factor on
+    # the chosen experts' normalised weights.
+    moe_shared_intermediate_size: int | None = None
+    moe_activation: str = "swiglu"
+    routed_scaling_factor: float = 1.0
+    # The experts' first matrices (``w_up``, and a gated expert's
+    # ``w_gate``) are stored ``[E, F, H]``, the hidden axis last, and not
+    # ``[E, H, F]``.  Set it where ``moe_intermediate_size`` is not whole
+    # 128-lane tiles (1856 = 14.5): ``[E, H, F]`` is then not row-major as
+    # the device lays a parameter out, and the grouped kernel, which takes
+    # its operands row-major, would have every layer's experts re-laid on
+    # every call (``ops.grouped_matmul``).  It says how a checkpoint is
+    # laid, so the width does not decide it by itself.
+    moe_up_transposed: bool = False
 
     def __post_init__(self):
-        if self.pos_encoding not in ("learned", "rope"):
+        if self.pos_encoding not in ("learned", "rope", "none"):
             raise ValueError(
-                f"pos_encoding must be 'learned' or 'rope', "
+                f"pos_encoding must be 'learned', 'rope' or 'none', "
                 f"got {self.pos_encoding!r}")
         if self.norm not in ("layernorm", "rmsnorm"):
             raise ValueError(
@@ -241,31 +290,71 @@ class GPTConfig:
             if self.has_conv and self.conv_L_cache < 2:
                 raise ValueError(
                     f"conv_L_cache must be >= 2, got {self.conv_L_cache}")
+            if self._count("mamba2") and (
+                    self.ssm_num_heads < 1 or self.ssm_conv_kernel < 2
+                    or self.ssm_num_heads % self.ssm_groups
+                    or self.ssm_chunk < 1):
+                raise ValueError(
+                    "a mamba2 layer needs ssm_num_heads >= 1 in whole "
+                    "ssm_groups, ssm_conv_kernel >= 2 and ssm_chunk >= 1, "
+                    f"got {self.ssm_num_heads} heads in {self.ssm_groups} "
+                    f"groups, {self.ssm_conv_kernel} taps, chunks of "
+                    f"{self.ssm_chunk}")
+            if self._count("experts") and not (
+                    self.mixer_only and self.num_experts is not None):
+                raise ValueError(
+                    "a layer_types 'experts' layer is the block's only "
+                    "operator: it needs mixer_only=True and num_experts")
         if self.num_experts is not None:
-            if self.mlp != "swiglu" or self.moe_intermediate_size is None \
+            # the dense layers' ``mlp`` says nothing of the experts, which
+            # carry their own activation
+            if self.moe_activation not in ("swiglu", "relu2") \
+                    or self.moe_intermediate_size is None \
                     or not 1 <= self.num_experts_per_tok <= self.num_experts \
                     or not 0 <= self.num_dense_layers <= self.num_layers:
                 raise ValueError(
-                    "num_experts needs mlp='swiglu', moe_intermediate_size, "
-                    "1 <= num_experts_per_tok <= num_experts and 0 <= "
-                    f"num_dense_layers <= num_layers, got mlp={self.mlp!r}, "
+                    "num_experts needs moe_activation 'swiglu' or 'relu2', "
+                    "moe_intermediate_size, 1 <= num_experts_per_tok <= "
+                    "num_experts and 0 <= num_dense_layers <= num_layers, "
+                    f"got moe_activation={self.moe_activation!r}, "
                     f"moe_intermediate_size={self.moe_intermediate_size}, "
                     f"{self.num_experts_per_tok} of {self.num_experts} "
                     f"experts, {self.num_dense_layers} dense layers")
+            if self.experts_held is not None:
+                object.__setattr__(self, "experts_held",
+                                   tuple(int(v) for v in self.experts_held))
+                first, count = self.experts_held
+                if first < 0 or count < 1 \
+                        or first + count > self.num_experts:
+                    raise ValueError(
+                        f"experts_held (first, count) = {self.experts_held} "
+                        f"does not lie inside the {self.num_experts} experts "
+                        "the router scores")
         if self.retention_chunk < 1:
             raise ValueError(f"retention_chunk must be >= 1, got "
                              f"{self.retention_chunk}")
         if self.scan_layers and (self.has_state or self.num_experts
-                                 is not None):
+                                 is not None or self.mixer_only):
             raise ValueError(
                 "scan_layers stacks ONE uniform block; layer_types with "
-                "conv or retention layers and num_experts (dense layers "
-                "before expert layers) make the blocks differ — leave "
-                "scan_layers off")
+                "conv, retention or mamba2 layers, mixer_only and "
+                "num_experts (dense layers before expert layers) make the "
+                "blocks differ — leave scan_layers off")
 
     @property
     def head_dim(self) -> int:
-        return self.hidden_size // self.num_heads
+        return self.attn_head_dim or self.hidden_size // self.num_heads
+
+    @property
+    def ssm_inner(self) -> int:
+        """Width of a Mamba-2 mixer's ``x``, ``z`` and ``y``."""
+        return self.ssm_num_heads * self.ssm_head_dim
+
+    @property
+    def ssm_conv_channels(self) -> int:
+        """Channels of a Mamba-2 mixer's convolution: ``x``, ``B`` and
+        ``C`` side by side."""
+        return self.ssm_inner + 2 * self.ssm_groups * self.ssm_state_size
 
     def layer_type(self, layer: int) -> str:
         return LAYER_TYPES[0] if self.layer_types is None \
@@ -282,24 +371,40 @@ class GPTConfig:
     @property
     def num_attention_layers(self) -> int:
         """Layers that own K/V (``full_attention``)."""
-        return self.num_layers - self._count("conv") \
-            - self._count("retention")
+        return self.num_layers if self.layer_types is None \
+            else self._count("full_attention")
+
+    @property
+    def num_state_layers(self) -> int:
+        """Layers that keep per-row recurrent state."""
+        return sum(self._count(t) for t in ("conv", "retention", "mamba2"))
 
     @property
     def has_state(self) -> bool:
         """Whether some layer keeps per-row RECURRENT state on the decode
         path (:data:`STATE_LEAVES`): fixed size per row, no position to
         mask by, so nothing to rewind to, share or hand off."""
-        return self.num_attention_layers < self.num_layers
+        return self.num_state_layers > 0
 
     def is_expert_layer(self, layer: int) -> bool:
-        return self.num_experts is not None \
-            and layer >= self.num_dense_layers
+        """Whether block ``layer`` holds the experts: as its feed-forward
+        half from ``num_dense_layers`` on, or, under ``mixer_only``, as
+        the one operator of an ``"experts"`` layer."""
+        if self.num_experts is None:
+            return False
+        if self.mixer_only:
+            return self.layer_type(layer) == "experts"
+        return layer >= self.num_dense_layers
 
     @property
     def num_expert_layers(self) -> int:
-        return 0 if self.num_experts is None \
-            else self.num_layers - self.num_dense_layers
+        return sum(self.is_expert_layer(i) for i in range(self.num_layers))
+
+    @property
+    def num_experts_held(self) -> int:
+        """Experts whose weights this chip holds."""
+        return 0 if self.num_experts is None else self.num_experts \
+            if self.experts_held is None else self.experts_held[1]
 
     @property
     def cache_kinds(self) -> str:
@@ -308,6 +413,7 @@ class GPTConfig:
         caused it."""
         kinds = []
         n_conv, n_ret = self._count("conv"), self._count("retention")
+        n_ssm = self._count("mamba2")
         if self.num_attention_layers:
             kinds.append(f"K/V of {self.num_attention_layers} "
                          "full_attention layer(s) (positional, rewindable)")
@@ -319,17 +425,24 @@ class GPTConfig:
             kinds.append(f"ret_state of {n_ret} retention layer(s) (the "
                          "decayed sum of every token so far per row: fixed "
                          "size, no snapshot to rewind to or share)")
+        if n_ssm:
+            kinds.append(f"ssm_state and ssm_conv of {n_ssm} mamba2 layer(s) "
+                         "(the decayed sum of every token so far and the "
+                         f"last {self.ssm_conv_kernel - 1} convolution "
+                         "inputs per row: fixed size, no snapshot to rewind "
+                         "to or share)")
         return "; ".join(kinds)
 
 
 #: the token mixers ``GPTConfig.layer_types`` may name
-LAYER_TYPES = ("full_attention", "conv", "retention")
+LAYER_TYPES = ("full_attention", "conv", "retention", "mamba2", "experts")
 
 #: cache leaves that are per-row recurrent state: the batch on their
 #: leading axis, a fixed size per row, written whole by a step.  What
 #: moves a sequence (seat, park, chunked admission) moves these rows;
 #: what needs a snapshot of them is refused (``GPTConfig.has_state``).
-STATE_LEAVES = ("conv_state", "ret_state", "ret_norm")
+STATE_LEAVES = ("conv_state", "ret_state", "ret_norm", "ssm_state",
+                "ssm_conv")
 
 
 def is_state_leaf(path) -> bool:
@@ -341,13 +454,22 @@ def state_step_bytes(cfg: GPTConfig, rows: int) -> int:
     """Bytes of per-row state one decode step of ``rows`` rows reads and
     writes, as THIS process runs it: a conv state is read and written
     once; a retention state as often as
-    ``ops.power_retention.state_passes`` says."""
-    conv = 2 * rows * (cfg.conv_L_cache - 1) * cfg.hidden_size \
-        * jnp.dtype(cfg.dtype).itemsize
+    ``ops.power_retention.state_passes`` says; a Mamba-2 mixer's two
+    states once each (``ops.ssm.ssm_step``, kernel or not)."""
+    def tail(taps: int, channels: int) -> int:
+        # a convolution's last inputs: read and written once
+        return 2 * rows * (taps - 1) * channels \
+            * jnp.dtype(cfg.dtype).itemsize
+
     ret = _retention.state_passes() * _retention.state_bytes(
         rows, cfg.num_kv_heads or cfg.num_heads, cfg.head_dim) \
         if cfg._count("retention") else 0
-    return cfg._count("conv") * conv + cfg._count("retention") * ret
+    ssm = 2 * _ssm.state_bytes(rows, cfg.ssm_num_heads, cfg.ssm_head_dim,
+                               cfg.ssm_state_size) \
+        + tail(cfg.ssm_conv_kernel, cfg.ssm_conv_channels) \
+        if cfg._count("mamba2") else 0
+    return cfg._count("conv") * tail(cfg.conv_L_cache, cfg.hidden_size) \
+        + cfg._count("retention") * ret + cfg._count("mamba2") * ssm
 
 
 def kv_row_width(num_kv_heads: int, head_dim: int) -> int:
@@ -783,6 +905,103 @@ class PowerRetention(nn.Module):
                           False)(y.astype(cfg.dtype).reshape(B, T, H * D))
 
 
+class Mamba2Mixer(nn.Module):
+    """The token mixer of a ``"mamba2"`` layer (``ops.ssm`` has the
+    recurrence): ``[z, xBC, dt] = W_in u`` of widths ``ssm_inner``,
+    ``ssm_conv_channels`` and ``ssm_num_heads``; ``xBC = silu(conv(xBC) +
+    b)``, depthwise and causal over ``ssm_conv_kernel`` taps, split into
+    ``x [H, P]``, ``B`` and ``C [G, N]``; ``dt = softplus(dt + dt_bias)``,
+    ``A = -exp(A_log)``; the recurrence, plus ``D_h x_h``; then the GATED
+    GROUP NORM ``RMSNorm_groups(y * silu(z)) * w`` (the gate first, the
+    mean square over each of the ``ssm_groups`` groups of channels) and
+    ``W_out``.  No biases but the convolution's.  Projections are
+    ``cfg.dtype`` with float32 accumulation; the convolution, ``dt``, the
+    decay, the state and its update, and the norm are float32.
+
+    ``decode=True`` carries ``ssm_state`` (float32, ``ops.ssm``'s layout)
+    and ``ssm_conv [B, ssm_conv_kernel - 1, ssm_conv_channels]``, the last
+    inputs of the convolution, BEFORE it.  One token a row is the
+    recurrent step (scope ``step``: the kernel on the TPU); a block of
+    tokens the chunked scan (scope ``scan``), which with ``lengths [B]``
+    leaves both states as they were after each right-padded row's last
+    valid token.  ``decode=False`` is the scan from an empty state."""
+
+    cfg: GPTConfig
+    decode: bool = False
+
+    @nn.compact
+    def __call__(self, x, lengths=None):
+        cfg = self.cfg
+        B, T, _ = x.shape
+        H, P, G, N = (cfg.ssm_num_heads, cfg.ssm_head_dim, cfg.ssm_groups,
+                      cfg.ssm_state_size)
+        inner, C, K = cfg.ssm_inner, cfg.ssm_conv_channels, \
+            cfg.ssm_conv_kernel
+        with jax.named_scope("in_proj"):
+            z, xbc, dt = jnp.split(
+                _dense(inner + C + H, (None, "tp"), cfg.dtype, "in_proj",
+                       use_bias=False)(x), [inner, inner + C], axis=-1)
+        w = self.param("conv_kernel", nn.initializers.normal(0.02), (K, C))
+        b = self.param("conv_bias", nn.initializers.zeros, (C,))
+        dt_bias = self.param("dt_bias", nn.initializers.zeros, (H,))
+        A_log = self.param("A_log", nn.initializers.zeros, (H,))
+        D = self.param("D", nn.initializers.ones, (H,))
+        scale = self.param("norm_scale", nn.initializers.ones, (inner,))
+        with jax.named_scope("conv"):
+            if self.decode:
+                tail = self.variable("cache", "ssm_conv", jnp.zeros,
+                                     (B, K - 1, C), cfg.dtype)
+                full = jnp.concatenate([tail.value, xbc], axis=1)
+                if lengths is None:
+                    tail.value = full[:, T:]
+                else:
+                    at = lengths[:, None] + jnp.arange(K - 1)[None, :]
+                    tail.value = jnp.take_along_axis(full, at[:, :, None],
+                                                     axis=1)
+            else:
+                full = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
+            wf = w.astype(jnp.float32)
+            xbc = jax.nn.silu(sum(
+                wf[j] * full[:, j:j + T].astype(jnp.float32)
+                for j in range(K)) + b.astype(jnp.float32))
+            xs, Bm, Cm = jnp.split(xbc, [inner, inner + G * N], axis=-1)
+            xs = xs.reshape(B, T, H, P)
+            Bm, Cm = Bm.reshape(B, T, G, N), Cm.reshape(B, T, G, N)
+            dt = jax.nn.softplus(dt.astype(jnp.float32)
+                                 + dt_bias.astype(jnp.float32))
+            A = -jnp.exp(A_log.astype(jnp.float32))
+        if self.decode:
+            state = self.variable(
+                "cache", "ssm_state", jnp.zeros,
+                _ssm.state_shape(B, H, P, G, N), jnp.float32)
+            s0 = state.value
+        else:
+            s0 = jnp.zeros(_ssm.state_shape(B, H, P, G, N), jnp.float32)
+        if self.decode and T == 1:
+            with jax.named_scope("step"):
+                y, s1 = _ssm.ssm_step(s0, xs[:, 0], dt[:, 0],
+                                      jnp.exp(dt[:, 0] * A), Bm[:, 0],
+                                      Cm[:, 0])
+                y = y[:, None]
+        else:
+            with jax.named_scope("scan"):
+                y, s1 = _ssm.ssm_chunked(s0, xs, dt, A, Bm, Cm,
+                                         cfg.ssm_chunk, lengths)
+        if self.decode:
+            state.value = s1
+        with jax.named_scope("gate_norm"):
+            y = y + D.astype(jnp.float32)[:, None] * xs
+            y = y.reshape(B, T, G, inner // G) * jax.nn.silu(
+                z.astype(jnp.float32)).reshape(B, T, G, inner // G)
+            y = y * jax.lax.rsqrt(
+                jnp.mean(jnp.square(y), -1, keepdims=True) + cfg.norm_eps)
+            y = (y.reshape(B, T, inner)
+                 * scale.astype(jnp.float32)).astype(cfg.dtype)
+        with jax.named_scope("out_proj"):
+            return _dense(cfg.hidden_size, ("tp", None), cfg.dtype,
+                          "out_proj", use_bias=False)(y)
+
+
 def _norm(cfg: GPTConfig, name: str):
     if cfg.norm == "rmsnorm":
         return nn.RMSNorm(epsilon=cfg.norm_eps, dtype=jnp.float32, name=name)
@@ -792,8 +1011,9 @@ def _norm(cfg: GPTConfig, name: str):
 class DecoderBlock(nn.Module):
     """One pre-norm block: ``h = x + Op(norm(x))``, ``h + FFN(norm(h))``.
     ``layer`` picks the operator (``cfg.layer_types``: attention, the
-    short convolution or power retention) and the FFN (the MLP, or the
-    experts from ``cfg.num_dense_layers`` on)."""
+    short convolution, power retention, a Mamba-2 mixer or the experts)
+    and the FFN (the MLP, or the experts from ``cfg.num_dense_layers``
+    on).  Under ``cfg.mixer_only`` the block ends after its operator."""
 
     cfg: GPTConfig
     decode: bool = False
@@ -805,21 +1025,28 @@ class DecoderBlock(nn.Module):
         # remat wrapper below can mark it static via ``static_argnums``
         # — jax.checkpoint traces kwargs, and a traced ``train`` breaks
         # the ``not train`` dropout toggle (TracerBoolConversionError).
+        from tensorflowonspark_tpu.models.moe import SparseMoE
+
         cfg = self.cfg
+        kind = cfg.layer_type(self.layer)
         y = _norm(cfg, "ln1")(x).astype(cfg.dtype)
-        if cfg.layer_type(self.layer) == "conv":
+        if kind == "conv":
             y = ShortConv(cfg, self.decode, name="conv")(y, lengths)
-        elif cfg.layer_type(self.layer) == "retention":
+        elif kind == "retention":
             y = PowerRetention(cfg, self.decode, name="ret")(y, lengths)
+        elif kind == "mamba2":
+            y = Mamba2Mixer(cfg, self.decode, name="ssm")(y, lengths)
+        elif kind == "experts":
+            y = SparseMoE(cfg, name="moe")(y)
         else:
             y = CausalSelfAttention(cfg, self.decode, name="attn")(
                 y, train=train)
         y = nn.Dropout(cfg.dropout_rate, deterministic=not train)(y)
         x = x + y
+        if cfg.mixer_only:
+            return x
         y = _norm(cfg, "ln2")(x).astype(cfg.dtype)
         if cfg.is_expert_layer(self.layer):
-            from tensorflowonspark_tpu.models.moe import SparseMoE
-
             y = SparseMoE(cfg, name="moe")(y)
             y = nn.Dropout(cfg.dropout_rate, deterministic=not train)(y)
             return x + y
@@ -872,8 +1099,9 @@ class GPT(nn.Module):
                        dtype=cfg.dtype,
                        embedding_init=nn.with_partitioning(
                            nn.initializers.normal(0.02), cfg.emb_spec))
-        if cfg.pos_encoding == "rope":
-            # positions live in the attention rotations; no table at all
+        if cfg.pos_encoding != "learned":
+            # rope: positions live in the attention rotations; none: in
+            # the recurrent layers' order.  No table at all
             with jax.named_scope("embed"):
                 x = tok(input_ids)
         else:
@@ -991,9 +1219,9 @@ def rewind_cache(cache, position):
     rewound position (see :func:`lookup_generate`).
 
     Refuses a cache with recurrent-state leaves (:data:`STATE_LEAVES`): a
-    conv layer's state is the last gated inputs it saw and a retention
-    layer's the decayed sum of every token so far, with no position to
-    mask by — tokens written past ``position`` have already entered it,
+    conv layer's state is the last gated inputs it saw, a retention
+    layer's and a mamba2 layer's the decayed sum of every token so far
+    (and the last inputs of its convolution), with no position to mask by — tokens written past ``position`` have already entered it,
     and there is no snapshot to go back to."""
     held = sorted({path[-1].key for path, _ in
                    jax.tree_util.tree_flatten_with_path(cache)[0]
@@ -1001,7 +1229,8 @@ def rewind_cache(cache, position):
     if held:
         raise ValueError(
             f"rewind_cache: the cache holds {', '.join(held)} leaves (a "
-            "layer_types 'conv' or 'retention' layer); a recurrent state "
+            "layer_types 'conv', 'retention' or 'mamba2' layer); a "
+            "recurrent state "
             "cannot be rewound without a snapshot — speculative decoding "
             "(lookup_generate, ContinuousBatcher speculative_k/set_draft) "
             "is refused for such a configuration")

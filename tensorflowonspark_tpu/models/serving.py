@@ -49,9 +49,9 @@ tied to free PAGES as well as free slots, and prefix-hit requests
 prefilling only their tails — the fused ``_prefill`` executable
 prefills, selects first tokens, and scatters block tables + counters
 in one dispatch (docs/serving.md "KV paging & prefix cache").  Beside
-the pages a slot may own fixed-size rows of RECURRENT state (conv and
-retention layers, ``models.gpt.STATE_LEAVES``), moved by the same
-admission, parking and chunked-prefill code; a model with no attention
+the pages a slot may own fixed-size rows of RECURRENT state (conv,
+retention and Mamba-2 layers, ``models.gpt.STATE_LEAVES``), moved by the
+same admission, parking and chunked-prefill code; a model with no attention
 layer has only those, and its batcher builds no pool at all.
 
 Output contract (locked by ``tests/test_serving.py``): a request's
@@ -150,8 +150,9 @@ class _Slot:
 def _apply(model, params, cache, tokens, lengths=None):
     """One cached forward: ``(logits, cache, expert stats)``.  The stats
     are the expert layers' sown counts (``models.moe``: assignments made,
-    the busiest expert's, experts touched) as one flat int32 vector, three
-    per expert layer, or None for a model without experts.  ``lengths``
+    the busiest held expert's, held experts touched, assignments to held
+    experts) as one flat int32 vector, ``STATS_PER_LAYER`` per expert
+    layer, or None for a model without experts.  ``lengths``
     (a padded prefill of a model with recurrent state) goes to the model
     only when given, so a dense model's trace is the one it always was."""
     kwargs = {} if lengths is None else {"lengths": lengths}
@@ -602,14 +603,22 @@ class ContinuousBatcher:
         #: expert layers and over every decode and prefill dispatch whose
         #: tokens the host fetched (the stats ride that fetch: ``_pack``;
         #: a chunk slice of ``prefill_chunk`` has no fetch and is not
-        #: counted): assignments made (rows x experts per token), the
-        #: busiest expert's assignments, and experts that got at least
-        #: one — ``tfos_replica_expert_assignments_total``,
+        #: counted): assignments made (rows x experts per token), and of
+        #: the experts this chip holds (``GPTConfig.experts_held``: all of
+        #: them unless told) the busiest one's assignments, those that got
+        #: at least one, and the assignments that fell to them —
+        #: ``tfos_replica_expert_assignments_total``,
         #: ``..._expert_peak_assignments_total``,
-        #: ``..._experts_touched_total``
+        #: ``..._experts_touched_total``,
+        #: ``..._expert_assignments_held_total``; and the part of the
+        #: experts touched that prefill dispatches account for (the rest
+        #: is the decode steps') —
+        #: ``tfos_replica_prefill_experts_touched_total``
         self.expert_assignments = 0
         self.expert_peak_assignments = 0
         self.experts_touched = 0
+        self.expert_assignments_held = 0
+        self.prefill_experts_touched = 0
         #: rows whose recurrent state an admission wrote (configurations
         #: with conv or retention layers; 0 otherwise) —
         #: ``tfos_replica_state_rows_seated_total``
@@ -689,7 +698,7 @@ class ContinuousBatcher:
             (self.cfg, self.max_batch, self.spec_k, self.spec_ngram,
              self.prefill_chunk, self.decode_block_steps))
 
-        n_stats = 3 * self.cfg.num_expert_layers
+        n_stats = _moe.STATS_PER_LAYER * self.cfg.num_expert_layers
 
         def step_greedy(params, cache, tokens):
             if n_stats:
@@ -734,17 +743,22 @@ class ContinuousBatcher:
         fully pre-baked warm-up."""
         return None if self._aot is None else self._aot.stats()
 
-    def _fetch(self, packed, shape=None) -> np.ndarray:
+    def _fetch(self, packed, shape=None, prefill=False) -> np.ndarray:
         """The host's fetch of a dispatch's tokens (the caller holds the
         fetch span): splits off the expert stats that rode with them
-        (:func:`_pack`) into the lifetime counters."""
+        (:func:`_pack`) into the lifetime counters, a ``prefill``
+        dispatch's experts touched into a counter of their own too."""
         out = np.asarray(packed)
-        n = 3 * self.cfg.num_expert_layers
+        n = _moe.STATS_PER_LAYER * self.cfg.num_expert_layers
         if n:
-            made, peak, touched = out[-n:].reshape(-1, 3).sum(axis=0)
+            made, peak, touched, held = out[-n:].reshape(
+                -1, _moe.STATS_PER_LAYER).sum(axis=0)
             self.expert_assignments += int(made)
             self.expert_peak_assignments += int(peak)
             self.experts_touched += int(touched)
+            self.expert_assignments_held += int(held)
+            if prefill:
+                self.prefill_experts_touched += int(touched)
             out = out[:-n]
         return out if shape is None else out.reshape(shape)
 
@@ -1628,7 +1642,7 @@ class ContinuousBatcher:
             # the prefill is waited for
             self._settled = self._plain_step(stand_down="admission")
         with self._spans(_obs.BATCHER_PREFILL_FETCH):
-            return self._fetch(firsts)
+            return self._fetch(firsts, prefill=True)
 
     def _chunk_jit(self):
         """One fixed-chunk prefill executable: streams a chunk of
@@ -2165,7 +2179,8 @@ class ContinuousBatcher:
             # padded to the packed length for a model with experts
             # (``step_greedy``)
             tokens = np.zeros(
-                self.max_batch + 3 * self.cfg.num_expert_layers, np.int32)
+                self.max_batch + _moe.STATS_PER_LAYER
+                * self.cfg.num_expert_layers, np.int32)
             tokens[:self.max_batch] = [s.tokens[-1] if s else 0
                                        for s in self.slots]
             nxt, self.cache = self._step(self.params, self.cache,

@@ -12,6 +12,7 @@ streamed once (``grouped_matmul``, in place of XLA's ``ragged-dot``).
 
 from tensorflowonspark_tpu.ops.flash_attention import flash_attention
 from tensorflowonspark_tpu.ops.grouped_matmul import (grouped_dot,
+                                                      grouped_relu2,
                                                       grouped_swiglu)
 from tensorflowonspark_tpu.ops.paged_attention import paged_decode_attention
 from tensorflowonspark_tpu.ops.quant import (Int4Array, Int4PackedArray,
@@ -20,8 +21,8 @@ from tensorflowonspark_tpu.ops.quant import (Int4Array, Int4PackedArray,
                                              shard_quantized, tree_nbytes)
 from tensorflowonspark_tpu.ops.xent import tied_softmax_xent
 
-__all__ = ["flash_attention", "grouped_dot", "grouped_swiglu",
-           "paged_decode_attention", "Int4Array",
+__all__ = ["flash_attention", "grouped_dot", "grouped_relu2",
+           "grouped_swiglu", "paged_decode_attention", "Int4Array",
            "Int4PackedArray", "Int8Array", "quantize_int4", "quantize_int8",
            "quantize_params", "shard_quantized", "tree_nbytes",
            "tied_softmax_xent"]

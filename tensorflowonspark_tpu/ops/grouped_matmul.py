@@ -32,7 +32,19 @@ lie:
   included) cannot reach the output;
 - :func:`grouped_swiglu` is the same walk with two weight operands against
   the same rows and ``silu(gate) * up`` in float32 before the one cast:
-  half the launches and row reads of two calls.
+  half the launches and row reads of two calls.  :func:`grouped_relu2` is
+  the walk with one operand and ``relu(.)**2`` before the cast (an expert
+  without a gate matrix): the same kernel, another epilogue.
+- the weights enter AS THEY ARE STORED.  The device lays a parameter out
+  from its shape alone, to waste the least padding, and a kernel's
+  operand must be row-major: ``[E, K, N]`` is row-major as stored only
+  where ``N`` is whole 128-lane tiles.  Where it is not (experts of width
+  1856 = 14.5 tiles), the caller stores the matrices transposed, ``[E, N,
+  K]`` with ``K`` whole tiles, and says ``transposed=True``: the product
+  then contracts the last axis of both operands, which the matrix unit
+  does as readily.  A program that passed ``[E, K, 1856]`` would re-lay
+  all of a layer's experts on every call (a 160 MB copy a layer; what
+  ``tests/test_chip_compile.py`` found).
 
 Tiles are chosen from the shapes a call comes with (:func:`_tiles`).  Off
 the TPU the kernel runs under ``interpret=True`` (its own tests; the model
@@ -115,7 +127,8 @@ def _units(counts, m_pad: int, tm: int):
             jnp.where(rows, lo[group], 0), jnp.where(rows, hi[group], 0))
 
 
-def _kernel(_, tile_ref, first_ref, end_ref, x_ref, *refs, rows, tm, fused):
+def _kernel(_, tile_ref, first_ref, end_ref, x_ref, *refs, rows, tm,
+            activation, transposed):
     w_refs, o_ref = refs[:-1], refs[-1]
     u = pl.program_id(1)
     t = tile_ref[u]
@@ -131,9 +144,16 @@ def _kernel(_, tile_ref, first_ref, end_ref, x_ref, *refs, rows, tm, fused):
     def block(s, _):
         at = pl.multiple_of(s * rows, rows)
         x = x_ref[pl.ds(at, rows), :]
-        y = [jnp.dot(x, w[...], preferred_element_type=jnp.float32)
+        y = [lax.dot_general(x, w[...],
+                             (((1,), (1 if transposed else 0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
              for w in w_refs]
-        y = jax.nn.silu(y[0]) * y[1] if fused else y[0]
+        if activation == "swiglu":
+            y = jax.nn.silu(y[0]) * y[1]
+        elif activation == "relu2":
+            y = jnp.square(jnp.maximum(y[0], 0.0))
+        else:
+            y = y[0]
         row = at + lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
         o_ref[pl.ds(at, rows), :] = jnp.where(
             (row >= lo) & (row < hi), y.astype(o_ref.dtype),
@@ -142,17 +162,20 @@ def _kernel(_, tile_ref, first_ref, end_ref, x_ref, *refs, rows, tm, fused):
     lax.fori_loop(lo // rows, (hi + rows - 1) // rows, block, None)
 
 
-def _call(lhs, weights, counts, out_dtype, tiles, interpret):
+def _call(lhs, weights, counts, out_dtype, tiles, interpret,
+          activation=None, transposed=False):
     """Refuse operands that do not fit, settle ``interpret`` (static under
     the jit below, so here) and make the call."""
     for w in weights:
         if w.ndim != 3 or w.shape != weights[0].shape \
-                or w.dtype != lhs.dtype or w.shape[1] != lhs.shape[1] \
+                or w.dtype != lhs.dtype \
+                or w.shape[2 if transposed else 1] != lhs.shape[1] \
                 or counts.shape != (w.shape[0],):
             raise ValueError(
                 f"rows {lhs.shape} {lhs.dtype} and counts {counts.shape} "
                 f"do not fit weights {w.shape} {w.dtype}")
-    return _grouped(lhs, tuple(weights), counts,
+    return _grouped(lhs, tuple(weights), counts, activation=activation,
+                    transposed=bool(transposed),
                     out_dtype=jnp.dtype(out_dtype), tiles=tiles,
                     interpret=(not _on_tpu()) if interpret is None
                     else bool(interpret))
@@ -171,22 +194,35 @@ def grouped_dot(lhs, rhs, counts, *, tiles=None, interpret=None):
     return _call(lhs, (rhs,), counts, jnp.float32, tiles, interpret)
 
 
-def grouped_swiglu(lhs, w_gate, w_up, counts, *, out_dtype, tiles=None,
-                   interpret=None):
+def grouped_swiglu(lhs, w_gate, w_up, counts, *, out_dtype,
+                   transposed=False, tiles=None, interpret=None):
     """``silu(lhs . w_gate) * (lhs . w_up)`` group by group in one walk:
     both products as :func:`grouped_dot` makes them, the activation and
-    the product in float32, then the one cast to ``out_dtype``."""
-    return _call(lhs, (w_gate, w_up), counts, out_dtype, tiles, interpret)
+    the product in float32, then the one cast to ``out_dtype``.
+    ``transposed``: both weights are ``[E, N, K]``."""
+    return _call(lhs, (w_gate, w_up), counts, out_dtype, tiles, interpret,
+                 "swiglu", transposed)
+
+
+def grouped_relu2(lhs, w_up, counts, *, out_dtype, transposed=False,
+                  tiles=None, interpret=None):
+    """``relu(lhs . w_up)**2`` group by group: the product as
+    :func:`grouped_dot` makes it, the activation in float32, then the one
+    cast to ``out_dtype``.  ``transposed``: ``w_up`` is ``[E, N, K]``."""
+    return _call(lhs, (w_up,), counts, out_dtype, tiles, interpret, "relu2",
+                 transposed)
 
 
 # a program calls this once or twice per expert layer with the same
 # shapes: as a jitted function of its own it is traced and lowered once per
 # program, not once per layer (``ops.paged_attention._attend``)
-@functools.partial(jax.jit,
-                   static_argnames=("out_dtype", "tiles", "interpret"))
-def _grouped(lhs, weights, counts, *, out_dtype, tiles, interpret):
+@functools.partial(jax.jit, static_argnames=("activation", "transposed",
+                                             "out_dtype", "tiles",
+                                             "interpret"))
+def _grouped(lhs, weights, counts, *, activation, transposed, out_dtype,
+             tiles, interpret):
     m, K = lhs.shape
-    E, _, N = weights[0].shape
+    N = weights[0].shape[1 if transposed else 2]
     rows, tm, tn = tiles or _tiles(m, K, N,
                                    lhs.dtype.itemsize * len(weights))
     # whole row tiles (nothing to add at the served shapes)
@@ -198,7 +234,7 @@ def _grouped(lhs, weights, counts, *, out_dtype, tiles, interpret):
         return tile[u], 0
 
     def w_map(j, u, weights, tile, first, end):
-        return weights[u], 0, j
+        return (weights[u], j, 0) if transposed else (weights[u], 0, j)
 
     def o_map(j, u, weights, tile, first, end):
         return tile[u], j
@@ -208,13 +244,14 @@ def _grouped(lhs, weights, counts, *, out_dtype, tiles, interpret):
                    + tm * tn * out_dtype.itemsize)
     out = pl.pallas_call(
         functools.partial(_kernel, rows=rows, tm=tm,
-                          fused=len(weights) == 2),
+                          activation=activation, transposed=transposed),
         out_shape=jax.ShapeDtypeStruct((lhs.shape[0], N), out_dtype),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=len(meta),
             grid=(N // tn, U),
             in_specs=[pl.BlockSpec((tm, K), x_map)]
-            + [pl.BlockSpec((None, K, tn), w_map)] * len(weights),
+            + [pl.BlockSpec((None, tn, K) if transposed else (None, K, tn),
+                            w_map)] * len(weights),
             out_specs=pl.BlockSpec((tm, tn), o_map)),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),

@@ -558,14 +558,24 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
             "tfos_replica_expert_peak_assignments_total",
             "The busiest expert's assignments, summed likewise: x "
             "experts / assignments is the load's unevenness (1 = even)."),
+        "expert_assignments_held": reg.counter(
+            "tfos_replica_expert_assignments_held_total",
+            "Expert assignments that fell to experts this replica holds "
+            "(GPTConfig.experts_held; all of them unless told), summed "
+            "likewise: over tfos_replica_expert_assignments_total the "
+            "share of the router's choices computed here."),
         "experts_touched": reg.counter(
             "tfos_replica_experts_touched_total",
             "Experts that got at least one assignment, summed likewise: "
             "the expert weights a dispatch had to read."),
+        "prefill_experts_touched": reg.counter(
+            "tfos_replica_prefill_experts_touched_total",
+            "The part of tfos_replica_experts_touched_total that prefill "
+            "dispatches account for: the rest is the decode steps'."),
         "state_rows_seated": reg.counter(
             "tfos_replica_state_rows_seated_total",
             "Rows whose recurrent state an admission wrote (configurations "
-            "with conv or retention layers)."),
+            "with conv, retention or mamba2 layers)."),
         "carried_prefills": reg.counter(
             "tfos_replica_carried_prefills_total",
             "Prefill dispatches that brought recurrent state with them (a "
@@ -578,7 +588,8 @@ def run_serve_loop(args, ctx, batcher, *, step_hook=None,
             "Bytes of per-row recurrent state the decode steps read and "
             "wrote (models.gpt.state_step_bytes of the whole batch per "
             "step: a retention state once each through the kernel, a "
-            "third time through the jax.numpy arithmetic)."),
+            "third time through the jax.numpy arithmetic; a mamba2 "
+            "layer's SSM state and convolution tail once each)."),
         "grouped_matmul_calls": reg.counter(
             "tfos_replica_grouped_matmul_calls_total",
             "tfos_grouped_matmul kernel calls (ops.grouped_matmul) the "

@@ -188,16 +188,18 @@ def default_shard_params(cfg, params, mesh):
     if getattr(cfg, "has_state", False) \
             or getattr(cfg, "num_experts", None) is not None:
         # the expert weights [E, H, F] and the conv kernel carry no
-        # partitioning annotations, and a retention layer's state (its
-        # step is a pallas_call, which is not partitioned) none by
-        # key/value head: a mesh would replicate most of such a model
-        # and call it sharded
+        # partitioning annotations, and a retention or mamba2 layer's
+        # state (its step is a pallas_call, which is not partitioned) none
+        # by head: a mesh would replicate most of such a model and call it
+        # sharded
         raise ValueError(
             "default_shard_params lays out the dense-GPT leaves only; a "
-            "configuration with conv or retention layers or experts "
-            "(layer_types / num_experts) has no sharded layout yet — "
-            "serve it on one chip, or pass serve_shard_params= with its "
-            "own layout")
+            "configuration with conv, retention or mamba2 layers or "
+            "experts (layer_types / num_experts) has no sharded layout "
+            "yet — serve it on one chip, or pass serve_shard_params= with "
+            f"its own layout; this configuration keeps {cfg.cache_kinds}"
+            + ("" if getattr(cfg, "num_experts", None) is None else
+               f" and {cfg.num_experts_held} of {cfg.num_experts} experts"))
     model = GPT(cfg)
     abstract = jax.eval_shape(
         lambda: model.init(jax.random.key(0), jnp.ones((1, 4), jnp.int32)))

@@ -44,7 +44,7 @@ def one_chip(topo):
 
     from tensorflowonspark_tpu.models import gpt, moe
     from tensorflowonspark_tpu.ops import (grouped_matmul, paged_attention,
-                                           power_retention)
+                                           power_retention, ssm)
 
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -54,7 +54,7 @@ def one_chip(topo):
     # ``ragged_dot`` and a kernel alone its interpreter: everything this
     # module compiles is for the chip
     seen = [(m, m._on_tpu) for m in (gpt, moe, grouped_matmul,
-                                     paged_attention, power_retention)]
+                                     paged_attention, power_retention, ssm)]
     for m, _ in seen:
         m._on_tpu = lambda: True
     try:
@@ -443,3 +443,106 @@ def test_retention_layers_compile_for_v5e(one_chip, program):
               if re.search(r"= \w+\[(151936,5120|5120,151936)\]\S* copy\(",
                            ln)]
     assert not relaid, relaid
+
+
+# -- one mixer a block at Nemotron-3-Nano-30B-A3B's widths (ISSUE 42) -------
+# One period of the pattern (Mamba-2, experts, Mamba-2, attention, experts)
+# at the published widths with the chip's share (16 of 128 experts, an
+# eighth of the vocabulary), the cell's 32 rows and its 2048 x 16-token
+# pool: the state-space kernel fits its fast memory and updates the donated
+# state IN PLACE, the held experts' two products are the grouped kernel
+# (one call with the relu2 epilogue, one down), the shared expert is plain
+# products, and attention's 256-lane pool rows are row-major.
+
+_NEMOTRON_PROGRAMS = {"decode_B32_T1": (32, 1, False),
+                      "prefill_B4_T256": (4, 256, True)}
+
+
+def _nemotron_cfg(pattern="MEM*E"):
+    from tensorflowonspark_tpu.models.gpt import GPTConfig
+
+    kinds = {"M": "mamba2", "E": "experts", "*": "full_attention"}
+    return GPTConfig(
+        vocab_size=16384, hidden_size=2688, num_layers=len(pattern),
+        num_heads=32, num_kv_heads=2, attn_head_dim=128,
+        max_position_embeddings=1024, pos_encoding="none", norm="rmsnorm",
+        norm_eps=1e-5, use_bias=False, mixer_only=True,
+        layer_types=tuple(kinds[c] for c in pattern), ssm_num_heads=64,
+        ssm_head_dim=64, ssm_groups=8, ssm_state_size=128,
+        ssm_conv_kernel=4, ssm_chunk=128, tie_word_embeddings=False,
+        num_experts=128, num_experts_per_tok=6, moe_intermediate_size=1856,
+        experts_held=(0, 16), moe_shared_intermediate_size=3712,
+        moe_activation="relu2", routed_scaling_factor=2.5, moe_up_transposed=True,
+        per_row_positions=True, kv_page_tokens=16, kv_pool_pages=2048)
+
+
+def _compile_nemotron(one_chip, cfg, B, T, padded):
+    from tensorflowonspark_tpu.models import moe
+    from tensorflowonspark_tpu.models.gpt import GPT, init_cache
+
+    model = GPT(cfg, decode=True)
+    params = jax.eval_shape(
+        lambda: jax.tree.map(
+            lambda a: a.astype(jnp.bfloat16),
+            GPT(cfg).init(jax.random.key(0),
+                          jnp.zeros((1, 8), jnp.int32))["params"]))
+    cache = jax.eval_shape(lambda p: init_cache(cfg, p, B), params)
+
+    def step(params, cache, tokens, lengths):
+        logits, vars_ = model.apply(
+            {"params": params, "cache": cache}, tokens,
+            mutable=["cache", moe.STATS],
+            **({"lengths": lengths} if padded else {}))
+        return jnp.argmax(logits[:, -1], -1), vars_
+
+    def on_chip(tree):
+        return jax.tree.map(lambda t: jax.ShapeDtypeStruct(
+            t.shape, t.dtype, sharding=one_chip), tree)
+
+    return cache, jax.jit(step, donate_argnums=(1,)).lower(
+        on_chip(params), on_chip(cache),
+        jax.ShapeDtypeStruct((B, T), jnp.int32, sharding=one_chip),
+        jax.ShapeDtypeStruct((B,), jnp.int32, sharding=one_chip)).compile()
+
+
+@pytest.mark.parametrize("program", sorted(_NEMOTRON_PROGRAMS))
+def test_mamba2_and_held_expert_layers_compile_for_v5e(one_chip, program):
+    import re
+
+    B, T, padded = _NEMOTRON_PROGRAMS[program]
+    cfg = _nemotron_cfg()
+    cache, compiled = _compile_nemotron(one_chip, cfg, B, T, padded)
+    assert {p[-1].key for p, _ in jax.tree_util.tree_flatten_with_path(
+        cache)[0]} == {"index", "block_table", "k", "v", "ssm_state",
+                       "ssm_conv"}
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):].splitlines()
+    # two kernel calls per expert layer, no grouped product of XLA's
+    assert len(re.findall(r"= \S+ custom-call\(.*tfos_grouped_matmul",
+                          text)) == 2 * cfg.num_expert_layers == 4
+    assert "ragged-dot" not in text
+    # no instruction copies or re-lays a whole layer's held experts
+    assert not [ln.strip()[:120] for ln in text.splitlines()
+                if re.search(r"= bf16\[16,(2688,1856|1856,2688)\]\S* "
+                             r"(copy|transpose|fusion)\(", ln)]
+    # the decode step is the state-space kernel, once a Mamba-2 layer; a
+    # block of tokens is the chunked scan and holds none
+    calls = sum(1 for ln in text.splitlines()
+                if " custom-call(" in ln and "tfos_ssm_step" in ln)
+    assert calls == (0 if padded else 2)
+    # both states of both Mamba-2 layers are parameters of the step, and
+    # the SSM state is updated in place: the donated buffer is the output
+    state = rf"f32\[{B},8,128,512\]"
+    assert sum(1 for ln in entry if " parameter(" in ln
+               and re.search("= " + state, ln)) == 2
+    assert sum(1 for ln in entry if " parameter(" in ln
+               and re.search(rf"= bf16\[{B},3,6144\]", ln)) == 2
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        >= 2 * B * 8 * 128 * 512 * 4
+    # the attention layer's two pools: 2 x 128 = 256 lanes, row-major
+    pools = [ln for ln in entry if " parameter(" in ln
+             and re.search(r"= bf16\[32768,256\]", ln)]
+    assert len(pools) == 2
+    assert all(re.search(r"= bf16\[32768,256\]\{1,0[:}]", ln)
+               for ln in pools)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.0e9

@@ -189,3 +189,47 @@ def test_operands_that_do_not_fit_are_refused(why, make):
     x, (w,), c = _operands(16, 32, 32, [4, 12], jnp.float32)
     with pytest.raises(ValueError, match="do not fit"):
         gm.grouped_dot(*make(x, w, c), interpret=True)
+
+
+# ------------------------------------------------- the relu2 epilogue (PR 42)
+
+@pytest.mark.parametrize("transposed", [False, True],
+                         ids=["stored [E,K,N]", "stored [E,N,K]"])
+@pytest.mark.parametrize("case,held", [
+    ("sizes off the sublane tile", 128),
+    # rows past the held experts' (assignments to experts this chip does
+    # not hold) are NaN here and zeros in the output
+    ("several empty experts, the first and the last among them", 96)])
+def test_the_relu2_form_is_the_plain_call_squared(case, held, transposed):
+    m, K, N, counts, tiles = SIZES[case]
+    counts = np.asarray(counts) * held // m
+    x, (w,), c = _operands(m, K, N, counts, jnp.float32, seed=3)
+    want = np.square(np.maximum(_reference(x, w, c), 0.0))
+    got = gm.grouped_relu2(
+        x, jnp.swapaxes(w, 1, 2) if transposed else w, c,
+        out_dtype=jnp.float32, transposed=transposed, tiles=tiles,
+        interpret=True)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+    assert not np.asarray(got)[int(counts.sum()):].any()
+
+
+@pytest.mark.parametrize("transposed", [False, True],
+                         ids=["stored [E,K,N]", "stored [E,N,K]"])
+def test_the_gated_form_takes_its_weights_as_they_are_stored(transposed):
+    m, K, N, counts, tiles = SIZES["sizes off the sublane tile"]
+    x, (g, w), c = _operands(m, K, N, counts, jnp.float32, seed=4,
+                             n_weights=2)
+    gate = _reference(x, g, c)
+    want = gate / (1.0 + np.exp(-gate)) * _reference(x, w, c)
+    got = gm.grouped_swiglu(
+        x, *(jnp.swapaxes(v, 1, 2) if transposed else v for v in (g, w)),
+        c, out_dtype=jnp.float32, transposed=transposed, tiles=tiles,
+        interpret=True)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+
+
+def test_a_transposed_weight_of_the_wrong_shape_is_refused():
+    x, (w,), c = _operands(32, 64, 128, [16, 16], jnp.float32)
+    with pytest.raises(ValueError, match="do not fit"):
+        gm.grouped_relu2(x, w, c, out_dtype=jnp.float32, transposed=True,
+                         interpret=True)

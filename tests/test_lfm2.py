@@ -127,8 +127,10 @@ def test_no_assignment_is_dropped_when_every_row_picks_the_same_experts():
     want, sel = _moe_ref(p, u)
     assert (np.asarray(sel) == np.array([0, 5])).all()
     np.testing.assert_allclose(got, want, atol=2e-5)
-    # assignments made, the busiest expert's, experts touched
-    assert jax.tree.leaves(stats)[0].tolist() == [80, 40, 2]
+    # assignments made, the busiest expert's, experts touched, and the
+    # assignments that fell to experts held here (all of them: every
+    # expert is held)
+    assert jax.tree.leaves(stats)[0].tolist() == [80, 40, 2, 80]
 
 
 # ---------------------------------------------------------------- refusals

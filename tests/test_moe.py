@@ -8,6 +8,8 @@ two ``all_to_all`` hops, per-owner expert compute — must reproduce it
 bit-for-bit in values AND parameter gradients.
 """
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -181,3 +183,29 @@ def test_both_paths_compute_the_same_layer_and_sow_the_same_counts(
     assert jax.tree.leaves(kstats)[0].tolist() \
         == jax.tree.leaves(stats)[0].tolist() \
         and jax.tree.leaves(stats)[0][0] == rows * tokens * 4
+
+
+@pytest.mark.parametrize("kernel", [False, True],
+                         ids=["ragged_dot", "the kernel"])
+@pytest.mark.parametrize("activation", ["swiglu", "relu2"])
+def test_first_matrices_stored_hidden_axis_last_give_the_same_layer(
+        monkeypatch, kernel, activation):
+    """``moe_up_transposed`` says how ``w_up`` (and a gated expert's
+    ``w_gate``) are stored, whatever the activation, and changes nothing
+    else."""
+    cfg = _expert_cfg(moe_activation=activation)
+    x = jax.random.normal(jax.random.key(1), (2, 24, 32), jnp.float32)
+    params = served.SparseMoE(cfg).init(jax.random.key(0), x)["params"]
+    assert params["w_up"].shape == (8, 32, 16)
+    assert ("w_gate" in params) == (activation == "swiglu")
+    stored = {k: jnp.swapaxes(v, 1, 2) if k in ("w_up", "w_gate") else v
+              for k, v in params.items()}
+    with jax.default_matmul_precision("highest"):
+        want, _ = served.SparseMoE(cfg).apply(
+            {"params": params}, x, mutable=[served.STATS])
+        if kernel:
+            monkeypatch.setattr(served, "_on_tpu", lambda: True)
+        got, _ = served.SparseMoE(
+            dataclasses.replace(cfg, moe_up_transposed=True)).apply(
+                {"params": stored}, x, mutable=[served.STATS])
+    np.testing.assert_allclose(got, want, atol=1e-6)

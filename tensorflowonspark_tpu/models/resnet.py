@@ -9,6 +9,17 @@ with fp32 BatchNorm statistics and fp32 logits, 3×3 stem option for CIFAR,
 and ``axis_name``-aware BatchNorm for cross-replica statistics when desired
 (the ``SyncBatchNorm`` analogue — under ``pjit`` the default per-device
 stats are already the common practice).
+
+What lies between two layers is stored in the model's ``dtype``: every
+BatchNorm gives its result in it (``norm_dtype=None``; an explicit
+``norm_dtype`` wins), so the residual sum, the ``relu``, the max-pool and
+what autodiff saves of them are in it too, and a bf16 model moves half the
+HBM bytes of a float32-normalised one on the bandwidth-bound path.  Float32
+by construction whatever ``dtype`` says: ``param_dtype`` (kernels, scales,
+biases), ``batch_stats``, the statistics' reductions and the normalisation
+arithmetic (flax promotes the input to float32 inside the op and casts its
+result once), the pooled features into the float32 classifier, the logits.
+A ``dtype=float32`` model is float32 throughout.
 """
 
 from __future__ import annotations
@@ -51,17 +62,25 @@ def conv7_stem_to_s2d_kernel(k7):
     return k4.reshape(4, 4, 4 * C, O)
 
 
+def _stored_as(norm_dtype, dtype):
+    """The dtype a BatchNorm gives its result in: the model's ``dtype``
+    unless the caller named one (``is None``, not truthiness: a
+    ``numpy.dtype`` has length 0 and is falsy)."""
+    return dtype if norm_dtype is None else norm_dtype
+
+
 class BasicBlock(nn.Module):
     filters: int
     strides: int = 1
     dtype: jnp.dtype = jnp.bfloat16
     norm: type = nn.BatchNorm
-    norm_dtype: jnp.dtype = jnp.float32
+    norm_dtype: jnp.dtype | None = None  # None: the block's dtype
 
     @nn.compact
     def __call__(self, x, *, train: bool = False):
         norm = partial(self.norm, use_running_average=not train,
-                       momentum=0.9, epsilon=1e-5, dtype=self.norm_dtype)
+                       momentum=0.9, epsilon=1e-5,
+                       dtype=_stored_as(self.norm_dtype, self.dtype))
         conv = partial(nn.Conv, use_bias=False, dtype=self.dtype)
         residual = x
         y = conv(self.filters, (3, 3), strides=(self.strides, self.strides))(x)
@@ -81,12 +100,13 @@ class Bottleneck(nn.Module):
     strides: int = 1
     dtype: jnp.dtype = jnp.bfloat16
     norm: type = nn.BatchNorm
-    norm_dtype: jnp.dtype = jnp.float32
+    norm_dtype: jnp.dtype | None = None  # None: the block's dtype
 
     @nn.compact
     def __call__(self, x, *, train: bool = False):
         norm = partial(self.norm, use_running_average=not train,
-                       momentum=0.9, epsilon=1e-5, dtype=self.norm_dtype)
+                       momentum=0.9, epsilon=1e-5,
+                       dtype=_stored_as(self.norm_dtype, self.dtype))
         conv = partial(nn.Conv, use_bias=False, dtype=self.dtype)
         residual = x
         y = conv(self.filters, (1, 1))(x)
@@ -121,13 +141,9 @@ class ResNet(nn.Module):
     # ``cifar_stem`` is set.
     stem: str = "conv7"
     dtype: jnp.dtype = jnp.bfloat16
-    # BatchNorm compute/output dtype.  fp32 (default) keeps normalized
-    # activations at full precision but doubles the HBM bytes of every
-    # inter-conv tensor on the bandwidth-bound path; bf16 halves that
-    # traffic (flax still accumulates mean/var in fp32 internally, and
-    # params/batch_stats stay fp32 via param_dtype).  A/B'd on-chip by
-    # ``scripts/tpu_sweep.py --stage resnet --bn bf16``.
-    norm_dtype: jnp.dtype = jnp.float32
+    # BatchNorm result dtype; None: ``dtype`` (the module docstring says
+    # what stays float32).  ``scripts/tpu_sweep.py --bn f32|bf16`` sets it.
+    norm_dtype: jnp.dtype | None = None
 
     @nn.compact
     def __call__(self, x, *, train: bool = False):
@@ -146,7 +162,8 @@ class ResNet(nn.Module):
             x = nn.Conv(self.num_filters, (7, 7), strides=(2, 2),
                         padding=[(3, 3), (3, 3)], use_bias=False, dtype=self.dtype)(x)
         x = nn.BatchNorm(use_running_average=not train, momentum=0.9,
-                         epsilon=1e-5, dtype=self.norm_dtype)(x)
+                         epsilon=1e-5,
+                         dtype=_stored_as(self.norm_dtype, self.dtype))(x)
         x = nn.relu(x)
         if not self.cifar_stem:
             x = nn.max_pool(x, (3, 3), strides=(2, 2), padding=[(1, 1), (1, 1)])

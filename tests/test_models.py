@@ -15,6 +15,11 @@ from tensorflowonspark_tpu.models import (Bert, BertConfig,
                                           CifarResNet, MNISTNet, ResNet50,
                                           UNet, WideDeep)
 
+# bfloat16 storage against float32 on the same weights, two toy blocks deep
+BF16_LOGIT_ATOL = 0.05
+BF16_GRAD_RTOL = 0.1
+BF16_GRAD_ATOL = 2e-3     # leaf norms are 0.01-0.2; one is near 0
+
 TINY_BERT = BertConfig(vocab_size=128, hidden_size=32, num_layers=2,
                        num_heads=4, intermediate_size=64,
                        max_position_embeddings=64, dtype=jnp.float32)
@@ -97,6 +102,71 @@ def test_s2d_stem_trains_from_scratch():
     logits, _ = model.apply(variables, x, train=True,
                             mutable=["batch_stats"])
     assert logits.shape == (2, 5)
+
+
+@pytest.mark.parametrize("block", ["Bottleneck", "BasicBlock"])
+@pytest.mark.parametrize("dtype,norm_dtype", [
+    (jnp.bfloat16, None), (jnp.float32, None), (jnp.bfloat16, jnp.float32)],
+    ids=["bf16", "f32", "bf16-norm-f32"])
+def test_resnet_stores_activations_in_its_dtype(dtype, norm_dtype, block):
+    """What lies between two layers is stored in the model's ``dtype``
+    (an explicit ``norm_dtype`` wins); parameters and statistics stay
+    float32; a float32 model is the program it was; a bfloat16 model
+    agrees with the float32 one on the same weights."""
+    from tensorflowonspark_tpu.models import resnet
+
+    k = dict(stage_sizes=(1, 1), block=getattr(resnet, block), num_classes=5,
+             num_filters=8)
+    model = resnet.ResNet(**k, dtype=dtype, norm_dtype=norm_dtype)
+    full = resnet.ResNet(**k, dtype=jnp.float32, norm_dtype=jnp.float32)
+    x = jax.random.normal(jax.random.key(0), (4, 32, 32, 3), jnp.float32)
+    labels = jnp.arange(4) % 5
+    variables = jax.jit(partial(model.init, train=True))(jax.random.key(1), x)
+    # a block's last scale starts at 0, which silences the block
+    variables = jax.tree.map(lambda p: jnp.where(p == 0, 0.2, p), variables)
+
+    @partial(jax.jit, static_argnums=0)
+    def loss_and_grad(m, params):
+        def loss_fn(params):
+            logits, state = m.apply(
+                {**variables, "params": params}, x, train=True,
+                mutable=["batch_stats", "intermediates"],
+                capture_intermediates=True)
+            loss = optax.softmax_cross_entropy_with_integer_labels(
+                logits, labels).mean()
+            return loss, (logits, state)
+        return jax.value_and_grad(loss_fn, has_aux=True)(params)
+
+    (_, (logits, state)), grads = loss_and_grad(model, variables["params"])
+    stored = jnp.dtype(dtype if norm_dtype is None else norm_dtype)
+    held = {tuple(k.key for k in path[:-2]): leaf.dtype for path, leaf
+            in jax.tree_util.tree_leaves_with_path(state["intermediates"])}
+    norms = [name for name in held if name and "BatchNorm" in name[-1]]
+    blocks = [name for name in held if len(name) == 1 and block in name[0]]
+    assert len(blocks) == 2, held
+    assert len(norms) == (9 if block == "Bottleneck" else 6), held
+    assert {held[name] for name in norms + blocks} == {stored}, held
+    assert logits.dtype == jnp.float32
+    for leaf in jax.tree.leaves((variables["params"], grads,
+                                 state["batch_stats"])):
+        assert leaf.dtype == jnp.float32
+
+    (_, (full_logits, _)), full_grads = loss_and_grad(
+        full, variables["params"])
+    if jnp.dtype(dtype) == jnp.float32:
+        # the rule changes nothing where the caller asked for float32
+        np.testing.assert_array_equal(np.asarray(logits),
+                                      np.asarray(full_logits))
+        return
+    np.testing.assert_allclose(np.asarray(logits), np.asarray(full_logits),
+                               atol=BF16_LOGIT_ATOL)
+
+    def leaf_norms(g):
+        return np.asarray([jnp.linalg.norm(leaf)
+                           for leaf in jax.tree.leaves(g)])
+
+    np.testing.assert_allclose(leaf_norms(grads), leaf_norms(full_grads),
+                               rtol=BF16_GRAD_RTOL, atol=BF16_GRAD_ATOL)
 
 
 def test_unet_preserves_spatial_dims():
